@@ -2,7 +2,14 @@
 
 See SURVEY.md for the structural analysis of the reference (PrestoDB) this
 framework is built against, and README.md for the architecture overview.
+
+Importing the package configures JAX and initialises no backend: a chip
+belongs to one process, so a parent that imports this package and then
+starts workers (worker/launcher.py, benchmarks/suite_runner.py) must not
+hold it.
 """
+import os as _os
+
 import jax as _jax
 
 # The engine's value domains are 64-bit (BIGINT, DOUBLE, long decimal
@@ -11,63 +18,41 @@ import jax as _jax
 _jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: pipeline shapes recur across queries and
-# processes, and TPU sort/scan kernels can take tens of seconds to compile.
-# Opt out with PRESTO_TPU_NO_COMPILE_CACHE=1.
-import os as _os
+# processes, and TPU programs can take tens of seconds to compile.  ONE
+# directory per checkout: the one JAX_COMPILATION_CACHE_DIR names when it is
+# set (JAX reads it itself, and no code here sets another), else this fixed,
+# git-ignored path.  The path is part of the cache key, so it carries no
+# host, pid, time or temporary name.
+DEFAULT_COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
 
-def _host_fingerprint() -> str:
-    """Short id of this host's CPU capabilities.  XLA:CPU persists AOT
-    results whose machine features must match the executing host; loading
-    an entry compiled on a different CPU can SIGILL/segfault (observed as
-    cpu_aot_loader 'machine type ... doesn't match' faults).  Scoping the
-    cache directory per host-CPU makes foreign entries invisible."""
-    import hashlib
-    import platform
-    feats = platform.machine()
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("flags"):
-                    feats += " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-    except OSError:
-        pass
-    return hashlib.sha256(feats.encode()).hexdigest()[:12]
+
+def set_compile_cache_dir(path: str) -> str:
+    """Point JAX's persistent compilation cache at `path`, unless
+    JAX_COMPILATION_CACHE_DIR already places it.  Returns the directory
+    in use; one that cannot be created or set raises."""
+    env = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    _os.makedirs(path, exist_ok=True)
+    _jax.config.update("jax_compilation_cache_dir", str(path))
+    return str(path)
 
 
 # The XLA:CPU backend persists AOT executables whose recorded machine
 # features can mismatch even the producing host's runtime detection
 # (cpu_aot_loader warns "could lead to execution errors such as SIGILL",
 # and full-suite runs twice segfaulted inside
-# compilation_cache.get_executable_and_time) — so the persistent cache
-# stays OFF for the CPU backend and ON for TPU, where compiles are the
-# expensive path it exists for.  The backend is taken from the FIRST
-# JAX_PLATFORMS entry when set; otherwise from the resolved default
-# backend (initializing it — every real process does so moments later).
-def _wants_persistent_cache() -> bool:
-    plat = (_os.environ.get("JAX_PLATFORMS")
-            or _os.environ.get("JAX_PLATFORM_NAME") or "")
-    first = plat.split(",")[0].strip().lower()
-    if first:
-        return first != "cpu"
-    try:
-        return _jax.default_backend() != "cpu"
-    except Exception:
-        return False
-
-
+# compilation_cache.get_executable_and_time) — so the package turns the
+# cache ON except for the CPU backend.  Decided from the FIRST JAX_PLATFORMS
+# entry alone (unset = the accelerator JAX finds); asking
+# jax.default_backend() here would initialise a backend at import.
+# Opt out with PRESTO_TPU_NO_COMPILE_CACHE=1.
 if not _os.environ.get("PRESTO_TPU_NO_COMPILE_CACHE") \
-        and _wants_persistent_cache():
-    _cache_dir = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if _cache_dir is None:
-        _cache_dir = _os.path.join(
-            _os.path.expanduser("~/.cache/presto_tpu_xla"),
-            _host_fingerprint())
-    try:
-        _os.makedirs(_cache_dir, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:   # cache is best-effort
-        pass
+        and _os.environ.get("JAX_PLATFORMS", "").split(",")[0] \
+        .strip().lower() != "cpu":
+    set_compile_cache_dir(DEFAULT_COMPILE_CACHE_DIR)
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 __version__ = "0.1.0"

@@ -13,8 +13,9 @@ window.py evaluates running window aggregates with the same pairing
 prefix scan the compaction step uses.
 """
 from .scan_kernel import (DMA_MODES, KERNEL_DECLINE_REASONS,
-                          KERNEL_HASH_MAX_SLOTS, KERNEL_SPAN_MAX_GROUPS,
-                          SUBTILE_ROWS, build_direct_runner,
+                          KERNEL_FAMILY_COMPILES, KERNEL_HASH_MAX_SLOTS,
+                          KERNEL_SPAN_MAX_GROUPS, SUBTILE_ROWS,
+                          build_direct_runner, chain_families, kernel_gate,
                           try_direct_scan_kernel)
 from .grouped import build_hash_runner, try_grouped_scan_kernel
 from .join import (KERNEL_JOIN_MAX_BUILD_BYTES, plan_join_layout,
@@ -25,6 +26,7 @@ from .shim import kernel_interpret
 __all__ = [
     "DMA_MODES",
     "KERNEL_DECLINE_REASONS",
+    "KERNEL_FAMILY_COMPILES",
     "KERNEL_HASH_MAX_SLOTS",
     "KERNEL_JOIN_MAX_BUILD_BYTES",
     "KERNEL_SPAN_MAX_GROUPS",
@@ -32,6 +34,8 @@ __all__ = [
     "SUBTILE_ROWS",
     "build_direct_runner",
     "build_hash_runner",
+    "chain_families",
+    "kernel_gate",
     "plan_join_layout",
     "reserve_build_operands",
     "try_direct_scan_kernel",

@@ -75,11 +75,12 @@ from . import shim
 
 # Eligibility refusals, surfaced as kernelDeclined{reason} RuntimeStats
 # counters (exec/pipeline.py _kernel_declined) -- the kernel twin of the
-# fusionDeclined{...} family.  "Disabled", "AggFunctionShape" and
-# "Backend"(auto) are recorded by the pipeline itself; the rest are
-# produced here / in kernels/grouped.py.  ("ChunkAlignment" was held at 0
-# for one release after tail padding landed and is now retired — the
-# launcher pads/lane-masks every tail, so the decline cannot occur.)
+# fusionDeclined{...} family.  "AggFunctionShape" is recorded by the
+# pipeline itself, "Disabled"/"CompilerRefused"/"Backend"(auto) by
+# kernel_gate below; the rest are produced here / in kernels/grouped.py.
+# ("ChunkAlignment" was held at 0 for one release after tail padding
+# landed and is now retired — the launcher pads/lane-masks every tail,
+# so the decline cannot occur.)
 KERNEL_DECLINE_REASONS = (
     "Disabled",              # scan.kernel = xla
     "AggFunctionShape",      # non-BASIC aggregate functions (moment/corr/
@@ -87,7 +88,12 @@ KERNEL_DECLINE_REASONS = (
     "AggGroupCardinality",   # group count beyond the VMEM accumulator
     #                          gates (span > KERNEL_SPAN_MAX_GROUPS and
     #                          hash estimate/collision > KERNEL_HASH_MAX_SLOTS)
-    "Backend",               # platform is neither tpu nor cpu-interpret
+    "CompilerRefused",       # scan.kernel = auto and the chip's compiler
+    #                          refuses a kernel family the chain needs
+    #                          (KERNEL_FAMILY_COMPILES)
+    "Backend",               # auto off-TPU (interpret-mode emulation is
+    #                          never a win), or a platform that is
+    #                          neither tpu nor cpu-interpret
     "PlanShape",             # chain has uid steps (position-keyed unique
     #                          ids need the XLA chain's expansion layout)
     "ColumnsNotResident",    # a scanned column is not HBM-resident encoded
@@ -105,6 +111,47 @@ KERNEL_DECLINE_REASONS = (
     "WindowInputSize",       # padded sort run over KERNEL_WINDOW_MAX_BYTES
     #                          (whole input must sit in VMEM at once)
 )
+
+# Whether the chip's compiler (Mosaic: JAX 0.9.0, libtpu 0.0.34, v5e)
+# accepts each kernel family's launcher.  Static and the same on every
+# backend: tests/test_chip_compile.py lowers each family for a described
+# v5e and fails when this table and the compiler disagree, so the PR that
+# makes a family compile flips its entry there and here.  All five are
+# refused today: the kernels compute in 64-bit lanes over 1-D blocks,
+# which Mosaic has no tiling for (first error lines in ROADMAP.md).
+KERNEL_FAMILY_COMPILES = {
+    "direct": False,   # build_direct_runner, one-hot grid (G <= 64)
+    "span": False,     # build_direct_runner, packed-scatter span slots
+    "hash": False,     # grouped.build_hash_runner, open addressing
+    "join": False,     # join.join_appliers probes lowered into the above
+    "window": False,   # window._build_runner, prefix-scan window functions
+}
+
+
+def kernel_gate(mode: str, *families: str) -> Optional[str]:
+    """The one scan.kernel decision, made before any kernel is built: the
+    kernelDeclined reason under which `mode` keeps a scan needing these
+    kernel `families` on the XLA chain, or None when the kernel path is
+    taken.  "pallas" is an explicit pin and never declines here -- a
+    lowering the compiler refuses then fails the query with the
+    compiler's message."""
+    if mode == "xla":
+        return "Disabled"
+    if mode == "auto":
+        if not all(KERNEL_FAMILY_COMPILES[f] for f in families):
+            return "CompilerRefused"
+        if jax.default_backend() != "tpu":
+            return "Backend"
+    return None
+
+
+def chain_families(base: str, steps) -> Tuple[str, ...]:
+    """Kernel families a fused chain needs: its aggregation tail `base`
+    plus the in-kernel probe when the chain carries join/semi steps."""
+    if any(s[0] in ("join", "semi") for s in steps):
+        return (base, "join")
+    return (base,)
+
 
 # compacted rows are aggregated in subtiles of this many rows: the
 # G x SUBTILE one-hot grid stays small while a selective filter skips

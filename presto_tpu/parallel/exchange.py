@@ -30,10 +30,7 @@ from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
-try:                                    # moved out of experimental in 0.6
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..exec.batch import Batch, Column

@@ -169,13 +169,6 @@ def _eligible(compiler, output) -> Optional[BatchedTemplateRunner]:
         return None
     if ctx.params is None:
         return None
-    if cfg.scan_kernel == "pallas":
-        return None
-    if cfg.scan_kernel == "auto" and jax.default_backend() == "tpu":
-        # sequential runs engage the Pallas scan kernel here; batching
-        # through the XLA vmap would change the computation
-        return None
-
     projects = []
     node = output.source
     while isinstance(node, P.ProjectNode):
@@ -232,6 +225,12 @@ def _eligible(compiler, output) -> Optional[BatchedTemplateRunner]:
         return None
     info = _direct_mode_info(key_names, key_cols)
     if info is None:
+        return None
+    from ..exec.kernels import chain_families, kernel_gate
+    if kernel_gate(cfg.scan_kernel,
+                   *chain_families("direct", chain.steps)) is None:
+        # sequential runs engage the Pallas scan kernel here; batching
+        # through the XLA vmap would change the computation
         return None
     return BatchedTemplateRunner(compiler, output, chain, aux[:-1],
                                  expands, leaf_cap, specs, input_exprs,

@@ -4,9 +4,10 @@ Two pieces, both wired by the worker server when the corresponding etc/
 properties are set:
 
 1. `enable_compilation_cache(dir)` points JAX's persistent compilation
-   cache (`jax_compilation_cache_dir`) at a directory, so the XLA
-   executables behind every jitted step survive process restarts — a
-   re-trace after reload hits the on-disk cache instead of the compiler.
+   cache (`jax_compilation_cache_dir`) at a directory (the one
+   JAX_COMPILATION_CACHE_DIR names, when set), so the XLA executables
+   behind every jitted step survive process restarts — a re-trace after
+   reload hits the on-disk cache instead of the compiler.
 
 2. `PlanCacheSidecar` — a JSONL record of the statements the serving
    tier compiled (one exemplar per prepared template / catalog / schema
@@ -28,31 +29,24 @@ import json
 import os
 from typing import Dict, List, Optional
 
+import jax
+
+from .. import set_compile_cache_dir
 from ..common.locks import OrderedLock
 
 DEFAULT_SIDECAR_MAX_COUNT = 512
 
 
-def enable_compilation_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at `path`.  Thresholds
-    drop to zero so the serving tier's small point-query executables
-    qualify.  Each knob is applied independently — older JAX builds
-    missing one still get the rest.  Returns True when the cache dir
-    itself was accepted."""
-    try:
-        os.makedirs(path, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except Exception:   # noqa: BLE001 — persistence is advisory
-        return False
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            import jax
-            jax.config.update(knob, val)
-        except Exception:   # noqa: BLE001
-            pass
-    return True
+def enable_compilation_cache(path: str) -> str:
+    """Persist every executable this process compiles: thresholds drop to
+    zero so the serving tier's small point-query executables qualify, and
+    the cache goes to `path` -- unless JAX_COMPILATION_CACHE_DIR places
+    it, which wins (presto_tpu.set_compile_cache_dir).  Returns the
+    directory in use; a failure raises."""
+    used = set_compile_cache_dir(path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return used
 
 
 class PlanCacheSidecar:

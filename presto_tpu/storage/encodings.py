@@ -176,9 +176,13 @@ def encode_column(arr, n_rows: int, encodings: bool = True,
         return ResidentColumn("rle", (run_values, run_starts), n_rows)
 
     # --- dictionary: low-cardinality columns ----------------------------
-    probe = jnp.unique(body[:DICT_PROBE_ROWS])
+    # the probe is sorted on the host: one DICT_PROBE_ROWS transfer per
+    # column per process, where a device sort costs the chip's compiler
+    # tens of seconds per dtype and size
+    probe = np.unique(jax.device_get(  # lint: allow-host-sync
+        body[:DICT_PROBE_ROWS]))
     if hint == "dict" or probe.shape[0] <= DICT_MAX_NDV:
-        values = jnp.unique(body)
+        values = _distinct_values(body, probe)
         ndv = int(values.shape[0])
         if ndv <= DICT_MAX_NDV:
             code_dtype = jnp.int8 if ndv <= 127 else jnp.int16
@@ -196,6 +200,21 @@ def encode_column(arr, n_rows: int, encodings: bool = True,
                 ).astype(code_dtype)
                 return ResidentColumn("dict", (codes, values), n_rows)
     return ResidentColumn("plain", (arr,), n_rows)
+
+
+def _distinct_values(body, probe: np.ndarray):
+    """Sorted distinct values of `body`, given those of its first
+    DICT_PROBE_ROWS rows.  When every row is one of the probe's values
+    (a binary search and a compare, no sort) they are the distinct set;
+    only a column that goes on to new values pays the full-column sort."""
+    if probe.shape[0] <= DICT_MAX_NDV:
+        values = jnp.asarray(probe)
+        slot = jnp.clip(jnp.searchsorted(values, body),
+                        0, values.shape[0] - 1)
+        # build-time stat, one sync per column per process
+        if jax.device_get((values[slot] == body).all()):  # lint: allow-host-sync
+            return values
+    return jnp.unique(body)
 
 
 def _encode_column_host(arr, host: np.ndarray, n_rows: int,

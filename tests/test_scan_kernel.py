@@ -315,11 +315,26 @@ def test_misaligned_chunk_tail_padded():
     assert _declined(res) == {}
 
 
-def test_decline_backend_auto_off_tpu():
-    # auto is a performance decision: off-TPU the kernel only runs in
-    # interpret-mode emulation, so auto takes the XLA chain and meters
-    # Backend; explicit scan_kernel="pallas" pins the kernel (the other
-    # fixtures in this file) so CI still executes the real kernel body
+def test_decline_auto_compiler_refused():
+    # the chip's compiler refuses the direct family (KERNEL_FAMILY_COMPILES,
+    # held to the compiler by tests/test_chip_compile.py), so auto keeps
+    # the XLA chain on every backend and meters CompilerRefused -- never
+    # Backend, which would be false on a TPU; explicit scan_kernel="pallas"
+    # pins the kernel (the other fixtures in this file) so CI still
+    # executes the real kernel body
+    r = LocalQueryRunner("sf0.01", config=ExecutionConfig(
+        scan_kernel="auto"))
+    res = r.assert_same_as_reference(Q6)
+    assert _kernel_programs(res) == 0
+    assert _declined(res) == {"CompilerRefused": 1}
+
+
+def test_decline_backend_auto_off_tpu(monkeypatch):
+    # once a family compiles, auto is a performance decision: off-TPU the
+    # kernel only runs in interpret-mode emulation, so auto takes the XLA
+    # chain and meters Backend
+    from presto_tpu.exec.kernels import KERNEL_FAMILY_COMPILES
+    monkeypatch.setitem(KERNEL_FAMILY_COMPILES, "direct", True)
     r = LocalQueryRunner("sf0.01", config=ExecutionConfig(
         scan_kernel="auto"))
     res = r.assert_same_as_reference(Q6)
@@ -327,12 +342,28 @@ def test_decline_backend_auto_off_tpu():
     assert _declined(res).get("Backend", 0) >= 1
 
 
+@pytest.mark.parametrize("mode,families,compiles,reason", [
+    ("xla", ("direct",), True, "Disabled"),
+    ("auto", ("direct",), False, "CompilerRefused"),
+    ("auto", ("span", "join"), False, "CompilerRefused"),
+    ("auto", ("direct",), True, "Backend"),
+    ("pallas", ("direct",), False, None),
+    ("pallas", ("window",), True, None),
+])
+def test_kernel_gate(monkeypatch, mode, families, compiles, reason):
+    # the one static scan.kernel decision every call site shares:
+    # a join chain needs BOTH its aggregation family and the probe
+    from presto_tpu.exec.kernels import KERNEL_FAMILY_COMPILES, kernel_gate
+    monkeypatch.setitem(KERNEL_FAMILY_COMPILES, families[0], compiles)
+    assert kernel_gate(mode, *families) == reason
+
+
 def test_decline_reasons_are_closed():
     # the reason vocabulary is the EXPLAIN ANALYZE contract: keep it
     # closed
     assert set(KERNEL_DECLINE_REASONS) == {
         "Disabled", "AggFunctionShape", "AggGroupCardinality",
-        "Backend", "PlanShape", "ColumnsNotResident",
+        "CompilerRefused", "Backend", "PlanShape", "ColumnsNotResident",
         "JoinShape", "JoinBuildSize",
         "WindowFunctionShape", "WindowKeyShape", "WindowInputSize"}
 

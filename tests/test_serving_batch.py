@@ -376,6 +376,100 @@ def test_enable_compilation_cache(tmp_path):
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
+def test_env_cache_dir_wins_over_enable(tmp_path, monkeypatch):
+    # where JAX_COMPILATION_CACHE_DIR places the cache no code sets
+    # another directory: enable_compilation_cache (and with it the
+    # serving.compilation-cache-dir property) only lowers the thresholds
+    import jax
+    from presto_tpu.serving import enable_compilation_cache
+    env_dir = tmp_path / "from-env"
+    other = tmp_path / "other"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+            jax.config.jax_persistent_cache_min_entry_size_bytes)
+    try:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        assert enable_compilation_cache(str(other)) == str(env_dir)
+        assert jax.config.jax_compilation_cache_dir == prev[0]
+        assert not other.exists()
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev[1])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          prev[2])
+
+
+def test_enable_compilation_cache_failure_raises(tmp_path, monkeypatch):
+    # a directory that cannot be created is an error, not a silent no-op
+    from presto_tpu.serving import enable_compilation_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    with pytest.raises(OSError):
+        enable_compilation_cache(str(blocker / "cache"))
+
+
+def test_default_cache_dir_is_fixed_inside_checkout():
+    import os
+    import presto_tpu
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(
+        presto_tpu.__file__)))
+    assert presto_tpu.DEFAULT_COMPILE_CACHE_DIR == os.path.join(
+        repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def _child_import_report(env_overrides):
+    """What a fresh `python -c "import presto_tpu ..."` child reports:
+    (backend initialised?, jax_compilation_cache_dir)."""
+    import json
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME",
+                        "JAX_COMPILATION_CACHE_DIR",
+                        "PRESTO_TPU_NO_COMPILE_CACHE")}
+    env.update(env_overrides, PYTHONPATH=repo)
+    code = ("import json, jax, presto_tpu\n"
+            "import presto_tpu.worker.launcher\n"
+            "import presto_tpu.benchmarks.suite_runner\n"
+            "from jax._src import xla_bridge\n"
+            "print(json.dumps([xla_bridge.backends_are_initialized(),"
+            " jax.config.jax_compilation_cache_dir]))")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=120)
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_import_initialises_no_backend(tmp_path):
+    # JAX_PLATFORMS unset: the parents that spawn one process per chip
+    # (worker/launcher.py, benchmarks/suite_runner.py) import the package
+    # and must not hold the chip -- and the cache lands at the fixed
+    # default without asking jax.default_backend().  The opt-out keeps
+    # this child from creating the directory in the checkout under test.
+    import presto_tpu
+    initialised, cache_dir = _child_import_report(
+        {"PRESTO_TPU_NO_COMPILE_CACHE": "1"})
+    assert initialised is False
+    assert cache_dir is None
+    initialised, cache_dir = _child_import_report(
+        {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "placed")})
+    assert initialised is False
+    assert cache_dir == str(tmp_path / "placed")   # JAX's own env read
+    initialised, cache_dir = _child_import_report({"JAX_PLATFORMS": "cpu"})
+    assert initialised is False
+    assert cache_dir is None                      # off for the CPU backend
+    initialised, cache_dir = _child_import_report({"JAX_PLATFORMS": "tpu"})
+    assert initialised is False
+    assert cache_dir == presto_tpu.DEFAULT_COMPILE_CACHE_DIR
+
+
 # ---------------------------------------------------------------------------
 # fragment-level executable sharing
 # ---------------------------------------------------------------------------

@@ -71,6 +71,28 @@ def test_dict_roundtrip_int16_codes():
     np.testing.assert_array_equal(_np(col.decode_full())[:len(body)], body)
 
 
+@pytest.mark.parametrize("late_values", [False, True])
+def test_dict_values_from_probe(monkeypatch, late_values):
+    # the distinct set comes from the host-sorted probe when every row is
+    # one of its values; a column that goes on to new values after the
+    # probed rows falls back to the full-column sort -- same dictionary
+    from presto_tpu.storage import encodings
+    monkeypatch.setattr(encodings, "DICT_PROBE_ROWS", 1024)
+    sorts = []
+    unique = jnp.unique
+    monkeypatch.setattr(encodings.jnp, "unique",
+                        lambda a: sorts.append(a.shape) or unique(a))
+    rng = np.random.default_rng(3)
+    body = rng.integers(0, 11, size=1 << 13, dtype=np.int64)
+    if late_values:
+        body[4096:] += 11
+    col = encode_column(_padded(body), len(body))
+    assert col.kind == "dict"
+    assert int(col.arrays[1].shape[0]) == (22 if late_values else 11)
+    assert bool(sorts) == late_values
+    np.testing.assert_array_equal(_np(col.decode_full())[:len(body)], body)
+
+
 def test_rle_roundtrip_monotone():
     n = 1 << 14
     body = (np.arange(n, dtype=np.int64) // 64) + 1   # 256 runs of 64

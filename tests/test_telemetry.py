@@ -87,7 +87,8 @@ def test_spans_to_resource_spans_golden_shape():
 def test_metrics_payload_golden_shape():
     payload = metrics_to_resource_metrics(
         [("presto_tpu.exchange.bytes", 42.0, {}),
-         ("presto_tpu.kernel.declined", 2.0, {"reason": "Backend"})],
+         ("presto_tpu.kernel.declined", 2.0,
+          {"reason": "CompilerRefused"})],
         time_unix_nano=123, resource={"service.name": "p"})
     (rm,) = payload["resourceMetrics"]
     (sm,) = rm["scopeMetrics"]
@@ -97,7 +98,7 @@ def test_metrics_payload_golden_shape():
         {"timeUnixNano": "123", "asDouble": 42.0}]
     (dp,) = m1["gauge"]["dataPoints"]
     assert dp["attributes"] == [
-        {"key": "reason", "value": {"stringValue": "Backend"}}]
+        {"key": "reason", "value": {"stringValue": "CompilerRefused"}}]
     json.dumps(payload)
 
 

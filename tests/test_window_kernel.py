@@ -232,12 +232,18 @@ def test_input_size_gate_declines(pallas, monkeypatch):
     _assert_rows_equal(res, pallas.execute(RUNNING_SUM), ordered=False)
 
 
-def test_auto_off_tpu_declines_backend():
+@pytest.mark.parametrize("compiles,reason", [
+    (False, "CompilerRefused"),   # today's table: refused on every backend
+    (True, "Backend"),            # a family that compiles: auto off-TPU
+])
+def test_auto_declines(monkeypatch, compiles, reason):
+    from presto_tpu.exec.kernels import KERNEL_FAMILY_COMPILES
+    monkeypatch.setitem(KERNEL_FAMILY_COMPILES, "window", compiles)
     r = LocalQueryRunner("sf0.01", config=ExecutionConfig(
         scan_kernel="auto"))
     res = r.execute(RUNNING_SUM)
     assert _window_programs(res) == 0
-    assert _declined(res).get("Backend", 0) >= 1
+    assert _declined(res) == {reason: 1}
 
 
 def test_explain_analyze_reports_window_kernel(pallas):
