@@ -9,9 +9,12 @@ and flags the hazard shapes:
 
   SYNC001  explicit host sync: `jax.device_get(...)`, `.item()`,
            `.block_until_ready()`.  These are sometimes *required*
-           (adaptive re-plans, duplicate-key probes) but each site must
-           be acknowledged with the allowlist pragma so new ones can't
-           creep in silently.
+           (adaptive re-plans, duplicate-key probes): inside
+           `presto_tpu/` every one goes through
+           `utils.runtime_stats.host_get(x, why)`, which counts the sync
+           and its wait into the query's RuntimeStats, and the allowlist
+           pragma is honoured in that helper's file alone -- anywhere
+           else in the package a marked transfer is still a finding.
   SYNC002  `int()` / `float()` / `bool()` applied to a device value —
            an implicit transfer hidden inside a cast.
   SYNC003  `np.asarray()` / `np.array()` applied to a device value —
@@ -72,8 +75,11 @@ The tracking is heuristic — the lint is a tripwire for review, not a
 type system — so precision is tuned to zero false positives on the
 shipped tree rather than completeness.
 
-Legitimate sync points carry the pragma on any line of the statement:
+Legitimate sync points go through the counted helper; outside the
+package (tests, fixtures) the pragma on any line of the statement still
+acknowledges one:
 
+    kmax = int(host_get(_max_run(table), "build_max_run"))
     kmax = int(jax.device_get(_max_run(table)))  # lint: allow-host-sync
 
 Run as a module (exits nonzero when any finding survives the pragmas):
@@ -174,8 +180,16 @@ _SIMPLE_QUEUE_CALLS = {"queue.SimpleQueue", "SimpleQueue"}
 # list: most of the jax namespace (jit, vmap, tree_util) returns host
 # objects; the array-producing submodules are named explicitly.
 _DEVICE_PREFIXES = ("jnp.", "jax.numpy.", "lax.", "jax.lax.")
-# Calls that move a value to host (their result is safe to branch on).
+# Explicit transfers (SYNC001) ...
 _HOST_CALLS = {"jax.device_get"}
+# ... and every call whose result is on the host (safe to branch on):
+# host_get is the counted wrapper around the one sanctioned device_get
+_HOST_RESULT_CALLS = _HOST_CALLS | {"host_get"}
+# Inside presto_tpu/ the host-sync pragma silences SYNC001-SYNC004 in
+# this file only (the home of host_get); other trees (tests, fixtures,
+# `<string>`) keep the plain pragma.
+_SYNC_PRAGMA_PACKAGE = "presto_tpu/"
+_SYNC_PRAGMA_HOME = "presto_tpu/utils/runtime_stats.py"
 # numpy conversion entry points that force a device->host copy when fed
 # a device array.
 _NUMPY_CONVERTERS = {"np.asarray", "np.array", "numpy.asarray",
@@ -240,6 +254,8 @@ class _Linter(ast.NodeVisitor):
     def __init__(self, path: str, allowed: Dict[str, Set[int]]):
         self.path = path
         self.allowed = allowed.get(PRAGMA, set())
+        # SYNC001-004: the same pragma, honoured only where host_get lives
+        self.sync_allowed = self.allowed
         self.wall_allowed = allowed.get(WALL_PRAGMA, set())
         self.mem_allowed = allowed.get(MEM_PRAGMA, set())
         self.net_allowed = allowed.get(NET_PRAGMA, set())
@@ -247,6 +263,9 @@ class _Linter(ast.NodeVisitor):
         self._device: List[Set[str]] = [set()]
         import os
         norm = path.replace(os.sep, "/")
+        if _SYNC_PRAGMA_PACKAGE in norm \
+                and not norm.endswith(_SYNC_PRAGMA_HOME):
+            self.sync_allowed = set()
         self._network_scoped = (
             any(m in norm for m in _NETWORK_PATH_MARKERS)
             and not any(norm.endswith(a) for a in _NETWORK_ALLOWLIST))
@@ -278,7 +297,7 @@ class _Linter(ast.NodeVisitor):
             return node.id in self._scope()
         if isinstance(node, ast.Call):
             name = _dotted(node.func)
-            if name in _HOST_CALLS:
+            if name in _HOST_RESULT_CALLS:
                 return False
             if name.startswith(_DEVICE_PREFIXES):
                 return name.rsplit(".", 1)[-1] not in _METADATA_FUNCS
@@ -442,28 +461,31 @@ class _Linter(ast.NodeVisitor):
         if name in _HOST_CALLS:
             self._flag(node, SYNC_EXPLICIT,
                        f"{name}() is an explicit device->host transfer; "
-                       f"acknowledge with `# {PRAGMA}` if intended")
+                       f"use utils.runtime_stats.host_get(x, why)",
+                       allowed=self.sync_allowed)
         elif isinstance(node.func, ast.Attribute) and not node.args:
             if node.func.attr == "item":
                 self._flag(node, SYNC_EXPLICIT,
                            ".item() blocks on a device->host copy; "
-                           f"acknowledge with `# {PRAGMA}` if intended")
+                           "use utils.runtime_stats.host_get(x, why)",
+                           allowed=self.sync_allowed)
             elif node.func.attr == "block_until_ready":
                 self._flag(node, SYNC_EXPLICIT,
                            ".block_until_ready() stalls the host; "
-                           f"acknowledge with `# {PRAGMA}` if intended")
+                           "use utils.runtime_stats.host_get(x, why)",
+                           allowed=self.sync_allowed)
         if (name in ("int", "float", "bool") and len(node.args) == 1
                 and not node.keywords and self._is_device(node.args[0])):
             self._flag(node, SYNC_CAST,
                        f"{name}() on a device value forces a blocking "
-                       f"transfer; device_get first (with the pragma) or "
-                       f"keep the value on device")
+                       f"transfer; host_get it first or keep the value "
+                       f"on device", allowed=self.sync_allowed)
         if (name in _NUMPY_CONVERTERS and node.args
                 and self._is_device(node.args[0])):
             self._flag(node, SYNC_ASARRAY,
                        f"{name}() on a device array copies to host; use "
-                       f"jnp.asarray to stay on device or device_get "
-                       f"explicitly")
+                       f"jnp.asarray to stay on device or host_get "
+                       f"explicitly", allowed=self.sync_allowed)
         if self._network_scoped and name in _NETWORK_CALLS:
             self._flag(node, SYNC_NETWORK,
                        f"{name}() is blocking network I/O in a pipeline "
@@ -540,15 +562,15 @@ class _Linter(ast.NodeVisitor):
             self._flag(node.test, SYNC_BRANCH,
                        "Python branch on a device boolean blocks until the "
                        "value is on host; use lax.cond / jnp.where, or "
-                       "device_get with the pragma")
+                       "host_get it", allowed=self.sync_allowed)
         self.generic_visit(node)
 
     def visit_While(self, node: ast.While) -> None:
         if self._is_device(node.test):
             self._flag(node.test, SYNC_BRANCH,
                        "Python loop condition on a device value blocks every "
-                       "iteration; use lax.while_loop, or device_get with "
-                       "the pragma")
+                       "iteration; use lax.while_loop, or host_get it",
+                       allowed=self.sync_allowed)
         self.generic_visit(node)
 
 

@@ -30,6 +30,7 @@ from ..common.block import (DictionaryBlock, FixedWidthBlock, RunLengthBlock,
 from ..common.page import Page
 from ..common.types import (BooleanType, DateType, DecimalType, DoubleType,
                             IntegerType, RealType, Type, VarcharType, CharType)
+from ..utils.runtime_stats import host_get
 
 
 class Column:
@@ -307,7 +308,7 @@ def batch_to_page(batch: Batch, names, types) -> Page:
     fetch = {"__mask": batch.mask}
     if combined:
         fetch.update(column_fetch())
-    host = jax.device_get(fetch)  # lint: allow-host-sync
+    host = host_get(fetch, "page_fetch")
     mask = host["__mask"]
     keep = np.flatnonzero(mask)
     if keep.size == 0:
@@ -327,12 +328,12 @@ def batch_to_page(batch: Batch, names, types) -> Page:
             bucket = _bucket_for(keep.size) \
                 or 1 << int(keep.size - 1).bit_length()
             batch = _jit_compact(batch, bucket)
-            host = jax.device_get({"__mask": batch.mask,  # lint: allow-host-sync
-                                   **column_fetch()})
+            host = host_get({"__mask": batch.mask, **column_fetch()},
+                            "page_fetch_compacted")
             mask = host["__mask"]
             keep = np.flatnonzero(mask)
         else:
-            host.update(jax.device_get(column_fetch()))  # lint: allow-host-sync
+            host.update(host_get(column_fetch(), "page_fetch_columns"))
     blocks = []
     for name, typ in zip(names, types):
         col = batch.columns[name]

@@ -53,6 +53,7 @@ import numpy as np
 from ..spi import plan as P
 from .batch import Batch, Column
 from . import operators as ops
+from ..utils.runtime_stats import host_get, jit_as
 
 # absolute cap on a direct-address table (entries), and the max ratio of
 # key span to build rows before falling back to the hash table
@@ -87,7 +88,7 @@ jax.tree_util.register_pytree_node_class(DirectTable)
 
 @lru_cache(maxsize=None)
 def _direct_builder(size: int):
-    @jax.jit
+    @jit_as("direct_table_build")
     def build(values, mask, base):
         k = jnp.where(mask, values.astype(jnp.int64) - base, size)
         k = jnp.clip(k, 0, size).astype(jnp.int32)   # size = drop slot
@@ -100,7 +101,7 @@ def _direct_builder(size: int):
     return build
 
 
-@jax.jit
+@jit_as("key_stats")
 def _key_stats(values, mask):
     """(min, max, live count) of a key column over live rows."""
     v = values.astype(jnp.int64)
@@ -109,7 +110,7 @@ def _key_stats(values, mask):
     return vmin, vmax, jnp.sum(mask)
 
 
-@jax.jit
+@jit_as("max_run")
 def _max_run(table: ops.BuildTable):
     """Largest live-key duplicate run (the join's max fanout; padding runs
     excluded)."""
@@ -126,7 +127,7 @@ def _build_has_null_key(batch: Batch, key_names: Tuple[str, ...]) -> bool:
         c = batch.columns[k]
         if c.nulls is not None:
             m = m | jnp.any(batch.mask & c.nulls)
-    return bool(jax.device_get(m))  # lint: allow-host-sync
+    return bool(host_get(m, "build_has_null_key"))
 
 
 def _drop_null_keys(batch: Batch, key_names: Tuple[str, ...]) -> Batch:
@@ -329,6 +330,15 @@ class FusedChain:
                    for_join: bool):
         return build_lookup(self.compiler, build_node, keys, for_join)
 
+    def shape_probe(self, aux, expands: Tuple[int, ...], leaf_cap: int):
+        """Abstract output of `make` for one chunk (jax.eval_shape): what
+        callers read key dtypes, dictionaries and laziness from.  A trace,
+        not a launch -- under its own name (`chain_shape_probe`) in JAX's
+        trace events, since every execution that asks pays it again."""
+        def chain_shape_probe(p, v):
+            return self.make(p, v, aux, expands, leaf_cap)
+        return jax.eval_shape(chain_shape_probe, jnp.int64(0), jnp.int64(1))
+
     def make(self, pos, valid, aux, expands: Tuple[int, ...],
              leaf_cap: int, with_counts: bool = False):
         """Apply the chain to one scan chunk.  With with_counts=True the
@@ -342,7 +352,10 @@ class FusedChain:
             mk = meta["make"] if leaf_cap == self.cap \
                 else meta["make_factory"](leaf_cap)
             self._leaf_make[leaf_cap] = mk
-        outs, live = mk(pos, valid, aux[0])
+        # one named scope per operator type and table: a `profile=true`
+        # capture maps the fused ops back to plan operators
+        with jax.named_scope("scan:" + str(meta.get("table", ""))):
+            outs, live = mk(pos, valid, aux[0])
         dicts = meta["dicts"]
         batch = Batch({n: Column(v, None, dicts.get(n))
                        for n, v in outs.items()}, live)
@@ -358,66 +371,67 @@ class FusedChain:
         ji = 0                      # join/semi ordinal; aux[0] = scan cache
         for step in self.steps:
             kind = step[0]
-            if kind == "filter":
-                batch = ops.apply_filter(batch,
-                                         low.eval(step[1], _pb(batch)))
-            elif kind == "project":
-                pb = _pb(batch)
-                batch = Batch({v.name: low.eval(e, pb)
-                               for v, e in step[1]}, batch.mask)
-            elif kind == "rename":
-                batch = Batch({o: batch.columns[i] for o, i in step[1]},
-                              batch.mask)
-            elif kind == "join":
-                if expands[ji] == 1:
-                    batch = self._apply_join(batch, step[1], aux[ji + 1],
-                                             low)
-                else:
-                    batch = self._apply_join_expand(
-                        batch, step[1], aux[ji + 1], expands[ji], low)
-                ji += 1
-            elif kind == "uid":
-                # position-keyed unique ids: chunk [pos, pos+leaf_cap)
-                # owns id range [pos*K, (pos+leaf_cap)*K) where K is the
-                # join expansion applied so far — disjoint across chunks
-                # and splits, deterministic per (chain, splits), so a
-                # deep-copied decorrelated subtree replays identical ids
-                # (same contract as the streaming operator,
-                # _compile_AssignUniqueIdNode)
-                node = step[1]
-                kprod = 1
-                for j in range(ji):
-                    kprod *= expands[j]
-                cap_here = batch.mask.shape[0]
-                leaf_c = cap_here // kprod
-                base = self.compiler.ctx.task_index << 40
-                # id keyed by (global leaf row, expansion branch): the
-                # join-expand layout is slot = j*C + i, so slot s maps to
-                # leaf row s % leaf_c and branch s // leaf_c — unique even
-                # when a truncated chunk's live rows land in high branches
-                s = jnp.arange(cap_here, dtype=jnp.int64)
-                ids = (base
-                       + (jnp.asarray(pos, dtype=jnp.int64) + s % leaf_c)
-                       * kprod + s // leaf_c)
-                batch = batch.with_columns(
-                    {node.id_variable.name: Column(ids)})
-            elif kind == "semi":
-                node = step[1]
-                key = node.source_join_variable.name
-                tbl, bhn = aux[ji + 1]
-                hit, _ = (probe_direct(batch, tbl, key)
-                          if isinstance(tbl, DirectTable)
-                          else probe_unique(batch, tbl, (key,)))
-                # three-valued marker: NULL probe key, or miss against a
-                # build side that contained NULL (reference
-                # HashSemiJoinOperator semantics)
-                nulls = ~hit & bhn
-                pn = batch.columns[key].nulls
-                if pn is not None:
-                    nulls = nulls | pn
-                batch = batch.with_columns(
-                    {node.semi_join_output.name: Column(hit, nulls)})
-                ji += 1
+            with jax.named_scope(kind):
+                if kind == "filter":
+                    batch = ops.apply_filter(batch,
+                                             low.eval(step[1], _pb(batch)))
+                elif kind == "project":
+                    pb = _pb(batch)
+                    batch = Batch({v.name: low.eval(e, pb)
+                                   for v, e in step[1]}, batch.mask)
+                elif kind == "rename":
+                    batch = Batch({o: batch.columns[i] for o, i in step[1]},
+                                  batch.mask)
+                elif kind == "join":
+                    if expands[ji] == 1:
+                        batch = self._apply_join(batch, step[1], aux[ji + 1],
+                                                 low)
+                    else:
+                        batch = self._apply_join_expand(
+                            batch, step[1], aux[ji + 1], expands[ji], low)
+                    ji += 1
+                elif kind == "uid":
+                    # position-keyed unique ids: chunk [pos, pos+leaf_cap)
+                    # owns id range [pos*K, (pos+leaf_cap)*K) where K is the
+                    # join expansion applied so far — disjoint across chunks
+                    # and splits, deterministic per (chain, splits), so a
+                    # deep-copied decorrelated subtree replays identical ids
+                    # (same contract as the streaming operator,
+                    # _compile_AssignUniqueIdNode)
+                    node = step[1]
+                    kprod = 1
+                    for j in range(ji):
+                        kprod *= expands[j]
+                    cap_here = batch.mask.shape[0]
+                    leaf_c = cap_here // kprod
+                    base = self.compiler.ctx.task_index << 40
+                    # id keyed by (global leaf row, expansion branch): the
+                    # join-expand layout is slot = j*C + i, so slot s maps to
+                    # leaf row s % leaf_c and branch s // leaf_c — unique even
+                    # when a truncated chunk's live rows land in high branches
+                    s = jnp.arange(cap_here, dtype=jnp.int64)
+                    ids = (base
+                           + (jnp.asarray(pos, dtype=jnp.int64) + s % leaf_c)
+                           * kprod + s // leaf_c)
+                    batch = batch.with_columns(
+                        {node.id_variable.name: Column(ids)})
+                elif kind == "semi":
+                    node = step[1]
+                    key = node.source_join_variable.name
+                    tbl, bhn = aux[ji + 1]
+                    hit, _ = (probe_direct(batch, tbl, key)
+                              if isinstance(tbl, DirectTable)
+                              else probe_unique(batch, tbl, (key,)))
+                    # three-valued marker: NULL probe key, or miss against a
+                    # build side that contained NULL (reference
+                    # HashSemiJoinOperator semantics)
+                    nulls = ~hit & bhn
+                    pn = batch.columns[key].nulls
+                    if pn is not None:
+                        nulls = nulls | pn
+                    batch = batch.with_columns(
+                        {node.semi_join_output.name: Column(hit, nulls)})
+                    ji += 1
             if with_counts:
                 counts.append(jnp.sum(batch.mask))
         if with_counts:
@@ -523,7 +537,8 @@ def try_direct_table(batch: Batch, key: str,
     col = batch.columns[key]
     if col.values.dtype not in (jnp.int64, jnp.int32, jnp.int16):
         return None
-    vmin, vmax, live = jax.device_get(_key_stats(col.values, batch.mask))  # lint: allow-host-sync
+    vmin, vmax, live = host_get(_key_stats(col.values, batch.mask),
+                                "build_key_stats")
     span = int(vmax) - int(vmin) + 1
     if not (int(live) > 0 and span <= DIRECT_TABLE_MAX
             and span <= max(1024, DIRECT_TABLE_SPAN_RATIO * int(live))):
@@ -531,7 +546,7 @@ def try_direct_table(batch: Batch, key: str,
     size = 1 << (span - 1).bit_length()
     slots, dup = _direct_builder(size)(col.values, batch.mask,
                                        jnp.int64(int(vmin)))
-    if not allow_dup and bool(jax.device_get(dup)):  # lint: allow-host-sync
+    if not allow_dup and bool(host_get(dup, "build_dup_keys")):
         return None
     return DirectTable(slots, jnp.int64(int(vmin)), dict(batch.columns))
 
@@ -558,7 +573,7 @@ def build_lookup(compiler, build_node: P.PlanNode, keys: Tuple[str, ...],
     table = _jits()[1](batch, keys)
     if not for_join:
         return table, 1, had_null
-    kmax = int(jax.device_get(_max_run(table)))  # lint: allow-host-sync
+    kmax = int(host_get(_max_run(table), "build_max_run"))
     if kmax <= 1:
         return table, 1, False
     if kmax > MAX_EXPAND:
@@ -677,8 +692,7 @@ def fused_materialize(compiler, node: P.PlanNode,
     chunks = chain.chunks_for(expands, meter=True)
     S = len(chunks)
     try:
-        jax.eval_shape(lambda p, v: chain.make(p, v, aux, expands, leaf_cap),
-                       jnp.int64(0), jnp.int64(1))
+        chain.shape_probe(aux, expands, leaf_cap)
     except NotImplementedError:
         return None
     pos_arr = jnp.asarray([c[0] for c in chunks], dtype=jnp.int64)
@@ -686,7 +700,7 @@ def fused_materialize(compiler, node: P.PlanNode,
     key = ("fmat", node.id, expands)
     run_all = compiler._jit_cache.get(key)
     if run_all is None:
-        @jax.jit
+        @jit_as("chain_materialize")
         def run_all(pos_arr, cnt_arr, aux):
             def step(pc):
                 return chain.make(pc[0], pc[1], aux, expands, leaf_cap)
@@ -726,7 +740,7 @@ def chain_counts_fn(chain: "FusedChain", expands: Tuple[int, ...],
     counters in its loop state (sort-agg stacking, runtime-span)."""
     fn = cache.get(cache_key)
     if fn is None:
-        @jax.jit
+        @jit_as("chain_counts")
         def fn(pos_arr, cnt_arr, aux):
             def body(i, acc):
                 _b, c = chain.make(pos_arr[i], cnt_arr[i], aux, expands,
@@ -749,7 +763,7 @@ def record_chain_stats(stats, chain: "FusedChain", counts, n_chunks: int,
     _instrument wrapper (fused_stream yields through it)."""
     if stats is None or counts is None:
         return
-    vals = [int(v) for v in jax.device_get(counts)]  # lint: allow-host-sync
+    vals = [int(v) for v in host_get(counts, "chain_counts")]
     root = chain.node_ids[-1] if chain.node_ids else None
     for nid, rows in zip(chain.node_ids, vals):
         if nid is None:
@@ -819,14 +833,12 @@ def fused_stream(compiler, node: P.PlanNode):
         leaf_cap = chain.leaf_cap(expands)
         chunks = chain.chunks_for(expands)
         try:
-            jax.eval_shape(
-                lambda p, v: chain.make(p, v, aux, expands, leaf_cap),
-                jnp.int64(0), jnp.int64(1))
+            chain.shape_probe(aux, expands, leaf_cap)
         except NotImplementedError:
             compiler._jit_cache[key] = None
             return None
 
-        @jax.jit
+        @jit_as("chain_stream_step")
         def step(pos, valid, aux):
             # under EXPLAIN ANALYZE the per-step row counters ride the
             # same jitted program as extra outputs (zero host syncs)

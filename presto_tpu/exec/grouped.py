@@ -42,6 +42,7 @@ from ..spi import plan as P
 from ..spi.expr import VariableReferenceExpression
 from . import operators as ops
 from .batch import Batch
+from ..utils.runtime_stats import host_get, jit_as
 
 # keyspace span above which auto mode engages, and the per-bucket span it
 # targets (accumulator footprint and build-table size scale with the span)
@@ -219,7 +220,7 @@ class GroupedRunner:
             key_names, specs = self.key_names, self.specs
             agg_exprs = self.agg_exprs_fn
 
-            @jax.jit
+            @jit_as("grouped_sort_agg")
             def prog(pos_arr, cnt_arr, aux):
                 def step(pc):
                     b = chain.make(pc[0], pc[1], aux, expands, leaf_cap)
@@ -243,7 +244,8 @@ class GroupedRunner:
 
     @staticmethod
     def _check_dups(dup_flags) -> None:
-        if dup_flags and any(bool(d) for d in jax.device_get(dup_flags)):  # lint: allow-host-sync
+        if dup_flags and any(bool(d) for d in host_get(
+                dup_flags, "grouped_build_dups")):
             # a bucketed build's key multiplicity exceeds what the shared
             # program reserved for this bucket (duplicates against a
             # direct table, or a run longer than the fanout-k expansion):
@@ -462,7 +464,8 @@ def make_grouped_runner(compiler, node, chain, key_names, specs,
             continue
         b0 = _drop_null_keys(b0, (bkey,))
         from .pipeline import _jits
-        kmax = int(jax.device_get(_max_run(_jits()[1](b0, (bkey,)))))  # lint: allow-host-sync
+        kmax = int(host_get(_max_run(_jits()[1](b0, (bkey,))),
+                            "build_max_run"))
         if kmax > MAX_EXPAND:
             continue                    # too wide to reserve: replicate
         fanouts[si] = 1 if kmax <= 1 else 1 << (kmax - 1).bit_length()
@@ -495,13 +498,12 @@ def make_grouped_runner(compiler, node, chain, key_names, specs,
         aux0, dups0 = runner._bucket_aux(layout[0])
     except NotImplementedError:
         return None
-    if dups0 and any(bool(d) for d in jax.device_get(dups0)):  # lint: allow-host-sync
+    if dups0 and any(bool(d) for d in host_get(dups0,
+                                               "grouped_build_dups")):
         return None     # non-unique bucketed build key: single lifespan
     runner._aux0 = (aux0, dups0)
     try:
-        probe = jax.eval_shape(
-            lambda p, v: chain.make(p, v, aux0, expands, runner.leaf_cap),
-            jnp.int64(0), jnp.int64(1))
+        probe = chain.shape_probe(aux0, expands, runner.leaf_cap)
     except NotImplementedError:
         return None
     key_dtypes, key_dicts = {}, {}

@@ -48,6 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import operators as ops
 from ..batch import Batch, Column
 from . import shim
+from ...utils.runtime_stats import host_get, jit_as
 from .scan_kernel import (GROUPED_SUBTILE_ROWS, KERNEL_HASH_MAX_SLOTS,
                           _chunk_block, _whole_1d, _whole_2d,
                           agg_compaction_entries, aligned_grid,
@@ -91,7 +92,7 @@ def build_hash_runner(chain, kinds: Dict[str, str], n_params: int, *,
     # +-int64 extrema), so the kernel recreates the template in its
     # step-0 output init from host scalar fills -- pallas_call rejects
     # device arrays captured as tracing constants
-    t_host = jax.device_get(template)  # lint: allow-host-sync
+    t_host = host_get(template, "kernel_state_template")
     fills = {name: np.asarray(v).flat[0] for name, v in t_host.items()}
     entry_dtypes = {name: np.asarray(v).dtype for name, v in t_host.items()}
 
@@ -175,7 +176,7 @@ def build_hash_runner(chain, kinds: Dict[str, str], n_params: int, *,
         counts_ref[...] = counts_ref[...] + jnp.stack(counts).astype(
             jnp.int64)[None, :]
 
-    @jax.jit
+    @jit_as("pallas_grouped_agg")
     def run(bidx, lo, hi, arrays, jarrays, params):
         flat = list(arrays)
         in_specs = encoded_in_specs(names, kinds, flat, br, staged)
@@ -372,7 +373,8 @@ def try_grouped_scan_kernel(chain, aux, *, specs, key_names, key_dtypes,
             state = {}
             for name, v in zip(entry_names, outs[:-1]):
                 state[name] = v[0] if name == "__collision" else v
-            if not bool(jax.device_get(state["__collision"])):  # lint: allow-host-sync
+            if not bool(host_get(state["__collision"],
+                                 "agg_hash_collision")):
                 out = ops.agg_finalize(state, specs, key_names,
                                        key_dicts, key_lazy)
                 meter_kernel_run(runtime_stats, len(grid), n_staged, dma)

@@ -72,6 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import operators as ops
 from ..batch import Batch, Column
 from . import shim
+from ...utils.runtime_stats import jit_as
 
 # Eligibility refusals, surfaced as kernelDeclined{reason} RuntimeStats
 # counters (exec/pipeline.py _kernel_declined) -- the kernel twin of the
@@ -742,7 +743,7 @@ def build_direct_runner(chain, kinds: Dict[str, str], n_params: int, *,
         counts_ref[...] = counts_ref[...] + jnp.stack(counts).astype(
             jnp.int64)[None, :]
 
-    @jax.jit
+    @jit_as("pallas_scan_agg")
     def run(bidx, lo, hi, arrays, jarrays, params, init_i_arg,
             init_f_arg):
         flat = list(arrays)

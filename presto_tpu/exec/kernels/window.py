@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from .. import operators as ops
 from ..batch import Batch, Column
 from . import shim
+from ...utils.runtime_stats import jit_as
 from .scan_kernel import KERNEL_METRICS
 
 # the whole sorted run (mask + key/arg columns + per-spec outputs) is
@@ -204,7 +205,7 @@ def _build_runner(partition_names, orderings, specs, layout, N):
                  + [jax.ShapeDtypeStruct((N,), bool)
                     for _ in range(n_specs)])
 
-    @jax.jit
+    @jit_as("pallas_window")
     def launch(flat):
         return shim.pallas_call(kernel, out_shape=out_shape)(*flat)
 

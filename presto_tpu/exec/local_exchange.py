@@ -22,6 +22,7 @@ over it.
 """
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -30,6 +31,8 @@ from typing import Callable, Iterator, List, Optional
 import jax.numpy as jnp
 
 from . import operators as ops
+from ..utils.runtime_stats import current_span, current_stats
+from ..utils.stack import roomy
 
 
 class LocalExchange:
@@ -130,6 +133,14 @@ class LocalExchange:
                     break
 
 
+def _owned(owner, parent_span: str):
+    """The caller's RuntimeStats as owner of a helper thread's work (a
+    no-op scope when the caller had none)."""
+    if owner is None:
+        return contextlib.nullcontext()
+    return owner.activate(parent_span=parent_span)
+
+
 def background_drain(it: Iterator, wall_out: Optional[list] = None,
                      capacity: int = 4):
     """Drain `it` on a background thread, yielding items as they arrive —
@@ -140,13 +151,17 @@ def background_drain(it: Iterator, wall_out: Optional[list] = None,
     stops and unblocks the producer."""
     ex = LocalExchange(1, "ROUND_ROBIN", capacity=capacity)
     ex.add_producer()
+    # the producer works for whoever asked: launches, host syncs and JAX
+    # events on its thread belong to the caller's RuntimeStats
+    owner, parent_span = current_stats(), current_span()
 
     def producer():
         t0 = time.perf_counter()  # lint: allow-wall-clock
         try:
-            for item in it:
-                if not ex.push(item):
-                    return
+            with _owned(owner, parent_span):
+                for item in it:
+                    if not ex.push(item):
+                        return
         except BaseException as e:     # relayed to the consumer
             ex.push(e)
         finally:
@@ -154,7 +169,7 @@ def background_drain(it: Iterator, wall_out: Optional[list] = None,
                 wall_out[0] = time.perf_counter() - t0  # lint: allow-wall-clock
             ex.producer_finished()
 
-    threading.Thread(target=producer, daemon=True,
+    threading.Thread(target=roomy, args=(producer,), daemon=True,
                      name="local-exchange-drain").start()
 
     def gen():
@@ -180,6 +195,7 @@ def parallel_drain(sources: List[Callable[[], Iterator]],
             yield from thunk()
         return
     n_threads = min(concurrency, len(sources))
+    owner, parent_span = current_stats(), current_span()
     ex = LocalExchange(1, "ROUND_ROBIN", capacity=concurrency * 2)
     walls = [0.0] * len(sources)
     idx_q: "queue.Queue" = queue.Queue()
@@ -208,13 +224,14 @@ def parallel_drain(sources: List[Callable[[], Iterator]],
 
     def run_driver():
         try:
-            driver()
+            with _owned(owner, parent_span):
+                driver()
         finally:
             ex.producer_finished()
 
     threads = []
     for _ in range(n_threads):
-        t = threading.Thread(target=run_driver, daemon=True,
+        t = threading.Thread(target=roomy, args=(run_driver,), daemon=True,
                              name="local-exchange-driver")
         threads.append(t)
         t.start()

@@ -23,7 +23,6 @@ import itertools
 import json
 import threading
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -44,6 +43,7 @@ from .lowering import Lowering, canonical_name, expr_has_params
 from .memory import (MemoryContext, MemoryExceededError, MemoryPool,
                      PartitionedSpillStore, QueryMemoryLimitExceededError,
                      batch_bytes)
+from ..utils.runtime_stats import host_get, jit_as, named_jit
 
 DEFAULT_CAPACITY = 1 << 20
 # ceiling on the materialized (keys + agg inputs) bytes for sort-based
@@ -53,8 +53,9 @@ SORT_AGG_MAX_BYTES = 6 << 30
 # module-level jitted singletons: compiled once per process/shape, reused by
 # every query (the compile-once/execute-many property that makes repeated
 # queries cheap — the analog of the reference's reusable DriverFactories)
-_jit_concat = jax.jit(lambda batches: _concat_batches(batches))
-_jit_compact = jax.jit(ops.compact, static_argnums=1)
+_jit_concat = named_jit("concat_batches",
+                        lambda batches: _concat_batches(batches))
+_jit_compact = named_jit("compact", ops.compact, static_argnums=1)
 
 
 def _compact_concat(batches: List[Batch]) -> Batch:
@@ -71,8 +72,8 @@ def _compact_concat(batches: List[Batch]) -> Batch:
     if len(batches) == 1:
         return batches[0]
     total_cap = sum(b.capacity for b in batches)
-    counts = [int(c) for c in jax.device_get(  # lint: allow-host-sync
-        [b.mask.sum() for b in batches])]
+    counts = [int(c) for c in host_get(
+        [b.mask.sum() for b in batches], "compact_concat_live")]
     if sum(counts) * 4 >= total_cap:
         return _jit_concat(batches)
     out = []
@@ -102,7 +103,7 @@ def _maybe_compact(batch: Batch) -> Batch:
     """Compact a single mostly-dead batch (e.g. a sparse aggregation table)
     to a bucketed capacity so downstream sorts/joins/probes don't pay
     full-capacity costs.  One host sync for the live count."""
-    live = int(jax.device_get(batch.mask.sum()))  # lint: allow-host-sync
+    live = int(host_get(batch.mask.sum(), "maybe_compact_live"))
     if live * 4 >= batch.capacity:
         return batch
     bucket = _bucket_for(live)
@@ -119,9 +120,12 @@ _jit_window = None
 def _jits():
     global _jit_sort, _jit_build, _jit_window
     if _jit_sort is None:
-        _jit_sort = jax.jit(ops.sort_batch, static_argnums=1)
-        _jit_build = jax.jit(ops.build_table, static_argnums=(1,))
-        _jit_window = jax.jit(ops.window_batch, static_argnums=(1, 2, 3))
+        _jit_sort = named_jit("sort_batch", ops.sort_batch,
+                              static_argnums=1)
+        _jit_build = named_jit("build_table", ops.build_table,
+                               static_argnums=(1,))
+        _jit_window = named_jit("window_batch", ops.window_batch,
+                                static_argnums=(1, 2, 3))
     return _jit_sort, _jit_build, _jit_window
 
 
@@ -428,6 +432,10 @@ class TaskContext:
     memory: Optional[MemoryPool] = None
     # EXPLAIN ANALYZE: node id -> {rows, wall_s, batches} (None = disabled)
     stats: Optional[Dict[str, dict]] = None
+    # node id -> [first, last] unix seconds at which _instrument saw the
+    # node hand a batch up: the real interval of its exported operator
+    # span (kept beside `stats`, whose keys QueryInfo serves as they are)
+    operator_times: Dict[str, List[float]] = field(default_factory=dict)
     # lifespan sharding (exec/grouped.py stage_shards_lifespans): when set
     # to (shard_index, shard_count), this task owns bucket lifespans
     # shard_index, shard_index+shard_count, ... of the grouped layout;
@@ -637,12 +645,14 @@ class PlanCompiler:
         ONE traced program per (node id, purpose) key instead of each
         re-tracing an identical closure (TaskContext.shared_jits).  Falls
         back to a plain jit when no stage cache is installed."""
+        # key = (node id, purpose, ...): the purpose alone names the
+        # program (structural: the node id never enters a program name)
         cache = self.ctx.shared_jits
         if cache is None:
-            return jax.jit(fn, **kw)
+            return named_jit(key[1], fn, **kw)
         ent = cache.get(key)
         if ent is None:
-            ent = cache.setdefault(key, jax.jit(fn, **kw))
+            ent = cache.setdefault(key, named_jit(key[1], fn, **kw))
         return ent
 
     def fragment_jit(self, node, purpose: str, fn, extra=(), **kw):
@@ -666,7 +676,7 @@ class PlanCompiler:
         key = (purpose, P.structural_key(node), tuple(extra),
                config_fingerprint(cfg))
         return FRAGMENT_JIT_CACHE.get_or_build(
-            key, lambda: jax.jit(fn, **kw))
+            key, lambda: named_jit(purpose, fn, **kw))
 
     def _new_spill_store(self, salt: Optional[int] = None
                          ) -> PartitionedSpillStore:
@@ -685,14 +695,22 @@ class PlanCompiler:
     def compile(self, root: P.PlanNode) -> BatchSource:
         return self._compile(root)
 
-    def run_to_pages(self, root: P.PlanNode) -> Iterator[Page]:
+    def compile_root(self, root: P.PlanNode) -> BatchSource:
+        """`compile` for one execution of the whole fragment: the host's
+        share of a run before the first batch is pulled (the span
+        `pipelineBuild` of the task and the runners)."""
         for st in self._shared_states:
             st.update(buf=[], it=None, done=False)
-        src = self.compile(root)
+        return self.compile(root)
+
+    def source_to_pages(self, src: BatchSource) -> Iterator[Page]:
         for batch in src.batches():
             page = batch_to_page(batch, src.names, src.types)
             if page.position_count:
                 yield page
+
+    def run_to_pages(self, root: P.PlanNode) -> Iterator[Page]:
+        yield from self.source_to_pages(self.compile_root(root))
 
     def run_to_batches(self, root: P.PlanNode) -> Iterator[Batch]:
         """Device-resident drain of the fragment (the ICI exchange path:
@@ -761,6 +779,7 @@ class PlanCompiler:
         children, like the reference's operator getOutput accounting),
         output row counts, and estimated output bytes per plan node."""
         stats = self.ctx.stats
+        times = self.ctx.operator_times
         # 8 value bytes + 1 null byte per column: an ESTIMATE (dictionary
         # and lazy columns are cheaper on device), stable across paths so
         # fused/unfused byte counts compare
@@ -772,6 +791,10 @@ class PlanCompiler:
                 node.id, {"rows": 0, "wall_s": 0.0, "batches": 0})
             ent.setdefault("bytes", 0)
             ent.setdefault("operatorType", type(node).__name__)
+            # first pull .. last batch handed up: the operator span's
+            # real interval
+            now = time.time()  # lint: allow-wall-clock
+            span = times.setdefault(node.id, [now, now])
             it = src.batches()
             while True:
                 t0 = time.perf_counter()  # lint: allow-wall-clock
@@ -781,7 +804,8 @@ class PlanCompiler:
                     ent["wall_s"] += time.perf_counter() - t0  # lint: allow-wall-clock
                     return
                 ent["wall_s"] += time.perf_counter() - t0  # lint: allow-wall-clock
-                rows = int(b.mask.sum())
+                span[1] = time.time()  # lint: allow-wall-clock
+                rows = int(host_get(b.mask.sum(), "operator_stats_rows"))
                 ent["rows"] += rows
                 ent["bytes"] += rows * row_bytes
                 ent["batches"] += 1
@@ -991,8 +1015,8 @@ class PlanCompiler:
                                     else rows_out + kept)
                 yield b
             if engaged and rows_in is not None:
-                inn, out = jax.device_get(  # lint: allow-host-sync
-                    (rows_in, rows_out))
+                inn, out = host_get((rows_in, rows_out),
+                                    "runtime_filter_rows")
                 from .adaptive import ADAPTIVE_METRICS
                 ADAPTIVE_METRICS.incr("filter_rows_in", int(inn))
                 ADAPTIVE_METRICS.incr("filter_rows_pruned",
@@ -1947,9 +1971,7 @@ class PlanCompiler:
             leaf_cap = chain.leaf_cap(expands)
             chunks = chain.chunks_for(expands, meter=True)
             try:
-                probe = jax.eval_shape(
-                    lambda p, v: chain.make(p, v, aux, expands, leaf_cap),
-                    jnp.int64(0), jnp.int64(1))
+                probe = chain.shape_probe(aux, expands, leaf_cap)
             except NotImplementedError:
                 _fusion_declined("ProbeUnsupported")
                 return None
@@ -1985,26 +2007,31 @@ class PlanCompiler:
                 key = key + (expands, analyzing)
                 run_all = fused_cache.get(key)
                 if run_all is None:
+                    # scan_agg_direct | _static_span | _hash, and the
+                    # EXPLAIN ANALYZE variant `<...>_counted`
+                    program = "scan_agg_" + key[0]
                     if analyzing:
-                        @jax.jit
+                        @jit_as(program + "_counted")
                         def run_all(pos_arr, cnt_arr, state, aux):
                             def body(i, carry):
                                 st, cnts = carry
                                 b, c = chain.make(
                                     pos_arr[i], cnt_arr[i], aux, expands,
                                     leaf_cap, with_counts=True)
-                                return update(st, b), cnts + c
+                                with jax.named_scope("agg"):
+                                    return update(st, b), cnts + c
                             return jax.lax.fori_loop(
                                 0, pos_arr.shape[0], body,
                                 (state, jnp.zeros(1 + len(chain.steps),
                                                   dtype=jnp.int64)))
                     else:
-                        @jax.jit
+                        @jit_as(program)
                         def run_all(pos_arr, cnt_arr, state, aux):
                             def body(i, st):
                                 b = chain.make(pos_arr[i], cnt_arr[i], aux,
                                                expands, leaf_cap)
-                                return update(st, b)
+                                with jax.named_scope("agg"):
+                                    return update(st, b)
                             # chunk count from the traced shape, NOT a
                             # closure constant: param-aware pruning may
                             # change it between executions (shape change
@@ -2149,7 +2176,7 @@ class PlanCompiler:
                 cand_names = tuple(key_names[i] for i in candidates)
                 spanp = fused_cache.get(("span_probe", cand_names, expands))
                 if spanp is None:
-                    @jax.jit
+                    @jit_as("scan_agg_span_probe")
                     def spanp(pos_arr, cnt_arr, aux):
                         def body(i, mm):
                             b = chain.make(pos_arr[i], cnt_arr[i], aux,
@@ -2177,7 +2204,8 @@ class PlanCompiler:
                 if span_key in fused_cache:
                     ranges = fused_cache[span_key]
                 else:
-                    los, his = jax.device_get(spanp(pos_arr, cnt_arr, aux))  # lint: allow-host-sync
+                    los, his = host_get(spanp(pos_arr, cnt_arr, aux),
+                                        "agg_span_probe")
                     ranges = [(int(l), int(h)) for l, h in zip(los, his)]
                     fused_cache[span_key] = ranges
                 # the anchor must be unique per group (verified below by
@@ -2219,7 +2247,7 @@ class PlanCompiler:
                         run = fused_cache.get(
                             ("span", G, kname, dep_names, expands))
                         if run is None:
-                            @jax.jit
+                            @jit_as("scan_agg_runtime_span")
                             def run(pos_arr, cnt_arr, state, aux, base):
                                 def body(i, st):
                                     b = chain.make(pos_arr[i], cnt_arr[i],
@@ -2244,7 +2272,8 @@ class PlanCompiler:
                                 **ops.depkey_init(G, dep_names)}
                         state, dep_ok = run(pos_arr, cnt_arr, init,
                                             aux, base)
-                        if dep_names and not bool(jax.device_get(dep_ok)):  # lint: allow-host-sync
+                        if dep_names and not bool(
+                                host_get(dep_ok, "agg_span_dep_ok")):
                             # a grouping key varies within an anchor
                             # group: this anchor was not unique — try the
                             # next candidate, else the sort path below
@@ -2282,7 +2311,7 @@ class PlanCompiler:
                     and pool.try_reserve(est_mat):
                 run = fused_cache.get(("sortagg", expands))
                 if run is None:
-                    @jax.jit
+                    @jit_as("scan_agg_sort")
                     def run(pos_arr, cnt_arr, aux):
                         def step(pc):
                             b = chain.make(pc[0], pc[1], aux, expands,
@@ -2341,9 +2370,11 @@ class PlanCompiler:
                     state = loop(("hash", num_slots, salt), update,
                                  ops.agg_init(num_slots, specs, key_names,
                                               key_dtypes))
-                    if not bool(jax.device_get(state["__collision"])):  # lint: allow-host-sync
-                        if not key_names \
-                                and not bool(jnp.any(state["__occupied"])):  # lint: allow-host-sync
+                    if not bool(host_get(state["__collision"],
+                                         "agg_hash_collision")):
+                        if not key_names and not bool(host_get(
+                                jnp.any(state["__occupied"]),
+                                "agg_occupied")):
                             state["__occupied"] = \
                                 state["__occupied"].at[0].set(True)
                         return _maybe_compact(ops.agg_finalize(
@@ -2405,7 +2436,7 @@ class PlanCompiler:
             key = ("sortagg_fallback", node.id)
             fn = self._jit_cache.get(key)
             if fn is None:
-                @jax.jit
+                @jit_as("agg_sort_fallback")
                 def fn(b):
                     inputs = {out: (low2.eval(e, b) if e is not None
                                     else None)
@@ -2474,7 +2505,7 @@ class PlanCompiler:
             key = ("pctsketch", node.id)
             fns = self._jit_cache.get(key)
             if fns is None:
-                @jax.jit
+                @jit_as("agg_percentile_summarize")
                 def summarize(b):
                     out = {}
                     for s in pct_specs:
@@ -2484,7 +2515,7 @@ class PlanCompiler:
                             col.values, alive, m)
                     return out
 
-                @jax.jit
+                @jit_as("agg_percentile_update_others")
                 def update_others(state, b):
                     agg_cols = {s.output: low2.eval(
                         input_exprs[s.output], b)
@@ -2507,7 +2538,8 @@ class PlanCompiler:
                 if state is not None:
                     state = update_others(state, b)
             if state is not None:
-                if not bool(jnp.any(state["__occupied"])):  # lint: allow-host-sync
+                if not bool(host_get(jnp.any(state["__occupied"]),
+                                     "agg_occupied")):
                     state["__occupied"] = \
                         state["__occupied"].at[0].set(True)
                 row = ops.agg_finalize(state, other_specs, (), {}, {})
@@ -2691,8 +2723,8 @@ class PlanCompiler:
                             state, specs, key_names, direct[0], direct[1],
                             key_dicts, force_row=not key_names)
                         return
-                    if not key_names \
-                            and not bool(jnp.any(state["__occupied"])):  # lint: allow-host-sync
+                    if not key_names and not bool(host_get(
+                            jnp.any(state["__occupied"]), "agg_occupied")):
                         # global aggregation over empty input: one row
                         state["__occupied"] = \
                             state["__occupied"].at[0].set(True)
@@ -2840,7 +2872,7 @@ class PlanCompiler:
                                      np.arange(len(uniq) + 1))
             for g in range(len(uniq)):
                 t = tuple(None if uniq[g][2 * j + 1] else
-                          uniq[g][2 * j].item()  # lint: allow-host-sync
+                          uniq[g][2 * j].tolist()
                           for j in range(len(key_names)))
                 idxs = order[bounds[g]:bounds[g + 1]]
                 ent = per_key.setdefault(
@@ -2897,7 +2929,7 @@ class PlanCompiler:
                           num_slots, salt)
                     upd = self._jit_cache.get(jk)
                     if upd is None:
-                        @jax.jit
+                        @jit_as("agg_skew_update")
                         def upd(state, b):
                             kc = [b.columns[k] for k in key_names]
                             ac = {s.output: (low.eval(
@@ -2916,7 +2948,8 @@ class PlanCompiler:
                                          tuple(key_names), key_dtypes)
                     for b in bstore.bucket_batches(p, cfg.batch_rows):
                         state = upd(state, b)
-                    if not bool(jax.device_get(state["__collision"])):  # lint: allow-host-sync
+                    if not bool(host_get(state["__collision"],
+                                         "agg_skew_collision")):
                         out_batch = ops.agg_finalize(
                             state, other_specs, tuple(key_names),
                             key_dicts, key_lazy)
@@ -3268,8 +3301,9 @@ class PlanCompiler:
                         submit(nxt)
                     if not inflight:
                         break
-                    metas = jax.device_get(  # lint: allow-host-sync
-                        [(ov, tot) for _p, _j, ov, tot in inflight])
+                    metas = host_get(
+                        [(ov, tot) for _p, _j, ov, tot in inflight],
+                        "join_overflow")
                     window = list(inflight)
                     inflight.clear()
                     for (piece, joined, _o, _t), (ovv, livev) in zip(
@@ -3318,8 +3352,8 @@ class PlanCompiler:
                         # shared join program per task — normalize to a
                         # power-of-two bucket so the stage converges on
                         # one build shape (costs one live-count sync)
-                        live = int(jax.device_get(  # lint: allow-host-sync
-                            build_batch.mask.sum()))
+                        live = int(host_get(build_batch.mask.sum(),
+                                            "join_build_live"))
                         bucket = _bucket_for(live) \
                             or 1 << max(0, live - 1).bit_length()
                         if bucket != build_batch.capacity:
@@ -3419,13 +3453,14 @@ class PlanCompiler:
         key = node.source_join_variable.name
         fkey = node.filtering_source_join_variable.name
 
-        @partial(jax.jit, static_argnames=("build_has_null",))
+        @jit_as("semi_join_step", static_argnames=("build_has_null",))
         def step(batch, table, build_has_null):
             marker = ops.semi_join_mark(batch, table, [key],
                                         build_has_null=build_has_null)
             return batch.with_columns({node.semi_join_output.name: marker})
 
-        @partial(jax.jit, static_argnames=("build_has_null",))
+        @jit_as("semi_join_step_direct",
+                static_argnames=("build_has_null",))
         def step_direct(batch, dt, build_has_null):
             marker = ops.semi_join_mark_direct(
                 batch, dt, key, build_has_null=build_has_null)
@@ -3973,7 +4008,8 @@ def _apply_dyn_filter(batches, dyn_filter, stats_ent):
             continue
         nb = dyn_filter(b)
         if stats_ent is not None:
-            before, after = jax.device_get((b.mask.sum(), nb.mask.sum()))  # lint: allow-host-sync
+            before, after = host_get((b.mask.sum(), nb.mask.sum()),
+                                     "dynamic_filter_rows")
             stats_ent["dynamicFilterRowsDropped"] += int(before) - int(after)
         yield nb
 

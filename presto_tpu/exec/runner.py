@@ -124,7 +124,6 @@ class LocalQueryRunner:
         is available, every compiled XLA step); a miss optimizes and
         builds a compiler.  Either way the returned compiler's context
         carries the execution's bound-parameter vector."""
-        from ..serving import SERVING_METRICS
         from ..sql.canonical import cache_key_from_parts, parameterize
         from ..spi import plan as P
         with stats.record_wall("queryPlan"), self._validation():
@@ -151,15 +150,13 @@ class LocalQueryRunner:
                 # pooled compilers all checked out by concurrent
                 # executions: rebuild one from the cached template —
                 # parse/plan/optimize were still skipped
-                compiler = PlanCompiler(TaskContext(config=cfg))
-                SERVING_METRICS.incr("executable_builds")
+                compiler = self._new_compiler(cfg, stats)
             exe = _Execution(output, compiler, key, False,
                              list(slot_types))
         else:
             with stats.record_wall("queryOptimize"), self._validation():
                 output = Planner.optimize_output(pp.template)
-            compiler = PlanCompiler(TaskContext(config=cfg))
-            SERVING_METRICS.incr("executable_builds")
+            compiler = self._new_compiler(cfg, stats)
             exe = _Execution(output, compiler, key, True,
                              [s.type for s in pp.slots])
         if record_fast is not None and pp.origins_complete:
@@ -171,6 +168,16 @@ class LocalQueryRunner:
                  for s in pp.slots]))
         self._bind(exe, [s.value for s in pp.slots])
         return exe
+
+    @staticmethod
+    def _new_compiler(cfg, stats) -> PlanCompiler:
+        """A compiler for a miss or an exhausted pool: host Python only,
+        part of the query's `pipelineBuild`."""
+        from ..serving import SERVING_METRICS
+        with stats.span("pipelineBuild"):
+            compiler = PlanCompiler(TaskContext(config=cfg))
+        SERVING_METRICS.incr("executable_builds")
+        return compiler
 
     def _bind(self, exe: _Execution, values) -> None:
         from ..sql.canonical import device_params
@@ -290,9 +297,7 @@ class LocalQueryRunner:
                 if hit is not None:
                     output, slot_types, compiler = hit
                     if compiler is None:
-                        compiler = PlanCompiler(
-                            TaskContext(config=self.config))
-                        SERVING_METRICS.incr("executable_builds")
+                        compiler = self._new_compiler(self.config, stats)
                     exe = _Execution(output, compiler, key, False,
                                      list(slot_types))
                     self._bind(exe, values)
@@ -368,27 +373,37 @@ class LocalQueryRunner:
         if hit is None:
             return None
         output, slot_types, compiler = hit
+        # the launch serves every lane: it records into a RuntimeStats of
+        # its own (not the leader's, whose thread this is), and each
+        # lane's result carries that whole map -- what the launch that
+        # served it did -- beside `servingBatchOccupancy` to divide by
+        from ..utils.runtime_stats import RuntimeStats
+        batch_stats = RuntimeStats()
         if compiler is None:
-            compiler = PlanCompiler(TaskContext(config=self.config))
-            SERVING_METRICS.incr("executable_builds")
+            compiler = self._new_compiler(self.config, batch_stats)
         exe = _Execution(output, compiler, key, False, list(slot_types))
         if not exe.slot_types:
             self.plan_cache.checkin(key, compiler)
             return None
         self._bind(exe, values_by_lane[lanes[0]])
-        runner = batched_runner_for(compiler, output)
-        if runner is None:
-            self.plan_cache.checkin(key, compiler)
-            return None
-        dev_list = [device_params(values_by_lane[i], exe.slot_types)[0]
-                    for i in lanes]
-        try:
-            pages, launch_ns, demux_ns = runner.run(dev_list)
-        except Exception:   # noqa: BLE001 — whole drain failed: the
-            # compiler may be poisoned (not returned to the pool) and the
-            # template is pinned sequential; every lane re-runs solo
-            disable_for(compiler)
-            return None
+        with batch_stats.activate():
+            runner = batched_runner_for(compiler, output)
+            if runner is None:
+                self.plan_cache.checkin(key, compiler)
+                return None
+            dev_list = [device_params(values_by_lane[i],
+                                      exe.slot_types)[0] for i in lanes]
+            try:
+                pages, launch_ns, demux_ns = runner.run(dev_list)
+            except Exception:   # noqa: BLE001 — whole drain failed: the
+                # compiler may be poisoned (not returned to the pool) and
+                # the template is pinned sequential; every lane re-runs
+                # solo
+                disable_for(compiler)
+                return None
+        batch_stats.add("servingBatchOccupancy", len(lanes))
+        batch_stats.add("servingBatchLaunchNanos", launch_ns, "NANO")
+        lane_stats = batch_stats.to_dict()
         self._last_template_digest = plan_template_digest(
             fast.template_key)
         names = output.column_names
@@ -400,11 +415,7 @@ class LocalQueryRunner:
             res.peak_memory_bytes = (compiler.ctx.memory.peak
                                      if compiler.ctx.memory is not None
                                      else 0)
-            res.runtime_stats = {
-                "servingBatchOccupancy": {"sum": len(lanes), "unit": "NONE"},
-                "servingBatchLaunchNanos": {"sum": launch_ns,
-                                            "unit": "NANO"},
-            }
+            res.runtime_stats = dict(lane_stats)
             results[i] = res
             SERVING_METRICS.incr("prepared_fast_path")
             self._record_history(res, output)
@@ -417,17 +428,29 @@ class LocalQueryRunner:
 
     def execute(self, sql: str, prepared: Optional[Dict[str, str]] = None
                 ) -> QueryResult:
+        from contextlib import ExitStack
+
+        from ..utils.runtime_stats import RuntimeStats, current_stats
+        tracer = self.tracer_provider.new_tracer(sql) \
+            if self.tracer_provider else None
+        # the statement executor's stats when it set one (the query's
+        # RuntimeStats in QueryInfo), else this execution's own
+        stats = current_stats() or RuntimeStats(tracer=tracer, root="query")
+        with ExitStack() as stack:
+            root = stack.enter_context(tracer.span("query", sql=sql)) \
+                if tracer else None
+            stack.enter_context(stats.activate(parent_span=root))
+            return self._execute_owned(sql, prepared, stats)
+
+    def _execute_owned(self, sql: str, prepared, stats) -> QueryResult:
+        """`execute` under its RuntimeStats, which owns the thread: the
+        pipeline's launches and host syncs and JAX's events record into
+        it beside the phases below."""
         from ..common.types import BOOLEAN
         from ..serving import PREPARED_REGISTRY
         from ..sql import parser as A
-        from ..utils.runtime_stats import RuntimeStats
-        stats = RuntimeStats()
-        tracer = self.tracer_provider.new_tracer(sql) \
-            if self.tracer_provider else None
-        with stats.record_wall("queryParse"):
+        with stats.span("queryParse"):
             ast = A.parse_sql(sql)
-        if tracer:
-            tracer.add_point("query parsed")
         if isinstance(ast, A.Explain):
             return self._explain(ast)
         if isinstance(ast, (A.CreateTableAs, A.InsertInto, A.DropTable)):
@@ -447,30 +470,25 @@ class LocalQueryRunner:
             exe = self._execute_prepared(ast, stats, prepared)
         else:
             exe = self._checkout(ast, stats)
-        if tracer:
-            tracer.add_point("query planned")
         output, compiler = exe.output, exe.compiler
         names = output.column_names
         types = [v.type for v in output.outputs]
         # operators add fine-grained counters (grouped bucket walls, ...)
         compiler.ctx.runtime_stats = stats
-        from contextlib import nullcontext
-
         from ..telemetry import profile_capture
-        with (tracer.span("query", sql=sql) if tracer else nullcontext()):
-            with profile_capture(self.config.profile_dir, "query",
-                                 enabled=self.config.profile) as trace_dir:
-                with stats.record_wall("queryExecute"):
-                    result = pages_to_result(
-                        compiler.run_to_pages(output), names, types)
+        with profile_capture(self.config.profile_dir, "query",
+                             enabled=self.config.profile) as trace_dir:
+            with stats.span("queryExecute"):
+                with stats.span("pipelineBuild"):
+                    src = compiler.compile_root(output)
+                result = pages_to_result(
+                    compiler.source_to_pages(src), names, types)
         result.profile_trace_dir = trace_dir
         result.runtime_stats = stats.to_dict()
         # peak MemoryPool reservation, for QueryCompletedEvent enrichment
         result.peak_memory_bytes = (compiler.ctx.memory.peak
                                     if compiler.ctx.memory is not None
                                     else 0)
-        if tracer:
-            tracer.end_trace("query finished")
         self._release(exe)
         self._record_history(result, output)
         return result
@@ -484,9 +502,9 @@ class LocalQueryRunner:
         Returns None for statements that need materialized execution
         (DDL / EXPLAIN / PREPARE / DEALLOCATE)."""
         from ..sql import parser as A
-        from ..utils.runtime_stats import RuntimeStats
-        stats = RuntimeStats()
-        with stats.record_wall("queryParse"):
+        from ..utils.runtime_stats import RuntimeStats, current_stats
+        stats = current_stats() or RuntimeStats()
+        with stats.span("queryParse"):
             ast = A.parse_sql(sql)
         if isinstance(ast, (A.Explain, A.CreateTableAs, A.InsertInto,
                             A.DropTable, A.Prepare, A.Deallocate)):
@@ -503,9 +521,13 @@ class LocalQueryRunner:
                    for n, t in zip(names, types)]
 
         def rows():
+            # runs on the thread that pulls the rows: the statement layer
+            # makes the query's stats that thread's owner meanwhile
             from ..common.block import block_to_values
-            with stats.record_wall("queryExecute"):
-                for page in compiler.run_to_pages(output):
+            with stats.span("queryExecute"):
+                with stats.span("pipelineBuild"):
+                    src = compiler.compile_root(output)
+                for page in compiler.source_to_pages(src):
                     cols = [block_to_values(t, b)
                             for t, b in zip(types, page.blocks)]
                     for i in range(page.position_count):
@@ -597,7 +619,7 @@ class LocalQueryRunner:
             c0 = _t.thread_time()
             with profile_capture(self.config.profile_dir, "analyze",
                                  enabled=self.config.profile) as trace_dir:
-                with rstats.record_wall("queryExecute"):
+                with rstats.activate(), rstats.span("queryExecute"):
                     for _page in compiler.run_to_pages(output):
                         pass
             rstats.add("driverCpuNanos",
@@ -793,8 +815,6 @@ class DistributedQueryRunner(LocalQueryRunner):
                         as trace_dir:
                     for _page in sched.execute(subplan):
                         pass
-            if tracer:
-                tracer.end_trace("query finished")
             self.last_operator_stats = stats
             footer = format_analyze_footer(sched.stats,
                                            profile_dir=trace_dir)
@@ -841,8 +861,6 @@ class DistributedQueryRunner(LocalQueryRunner):
         # query-level context peak (all tasks' reservations bubbled up)
         result.peak_memory_bytes = (sched.memory.peak
                                     if sched.memory is not None else 0)
-        if tracer:
-            tracer.end_trace("query finished")
         self._record_history(result, subplan.fragment.root, subplan=subplan)
         return result
 

@@ -31,6 +31,7 @@ from .adaptive import (AdaptiveState, DynamicFilterCollector,
                        decide_exchange, decide_side_swap,
                        summaries_to_runtime, summarize_key_column)
 from .pipeline import ExecutionConfig, PlanCompiler, TaskContext
+from ..utils.runtime_stats import host_get
 
 
 @dataclass
@@ -710,7 +711,9 @@ class InProcessScheduler:
             out = None
             split_wall, split_bytes = 0.0, 0
             task_sums: Dict[str, object] = {}
-            with span_ctx, dev_ctx:
+            # the query's stats own this task thread: the pipeline's
+            # launches and host syncs and JAX's events record into them
+            with span_ctx, dev_ctx, self.stats.activate():
                 if ici:
                     # device path: output stays device-resident; a host
                     # summarization sync here would serialize the async
@@ -762,19 +765,21 @@ class InProcessScheduler:
                     merge_node_stats(self.node_stats, ctx.stats)
             if self.tracer is not None and ctx.stats:
                 # operator spans close out the query->fragment->task->
-                # operator hierarchy; operators stream interleaved so their
-                # intervals don't nest in real time — each span is emitted
-                # at task end carrying its measured wall as an attribute
+                # operator hierarchy: one per node _instrument saw
+                # produce, over its first-pull .. last-batch interval
+                # (operators stream interleaved, so siblings overlap);
+                # the measured wall rides as an attribute
                 for nid, s in ctx.stats.items():
-                    with self.tracer.span(
-                            f"operator {frag.fragment_id}.{task_index}."
-                            f"{nid}",
-                            parent=f"task {frag.fragment_id}.{task_index}",
-                            plan_node_id=nid,
-                            operator=s.get("operatorType", ""),
-                            rows=s.get("rows", 0),
-                            wall_s=s.get("wall_s", 0.0)):
-                        pass
+                    times = ctx.operator_times.get(nid)
+                    if times is None:
+                        continue
+                    self.tracer.add_span(
+                        f"operator {frag.fragment_id}.{task_index}.{nid}",
+                        f"task {frag.fragment_id}.{task_index}",
+                        times[0], times[1], plan_node_id=nid,
+                        operator=s.get("operatorType", ""),
+                        rows=s.get("rows", 0),
+                        wall_s=s.get("wall_s", 0.0))
             if split_bytes or split_wall:
                 # stats parity with the ICI path: the hashed page path IS
                 # the http fabric in-process (its pages move host-side,
@@ -855,10 +860,15 @@ class InProcessScheduler:
                            for i in range(stage.n_tasks)]
             else:
                 from concurrent.futures import ThreadPoolExecutor
+                from functools import partial
+
+                from ..utils.stack import roomy
                 with ThreadPoolExecutor(
                         max_workers=stage.n_tasks) as pool_ex:
-                    results = list(pool_ex.map(run_task_retrying,
-                                               range(stage.n_tasks)))
+                    # each task from a roomy frame (utils/stack.py)
+                    results = list(pool_ex.map(
+                        partial(roomy, run_task_retrying),
+                        range(stage.n_tasks)))
         task_batches = [r[0] for r in results]
         stage.task_walls = [round(r[1], 4) for r in results]
         stage.stage_wall = round(
@@ -949,8 +959,8 @@ class InProcessScheduler:
         # (the _compact_concat idiom) — the only host sync on this path;
         # the old per-task device_get loop serialized n round-trips
         present = [b for b in task_batches if b is not None]
-        counts = jax.device_get(  # lint: allow-host-sync
-            [b.mask.sum() for b in present])
+        counts = host_get([b.mask.sum() for b in present],
+                          "ici_exchange_live")
         max_live = max((int(c) for c in counts), default=0)
 
         # explicit exchange.ici-chunk-rows pins the chunk size; the

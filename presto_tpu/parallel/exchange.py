@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..exec.batch import Batch, Column
 from ..exec.operators import hash_columns
+from ..utils.runtime_stats import named_jit
 from .mesh import WORKER_AXIS
 
 
@@ -124,4 +125,5 @@ def make_partitioned_exchange(mesh, key_names: Tuple[str, ...],
     spec = P(WORKER_AXIS)
     shmapped = shard_map(fn, mesh=mesh, in_specs=(spec,),
                          out_specs=(spec, P()))
-    return jax.jit(shmapped, donate_argnums=(0,) if donate else ())
+    return named_jit("ici_exchange", shmapped,
+                     donate_argnums=(0,) if donate else ())

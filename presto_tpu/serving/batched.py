@@ -39,6 +39,7 @@ from ..exec.batch import Batch, batch_to_page
 from ..exec.fused import assemble_chain
 from ..exec.lowering import canonical_name
 from ..exec.pipeline import _direct_mode_info, _rewrite_agg_masks
+from ..utils.runtime_stats import named_jit
 
 
 class BatchedTemplateRunner:
@@ -61,7 +62,7 @@ class BatchedTemplateRunner:
         self.doms, self.G, self.strides, self.kdts, self.kdicts = info
         self.projects = projects        # ProjectNodes root->down above agg
         self.low = compiler.lowering
-        self._run_jit = jax.jit(self._run_all)
+        self._run_jit = named_jit("serve_batched_scan_agg", self._run_all)
 
     # -- the single-launch program ---------------------------------------
 
@@ -215,9 +216,7 @@ def _eligible(compiler, output) -> Optional[BatchedTemplateRunner]:
     aux = aux[:-1] + (ctx.params,)
     leaf_cap = chain.leaf_cap(expands)
     try:
-        probe = jax.eval_shape(
-            lambda p, v: chain.make(p, v, aux, expands, leaf_cap),
-            jnp.int64(0), jnp.int64(1))
+        probe = chain.shape_probe(aux, expands, leaf_cap)
     except Exception:   # noqa: BLE001
         return None
     key_cols = [probe.columns.get(k) for k in key_names]

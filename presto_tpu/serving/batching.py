@@ -26,8 +26,10 @@ never taken and the fixed window is the only added latency.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable, Dict, List, Optional
 
+from ..utils.runtime_stats import current_stats
 from .metrics import SERVING_METRICS
 
 DEFAULT_BATCH_WINDOW_MS = 3.0
@@ -35,22 +37,25 @@ DEFAULT_MAX_BATCH_SIZE = 16
 
 
 class _Slot:
-    __slots__ = ("item", "result", "event", "batched")
+    __slots__ = ("item", "result", "event", "batched", "enqueued_ns")
 
     def __init__(self, item):
         self.item = item
         self.result = None
         self.event = threading.Event()
         self.batched = False    # joined a >=2-lane drain attempt
+        self.enqueued_ns = time.perf_counter_ns()
 
 
 class _Group:
-    __slots__ = ("slots", "full", "closed")
+    __slots__ = ("slots", "full", "closed", "launched_ns")
 
     def __init__(self):
         self.slots: List[_Slot] = []
         self.full = threading.Event()
         self.closed = False
+        self.launched_ns = 0    # the group closed: its launch (or its
+        # lanes' solo runs) begins
 
 
 class MicroBatcher:
@@ -111,6 +116,7 @@ class MicroBatcher:
                 if self._groups.get(key) is g:
                     del self._groups[key]
                 slots = list(g.slots)
+                g.launched_ns = time.perf_counter_ns()
                 done = None
                 if len(slots) > 1:
                     done = threading.Event()
@@ -139,6 +145,14 @@ class MicroBatcher:
             # wedge followers forever
             slot.event.wait(self.window_s + 300.0)
 
+        # enqueue -> launch, into the lane's own query (each lane waits
+        # on its own executor thread): where the batch forms, so a
+        # batcher that saves no launch still shows what its window costs
+        owner = current_stats()
+        if owner is not None and g.launched_ns:
+            owner.add("servingBatchWaitWallNanos",
+                      g.launched_ns - slot.enqueued_ns, "NANO")
+            owner.add("servingBatchOccupancy", len(g.slots))
         if slot.result is None:
             if slot.batched:
                 SERVING_METRICS.incr("serving_batch_fallbacks")

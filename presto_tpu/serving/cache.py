@@ -27,6 +27,7 @@ from collections import OrderedDict
 from typing import List, Optional, Tuple
 
 from ..common.locks import OrderedLock
+from ..utils.runtime_stats import current_stats
 from .metrics import SERVING_METRICS
 
 DEFAULT_PLAN_CACHE_ENTRIES = 128
@@ -71,8 +72,12 @@ class PlanCache:
         with self._lock:
             # lock-acquisition wall = how long concurrent executions
             # queued behind the cache (the "checkout wait" of a
-            # contended serving plane)
+            # contended serving plane): process-wide below, and into the
+            # query that waited
             wait = time.perf_counter_ns() - t0  # lint: allow-wall-clock
+            owner = current_stats()
+            if owner is not None:
+                owner.add("compilerCheckoutWaitWallNanos", wait, "NANO")
             ent = self._entries.get(key)
             if ent is None:
                 self.misses += 1

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.runtime_stats import host_get
+
 # dictionary codes wider than int16 would erase most of the byte win
 DICT_MAX_NDV = 1 << 15
 # cheap cardinality probe before paying a full-column jnp.unique sort
@@ -157,7 +159,7 @@ def encode_column(arr, n_rows: int, encodings: bool = True,
     # --- RLE: runs of equal adjacent values -----------------------------
     changes = body[1:] != body[:-1]
     # build-time stat, one sync per column per process
-    nruns = 1 + int(jax.device_get(changes.sum()))  # lint: allow-host-sync
+    nruns = 1 + int(host_get(changes.sum(), "storage_rle_runs"))
     plain_bytes = n_rows * itemsize
     rle_bytes = nruns * (itemsize + 8)
     want = RLE_HINT_COMPRESSION if hint == "rle" else RLE_MIN_COMPRESSION
@@ -179,8 +181,8 @@ def encode_column(arr, n_rows: int, encodings: bool = True,
     # the probe is sorted on the host: one DICT_PROBE_ROWS transfer per
     # column per process, where a device sort costs the chip's compiler
     # tens of seconds per dtype and size
-    probe = np.unique(jax.device_get(  # lint: allow-host-sync
-        body[:DICT_PROBE_ROWS]))
+    probe = np.unique(host_get(body[:DICT_PROBE_ROWS],
+                               "storage_dict_probe"))
     if hint == "dict" or probe.shape[0] <= DICT_MAX_NDV:
         values = _distinct_values(body, probe)
         ndv = int(values.shape[0])
@@ -212,7 +214,7 @@ def _distinct_values(body, probe: np.ndarray):
         slot = jnp.clip(jnp.searchsorted(values, body),
                         0, values.shape[0] - 1)
         # build-time stat, one sync per column per process
-        if jax.device_get((values[slot] == body).all()):  # lint: allow-host-sync
+        if host_get((values[slot] == body).all(), "storage_dict_check"):
             return values
     return jnp.unique(body)
 
@@ -325,7 +327,7 @@ def build_zone_maps(arr, n_rows: int, zone_rows: int,
     else:
         ncnt = jnp.zeros(nz, dtype=jnp.int32)
     # build-time stat transfer: one sync per column per process
-    zmin, zmax, ncnt = jax.device_get((zmin, zmax, ncnt))  # lint: allow-host-sync
+    zmin, zmax, ncnt = host_get((zmin, zmax, ncnt), "storage_zone_maps")
     return ZoneMaps(np.asarray(zmin), np.asarray(zmax),
                     np.asarray(ncnt), zone_rows)
 

@@ -30,11 +30,11 @@ import functools
 from collections import OrderedDict
 from typing import Dict, Optional
 
-import jax
 import jax.numpy as jnp
 
 from ..common.locks import OrderedLock
 from ..exec.memory import MemoryPool
+from ..utils.runtime_stats import current_stats, host_get, named_jit
 from .encodings import (ResidentColumn, ZoneMaps, build_zone_maps,
                         encode_column)
 
@@ -164,6 +164,20 @@ class ResidentStore:
         if (n_rows + pad) * itemsize > self.max_column_bytes:
             STORAGE_METRICS.incr("build_rejected")
             return None
+        # generate + encode, timed into the query or task that missed: a
+        # second task building the same column at the same time is a
+        # second `storageBuilds` count
+        owner = current_stats()
+        if owner is None:
+            return self._build(key, cid, table, colname, sf, n_rows, pad,
+                               as_i32, zone_rows, encodings)
+        with owner.span("storageBuild", table=table, column=colname):
+            owner.add("storageBuilds", 1)
+            return self._build(key, cid, table, colname, sf, n_rows, pad,
+                               as_i32, zone_rows, encodings)
+
+    def _build(self, key, cid, table, colname, sf, n_rows, pad, as_i32,
+               zone_rows, encodings) -> Optional[ResidentEntry]:
         arr = _build_full(cid, table, colname, sf, n_rows, pad, as_i32)
         from ..connectors import device_gen
         hint = device_gen.encoding_hint(cid, table, colname)
@@ -173,7 +187,7 @@ class ResidentStore:
         host = None
         if n_rows <= HOST_STATS_ROWS:
             # build-time stat transfer, once per column per process
-            host = jax.device_get(arr)  # lint: allow-host-sync
+            host = host_get(arr, "storage_small_column")
         col = encode_column(arr, n_rows, encodings=encodings, hint=hint,
                             host=host)
         zones = build_zone_maps(arr, n_rows, zone_rows, host=host)
@@ -212,14 +226,13 @@ def _gen_fn(cid: str, table: str, colname: str, sf: float, chunk: int,
     differently-budgeted stores reuse the compiled executable."""
     from ..connectors import device_gen
 
-    @jax.jit
     def gen_chunk(pos):
         idx = pos + jnp.arange(chunk, dtype=jnp.int64)
         v = device_gen.column(cid, table, colname, sf, idx)
         return v.astype(jnp.int32) if as_i32 and v.dtype == jnp.int64 \
             else v
 
-    return gen_chunk
+    return named_jit(f"gen_{table}_{colname}", gen_chunk)
 
 
 def _build_full(cid: str, table: str, colname: str, sf: float,

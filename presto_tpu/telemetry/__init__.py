@@ -22,6 +22,11 @@ Layers:
                   age limits, reload across worker restarts).
   * profiler.py — `profile` session property: wrap one query's execution
                   in jax.profiler.trace() writing a per-query directory.
+  * jax_events.py — JAX's trace / lower / compile / cache events,
+                  attributed to the query or task whose thread caused
+                  them, and the per-program process table.
+  * gaps.py     — a capture reduced over the program's `presto:` spans:
+                  which span was innermost while the device idled.
 """
 from .otlp import (trace_id_for, span_id_for, spans_to_resource_spans,
                    metrics_to_resource_metrics, scrape_metric_points)
@@ -29,6 +34,7 @@ from .export import (TelemetrySink, CollectorSink, JsonlFileSink,
                      HttpOtlpSink, TelemetryExporter, make_sink,
                      set_process_exporter, get_process_exporter)
 from .history import QueryHistoryStore, HistoryEventListener
+from . import gaps
 from .profiler import profile_capture
 
 __all__ = [
@@ -38,5 +44,5 @@ __all__ = [
     "TelemetryExporter", "make_sink",
     "set_process_exporter", "get_process_exporter",
     "QueryHistoryStore", "HistoryEventListener",
-    "profile_capture",
+    "profile_capture", "gaps",
 ]

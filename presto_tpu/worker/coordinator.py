@@ -500,7 +500,7 @@ class _QueryExecution:
     per-task retry over durable shuffle — here over retained buffers)."""
 
     def __init__(self, runner: "HttpQueryRunner", root: _Stage, qid: str,
-                 trace_token: str = ""):
+                 trace_token: str = "", stats: Optional[RuntimeStats] = None):
         self.runner = runner
         self.root = root
         self.qid = qid
@@ -549,7 +549,11 @@ class _QueryExecution:
             "exchange_max_buffer_size", cfg.exchange_max_buffer_bytes))
         self.max_response_bytes = parse_data_size(self.session.get(
             "exchange_max_response_size", cfg.exchange_max_response_bytes))
-        self.stats = RuntimeStats()             # root-pull exchange stats
+        # the query's RuntimeStats (the statement executor's when it set
+        # one): coordinator spans and the root pull land here, and every
+        # task's runtimeStats is merged in when the query ends
+        self.stats = stats if stats is not None \
+            else RuntimeStats(query_id=qid)
         # trace token: honor one handed down by the statement layer (it
         # minted per-query), else mint from the query id; propagated to
         # every task via session + X-Presto-Trace-Token headers
@@ -663,7 +667,9 @@ class _QueryExecution:
         for cand in candidates:
             task = RemoteTask(cand, task_id, trace_token=self.trace_token)
             try:
-                task.update(req, deadline_ms=self._deadline_ms())
+                # one POST /v1/task; summed over a query's tasks
+                with self.stats.span("schedCreateTasks"):
+                    task.update(req, deadline_ms=self._deadline_ms())
             except urllib.error.HTTPError as e:
                 if e.code != 503:
                     raise
@@ -708,7 +714,9 @@ class _QueryExecution:
                 max_response_bytes=self.max_response_bytes,
                 stats=self.stats)
             try:
-                pages = list(client.pages())
+                # tasks created -> the root stage's last page pulled
+                with self.stats.span("schedAwaitStages"):
+                    pages = list(client.pages())
                 self._raise_pending_failures()
                 return pages
             except (ExchangeLostError, RemoteTaskError,
@@ -965,20 +973,24 @@ class _QueryExecution:
                                        for st in stages),
                 "operatorStats": merged}
 
-    def peak_memory_bytes(self) -> int:
-        """Cluster-wide peak: the sum of per-task memory-pool peaks
-        (reference peakTotalMemoryReservation).  Fetched task-by-task
-        AFTER the drain, so admission history seeding records what the
-        distributed run actually reserved instead of 0."""
+    def roll_up_tasks(self) -> int:
+        """Task -> query roll-up, one TaskInfo fetch per task AFTER the
+        drain: merges every task's `runtimeStats` into the query's
+        (RuntimeStats.merge_dict, the same function EXPLAIN ANALYZE's
+        footer uses) and returns the cluster-wide memory peak, the sum of
+        per-task memory-pool peaks (reference
+        peakTotalMemoryReservation), so admission history seeding records
+        what the distributed run actually reserved instead of 0."""
         total = 0
         for t in self.all_tasks:
             if t is None:
                 continue
             try:
                 stats = t.info(timeout_s=5).get("stats") or {}
-                total += int(stats.get("peakTotalMemoryInBytes", 0) or 0)
             except (OSError, ValueError):
                 continue
+            total += int(stats.get("peakTotalMemoryInBytes", 0) or 0)
+            self.stats.merge_dict(stats.get("runtimeStats"))
         return total
 
     def close(self) -> None:
@@ -1029,13 +1041,28 @@ class HttpQueryRunner(LocalQueryRunner):
         return live
 
     # -- planning ---------------------------------------------------------
-    def plan_subplan(self, sql: str):
+    def plan_subplan(self, sql: str, ast=None,
+                     stats: Optional[RuntimeStats] = None):
+        """SQL (or its parsed `ast`) -> (SubPlan, names, types), the
+        phases recorded as queryParse / queryPlan / queryOptimize /
+        queryFragment in `stats` as the in-process runner records its."""
+        from ..sql import parser as A
         from ..sql.fragmenter import FragmenterConfig, plan_distributed
-        output = self.plan(sql)
+        from ..sql.planner import Planner
+        stats = stats if stats is not None else RuntimeStats()
+        if ast is None:
+            with stats.span("queryParse"):
+                ast = A.parse_sql(sql)
+        planner = Planner(default_schema=self.schema,
+                          default_catalog=self.catalog)
+        with stats.span("queryPlan"), self._validation():
+            unopt = planner.plan_query_unoptimized(ast)
+        with stats.span("queryOptimize"), self._validation():
+            output = Planner.optimize_output(unopt)
         names = output.column_names
         types = [v.type for v in output.outputs]
         cfg = FragmenterConfig(broadcast_threshold=self.broadcast_threshold)
-        with self._validation():
+        with stats.span("queryFragment"), self._validation():
             sub = plan_distributed(output, cfg, exec_config=self.config)
         return sub, names, types
 
@@ -1107,19 +1134,11 @@ class HttpQueryRunner(LocalQueryRunner):
             # recorded in each TASK's RuntimeStats on its worker: merge
             # them across tasks, on top of the coordinator's own root-pull
             # stats
-            merged_rs = execution.stats.to_dict()
             for st in snapshot["stages"]:
                 for t in st["tasks"]:
-                    src = (t.get("stats") or {}).get("runtimeStats") or {}
-                    for k, v in src.items():
-                        e = merged_rs.get(k)
-                        if e is None:
-                            merged_rs[k] = dict(v)
-                        else:
-                            e["sum"] += v["sum"]
-                            e["count"] += v["count"]
-                            e["min"] = min(e["min"], v["min"])
-                            e["max"] = max(e["max"], v["max"])
+                    execution.stats.merge_dict(
+                        (t.get("stats") or {}).get("runtimeStats"))
+            merged_rs = execution.stats.to_dict()
             footer = format_analyze_footer(merged_rs,
                                            profile_dir=trace_dir)
         text = format_subplan(subplan, stats)
@@ -1131,33 +1150,43 @@ class HttpQueryRunner(LocalQueryRunner):
     # -- execution --------------------------------------------------------
     def execute(self, sql: str, trace_token: str = "") -> QueryResult:
         from ..sql import parser as A
+        from ..utils.runtime_stats import current_stats
+        qid = f"q{next(_query_counter)}_{int(time.time() * 1000) % 100000}"
+        # the statement executor's stats when it set one (the query's
+        # RuntimeStats in QueryInfo), else this execution's own
+        stats = current_stats() or RuntimeStats(query_id=qid)
         try:
-            ast = A.parse_sql(sql)
+            with stats.span("queryParse"):
+                ast = A.parse_sql(sql)
         except Exception:
-            ast = None
+            ast = None      # plan_subplan raises the parser's own error
         if ast is not None and isinstance(ast, A.Explain):
             return self._explain_http(ast, trace_token=trace_token)
-        subplan, names, types = self.plan_subplan(sql)
-        root = self._build_stages(subplan)
-        qid = f"q{next(_query_counter)}_{int(time.time() * 1000) % 100000}"
+        subplan, names, types = self.plan_subplan(sql, ast=ast, stats=stats)
+        with stats.span("queryFragment"):
+            root = self._build_stages(subplan)
         execution = _QueryExecution(self, root, qid,
-                                    trace_token=trace_token)
+                                    trace_token=trace_token, stats=stats)
         self.last_execution = execution
         try:
             pages = execution.run()
             result = pages_to_result(iter(pages), names, types)
-            result.runtime_stats = execution.stats.to_dict()
             try:
-                # per-task memory-pool peaks roll into the result so the
-                # QueryCompletedEvent / history record carries a real
-                # peak for adaptive admission seeding (was always 0)
-                result.peak_memory_bytes = execution.peak_memory_bytes()
+                # task -> query: every task's runtimeStats merged into
+                # the query's, and the per-task memory-pool peaks summed
+                # so the QueryCompletedEvent / history record carries a
+                # real peak for adaptive admission seeding (was always 0)
+                with stats.span("schedRollUpTasks"):
+                    result.peak_memory_bytes = execution.roll_up_tasks()
             except Exception:   # noqa: BLE001 — stats are best-effort
                 pass
+            result.runtime_stats = stats.to_dict()
             return result
         except Exception:
             self.queries_failed += 1
             raise
         finally:
             self.tasks_retried += execution.retries
-            execution.close()
+            # one DELETE /v1/task per attempt
+            with stats.span("schedCloseTasks"):
+                execution.close()

@@ -219,6 +219,7 @@ class _Handler(BaseHTTPRequestHandler):
     def do_status(self, groups, query):
         """Node status (reference server/NodeStatus.java: the payload the
         coordinator's memory manager and UI poll)."""
+        from ..telemetry.jax_events import PROGRAMS
         s = self.server_ref
         c = s.task_manager.counts()
         det = s.failure_detector
@@ -236,6 +237,7 @@ class _Handler(BaseHTTPRequestHandler):
             "heapUsed": c["memory_peak"],   # HBM peak, heap-shaped field
             **({"failureDetector": det.snapshot()} if det else {}),
             **self._serving_status(),
+            "programs": PROGRAMS.snapshot(),
         })
 
     def _serving_status(self) -> dict:
@@ -754,8 +756,12 @@ class _Handler(BaseHTTPRequestHandler):
         from ..parallel.fabric import FABRIC_METRICS
         from ..serving import SERVING_METRICS
         from ..storage.store import STORAGE_METRICS
+        from ..telemetry.jax_events import PROGRAMS
         from .exchange import EXCHANGE_METRICS
         return {"exchange": EXCHANGE_METRICS.snapshot(),
+                # per program name: traces, trace_s, loads, load_s,
+                # true_compiles (telemetry/jax_events.py)
+                "programs": PROGRAMS.snapshot(),
                 "fabric": FABRIC_METRICS.snapshot(),
                 "serving": SERVING_METRICS.snapshot(),
                 "storage": dict(STORAGE_METRICS),
@@ -1122,6 +1128,13 @@ class WorkerServer:
             if get_process_exporter() is None:
                 set_process_exporter(self.telemetry)
                 self._owns_process_exporter = True
+            if self.dispatch is not None:
+                # a sink is configured: queries keep their span trees
+                self.dispatch.record_spans = True
+        # JAX's trace / lower / compile / cache events, attributed to the
+        # query or task whose thread caused them (once per process)
+        from ..telemetry import jax_events
+        jax_events.install()
 
         # query history service (coordinator role): terminal QueryInfo
         # records, retention-bounded, reloaded from the JSONL spool across
@@ -1270,7 +1283,8 @@ class WorkerServer:
         execute_prepared_batch); everything else — and every lane the
         batched drain declines — takes `_run_single`, the unchanged
         sequential path."""
-        runner, uris = self._runner_for(q.schema, q.catalog, q.session)
+        with q.rstats.span("statementRunnerLookup"):
+            runner, uris = self._runner_for(q.schema, q.catalog, q.session)
         result = None
         served = False
         if (not uris and self._batcher is not None
@@ -1357,7 +1371,8 @@ class WorkerServer:
                 try:
                     # terminal snapshot for the query-history ring: tasks
                     # stay queryable on workers until TTL eviction
-                    q.query_info_extra = exe.query_info_snapshot()
+                    with q.rstats.span("statementQueryInfoSnapshot"):
+                        q.query_info_extra = exe.query_info_snapshot()
                 except Exception:  # noqa: BLE001 — snapshot best-effort
                     pass
         if q.sql.lstrip()[:6].lower() in ("create", "insert") \
@@ -1407,21 +1422,32 @@ class WorkerServer:
             q = self.dispatch.get(event.query_id)
         except KeyError:
             q = None
-        started = (q.started_at if q is not None and q.started_at
-                   else event.create_time)
+        # the query span: submit .. terminal (queueing included: the
+        # recorded `statementQueued` is its first stretch)
+        started = event.create_time
         spans = [Span("query", "", start=started, end=event.end_time,
                       attributes={"queryId": event.query_id,
                                   "sql": event.sql, "user": event.user,
                                   "state": event.state,
                                   "rows": event.rows})]
+        # what RuntimeStats.span recorded on the coordinator while the
+        # query ran (parse .. roll-up): real, nested intervals
+        tracer = q.rstats.tracer if q is not None else None
+        if tracer is not None:
+            spans.extend(tracer.spans)
         extra = q.query_info_extra if q is not None else None
         for st in (extra or {}).get("stages") or []:
             fid = st.get("fragmentId", st.get("stageId", 0))
-            wall = float(st.get("wallTimeInNanos", 0) or 0) / 1e9
+            # a fragment lasts from its first task's creation to its last
+            # task's end, as the tasks' own TaskInfo clocks say
+            t_stats = [t.get("stats") or {} for t in st.get("tasks") or []]
+            begins = [t["createTime"] for t in t_stats if "createTime" in t]
+            ends = [t["createTime"] + t.get("elapsedTimeInNanos", 0) / 1e9
+                    for t in t_stats if "createTime" in t]
             spans.append(Span(
-                f"fragment {fid}", "query", start=started,
-                end=(min(event.end_time, started + wall) if wall
-                     else event.end_time),
+                f"fragment {fid}", "query",
+                start=min(begins, default=started),
+                end=min(event.end_time, max(ends, default=event.end_time)),
                 attributes={"nTasks": st.get("nTasks", 0),
                             "partitioning": st.get("partitioning", "")}))
         exp.export_spans(event.trace_token, spans,

@@ -33,6 +33,8 @@ from decimal import Decimal
 from typing import Callable, Dict, List, Optional
 
 from ..common.locks import OrderedLock
+from ..utils.runtime_stats import RuntimeStats, SimpleTracer
+from ..utils.stack import roomy
 
 QUEUED = "QUEUED"
 RUNNING = "RUNNING"
@@ -297,7 +299,14 @@ class ManagedQuery:
     error: Optional[str] = None
     columns: Optional[List[dict]] = None
     rows: Optional[list] = None
-    runtime_stats: Optional[dict] = None
+    # the query's RuntimeStats: the statement layer's own spans, and --
+    # as the thread-local owner of the executor thread -- everything the
+    # runner, the pipeline and JAX record while the query runs
+    rstats: RuntimeStats = field(default_factory=RuntimeStats)
+    # what the executor's result carried (a runner that kept stats of its
+    # own, a batched lane's share of its launch)
+    _result_stats: Optional[dict] = None
+    _created_ns: int = field(default_factory=time.perf_counter_ns)
     # observability: the query's trace token (minted at submit or taken
     # from the client's X-Presto-Trace-Token) and the stage/task/operator
     # drill-down captured by the executor for /v1/query/{id}
@@ -324,6 +333,16 @@ class ManagedQuery:
     _drained: bool = False
     rows_served: int = 0
     last_access: float = field(default_factory=time.time)
+
+    @property
+    def runtime_stats(self) -> Optional[dict]:
+        """QueryInfo `runtimeStats`: the result's map with the query's
+        own on top (one owner recorded both when the runner found the
+        thread-local, so the union never counts twice)."""
+        own = self.rstats.to_dict()
+        if not own:
+            return self._result_stats
+        return {**(self._result_stats or {}), **own}
 
     def stats(self) -> dict:
         now = self.finished_at or time.time()
@@ -365,6 +384,9 @@ class DispatchManager:
         # rank 10: the outermost lock in the intake path — held only for
         # registry mutation, released before admission (12) or task work
         self._lock = OrderedLock("dispatch-manager", 10)  # lint: guarded-by(_lock)
+        # set by a server with a telemetry sink: each query then keeps
+        # its spans (real, nested intervals) for the exporter
+        self.record_spans = False
 
     # -- intake -----------------------------------------------------------
     # a streaming query whose client stopped polling is canceled so its
@@ -399,6 +421,9 @@ class DispatchManager:
         # the distributed runner out-of-band.
         q.trace_token = (trace_token or q.session.get("trace_token")
                          or f"trace-{qid}")
+        q.rstats = RuntimeStats(
+            tracer=SimpleTracer(q.trace_token) if self.record_spans
+            else None, root="query", query_id=qid)
         est = (session or {}).get("query_memory_bytes")
         if est is not None:
             try:
@@ -456,7 +481,7 @@ class DispatchManager:
                 return
 
     def _start(self, q: ManagedQuery) -> None:
-        t = threading.Thread(target=self._run, args=(q,),
+        t = threading.Thread(target=roomy, args=(self._run, q),
                              name=f"query-{q.query_id}", daemon=True)
         t.start()
 
@@ -468,10 +493,14 @@ class DispatchManager:
             return
         q.state = RUNNING
         q.started_at = time.time()
+        # submit -> an executor thread runs it (admission, thread start)
+        q.rstats.add("statementQueuedWallNanos",
+                     time.perf_counter_ns() - q._created_ns, "NANO")
         attempt = 0
         while True:
             try:
-                result = self._executor(q)
+                with q.rstats.activate():
+                    result = self._executor(q)
                 if isinstance(result, StreamingResult):
                     # rows are pulled lazily by executing_response; the
                     # query finishes (and frees its resource-group slot)
@@ -485,7 +514,7 @@ class DispatchManager:
                                              result.column_types)]
                 q.rows = [[_json_value(v) for v in row]
                           for row in result.rows]
-                q.runtime_stats = getattr(result, "runtime_stats", None)
+                q._result_stats = getattr(result, "runtime_stats", None)
                 q.peak_memory_bytes = int(
                     getattr(result, "peak_memory_bytes", 0) or 0)
                 q.profile_trace_dir = getattr(
@@ -569,7 +598,8 @@ class DispatchManager:
     def queued_response(self, q: ManagedQuery, token: int,
                         base_uri: str, wait_s: float = 0.1) -> dict:
         if q.state == QUEUED:
-            q.done.wait(wait_s)
+            with q.rstats.span("statementPollWait"):
+                q.done.wait(wait_s)
         resp = {"id": q.query_id,
                 "infoUri": f"{base_uri}/v1/query/{q.query_id}",
                 "stats": q.stats()}
@@ -596,12 +626,19 @@ class DispatchManager:
         """Pull rows from the streaming iterator until chunk `token`
         exists or the stream is drained; forget acknowledged chunks."""
         while not q._drained and q._max_token < token:
-            rows = list(itertools.islice(q._row_iter,
-                                         self.RESULT_CHUNK_ROWS))
+            # the rows' way to the client: on the single-node path the
+            # pipeline itself runs inside this pull, on the handler's
+            # thread, so the query's stats own that thread meanwhile
+            # ... and from a roomy frame, like every thread that may
+            # trace or lower a program (utils/stack.py)
+            with q.rstats.activate(), q.rstats.span("statementDrain"):
+                rows = roomy(list, itertools.islice(
+                    q._row_iter, self.RESULT_CHUNK_ROWS))
             if not rows:
                 q._drained = True
-                if q._stats_src is not None:
-                    q.runtime_stats = q._stats_src.to_dict()
+                if q._stats_src is not None \
+                        and q._stats_src is not q.rstats:
+                    q._result_stats = q._stats_src.to_dict()
                 break
             q._max_token += 1
             q._chunks[q._max_token] = rows
@@ -647,7 +684,12 @@ class DispatchManager:
         if q._row_iter is not None:
             return self._executing_streaming(q, token, base_uri)
         if not q.done.is_set():
-            q.done.wait(wait_s)
+            # a handler blocked on the client's behalf until the query is
+            # done or the poll times out.  (A poll that arrives before the
+            # executor has handed over a streaming result's iterator waits
+            # out the whole `wait_s` here: `done` is only set at drain.)
+            with q.rstats.span("statementPollWait"):
+                q.done.wait(wait_s)
         resp = {"id": q.query_id,
                 "infoUri": f"{base_uri}/v1/query/{q.query_id}",
                 "stats": q.stats()}
@@ -667,7 +709,8 @@ class DispatchManager:
         hi = lo + self.RESULT_CHUNK_ROWS
         resp["columns"] = q.columns
         if lo < len(q.rows):
-            resp["data"] = q.rows[lo:hi]
+            with q.rstats.span("statementDrain"):
+                resp["data"] = q.rows[lo:hi]
         if hi < len(q.rows):
             resp["nextUri"] = (f"{base_uri}/v1/statement/executing/"
                                f"{q.query_id}/{q.slug}/{token + 1}")

@@ -46,18 +46,23 @@ class TpuTask:
         self.error_type = ""              # lint: guarded-by(_cond)
         self.buffers: Optional[OutputBufferManager] = None
         self.done_at: Optional[float] = None  # lint: guarded-by(_cond)
+        self.finished_at: Optional[float] = None  # unix s, set with done_at
         self.memory_peak = 0
         self.memory_ctx = None            # task MemoryContext (set by start)
         # TaskInfo stats surface (reference TaskInfo/TaskStats): the
         # coordinator-side aggregation and UI drill-down consume these
         import time as _t
         self.created_at = _t.time()
+        self._created_ns = _t.perf_counter_ns()
         self.output_rows = 0
         self.output_pages = 0
         self.output_bytes = 0
         self.plan_nodes: List[dict] = []
+        # the task's RuntimeStats: every span and counter of the worker's
+        # side of a query lands here (thread-local owner while the task
+        # runs) and reaches the query through TaskInfo `runtimeStats`
         from ..utils.runtime_stats import RuntimeStats
-        self.stats = RuntimeStats()       # exchange-client walls/bytes etc.
+        self.stats = RuntimeStats(task_id=task_id)
         # X-Presto-Trace-Token propagated by the coordinator (session key
         # "trace_token"); echoed back in TaskInfo so a trace id observed at
         # the coordinator can be joined against worker-side task records
@@ -110,8 +115,10 @@ class TpuTask:
                 # exchange overlap surface (TaskStats per-pipeline walls)
                 "drainPipelineWallS": round(
                     getattr(self, "_drain_wall", [0.0])[0], 4),
+                # created -> terminal (still growing while it runs)
                 "elapsedTimeInNanos": int(
-                    (_t.time() - self.created_at) * 1e9),
+                    ((self.finished_at or _t.time()) - self.created_at)
+                    * 1e9),
                 # driver thread-time vs driver wall (sampled at the _run
                 # boundaries): the per-stage CPU/wall attribution in
                 # /v1/query/{id} sums these across the stage's tasks
@@ -179,6 +186,7 @@ class TpuTask:
                     self.error_type = error_type or INTERNAL_ERROR
             if state in DONE_STATES:
                 self.done_at = time.monotonic()
+                self.finished_at = time.time()
             self._cond.notify_all()
         if state == FAILED and self.manager is not None:
             # lifetime counter: incremented under the MANAGER's lock (this
@@ -406,8 +414,14 @@ class TpuTask:
                               dynamic_filters=self.dynamic_filters)
             self.trace_token = update.session.get("trace_token", "")
             if self.trace_token:
-                print(f"[trace {self.trace_token}] task {self.task_id} "
-                      f"starting")
+                from ..telemetry import get_process_exporter
+                if get_process_exporter() is not None:
+                    # a telemetry sink is configured: keep this task's
+                    # spans (real intervals, nested) for _export_spans
+                    from ..utils.runtime_stats import SimpleTracer
+                    self.stats.tracer = SimpleTracer(self.trace_token)
+                    self.stats.scope = self.task_id
+                    self.stats.root = f"task {self.task_id}"
             if str(update.session.get(
                     "collect_operator_stats", "")).lower() == "true":
                 # coordinator-requested per-node operator stats (EXPLAIN
@@ -465,8 +479,11 @@ class TpuTask:
             return
 
         self._set_state(RUNNING)
+        # from a roomy frame: the task traces, lowers and loads its
+        # programs on this thread (utils/stack.py)
+        from ..utils.stack import roomy
         self._thread = threading.Thread(
-            target=self._run, args=(fragment, spec, ctx),
+            target=roomy, args=(self._run, fragment, spec, ctx),
             name=f"task-{self.task_id}", daemon=True)
         self._thread.start()
 
@@ -493,10 +510,14 @@ class TpuTask:
         # task executes — by ANY thread, the flag is process-global and
         # counting so concurrent scoped tasks compose — is checked against
         # the declared rank order and metered into presto_tpu_lock_*
-        if getattr(ctx.config, "lock_validation", False):
-            with validation_scope():
-                return self._run_impl(fragment, spec, ctx)
-        return self._run_impl(fragment, spec, ctx)
+        import time as _t
+        self.stats.add("taskQueuedWallNanos",
+                       _t.perf_counter_ns() - self._created_ns, "NANO")
+        with self.stats.activate():
+            if getattr(ctx.config, "lock_validation", False):
+                with validation_scope():
+                    return self._run_impl(fragment, spec, ctx)
+            return self._run_impl(fragment, spec, ctx)
 
     def _run_impl(self, fragment: P.PlanFragment, spec,
                   ctx: TaskContext) -> None:
@@ -537,7 +558,8 @@ class TpuTask:
             # starts, so the scan's first split resolution already sees
             # them; producer-side summarization setup mirrors the
             # in-process scheduler (exec/scheduler._summarize_page_block)
-            self._await_dynamic_filters(fragment, ctx)
+            with self.stats.span("taskAwaitDynamicFilters"):
+                self._await_dynamic_filters(fragment, ctx)
             from ..exec.scheduler import _summarize_page_block
             dyn_max = ctx.config.dynamic_filtering_max_distinct
             dyn_idx = ([(out_names.index(c), fid)
@@ -545,8 +567,10 @@ class TpuTask:
                         if c in out_names]
                        if ctx.config.dynamic_filtering else [])
             task_sums: Dict[str, object] = {}
-            compiler = PlanCompiler(ctx)
-            pages = compiler.run_to_pages(fragment.root)
+            with self.stats.span("pipelineBuild"):
+                compiler = PlanCompiler(ctx)
+                src = compiler.compile_root(fragment.root)
+            pages = self._pages_of(src)
             if ctx.config.task_concurrency > 1:
                 # overlap pipeline drain (device dispatch + page decode)
                 # with serialization + buffering — the two-pipeline shape
@@ -575,23 +599,24 @@ class TpuTask:
                         else prev.merge(s, dyn_max)
                 compress = ctx.config.exchange_compression
                 codec = ctx.config.exchange_compression_codec
-                if partitioned:
-                    targets = partition_targets(page, out_types, key_indices,
-                                                n_parts)
-                    for p, sub in enumerate(
-                            split_page(page, targets, n_parts)):
-                        if sub is not None:
-                            data = serialize_page(sub, compress=compress,
-                                                  codec=codec)
-                            self.output_pages += 1
-                            self.output_bytes += len(data)
-                            self.buffers.add(p, data)
-                else:
-                    data = serialize_page(page, compress=compress,
-                                          codec=codec)
-                    self.output_pages += 1
-                    self.output_bytes += len(data)
-                    self.buffers.add(0, data)
+                with self.stats.span("taskSerialize"):
+                    if partitioned:
+                        targets = partition_targets(page, out_types,
+                                                    key_indices, n_parts)
+                        for p, sub in enumerate(
+                                split_page(page, targets, n_parts)):
+                            if sub is not None:
+                                data = serialize_page(sub, compress=compress,
+                                                      codec=codec)
+                                self.output_pages += 1
+                                self.output_bytes += len(data)
+                                self.buffers.add(p, data)
+                    else:
+                        data = serialize_page(page, compress=compress,
+                                              codec=codec)
+                        self.output_pages += 1
+                        self.output_bytes += len(data)
+                        self.buffers.add(0, data)
             self.memory_peak = ctx.memory.peak
             if dyn_idx:
                 # a task with no output still publishes EMPTY summaries:
@@ -637,11 +662,30 @@ class TpuTask:
             self.stats.add("driverWallNanos", self._driver_wall_nanos,
                            "NANO")
             try:
-                self._export_spans(fragment)
+                self._export_spans(fragment, ctx)
             except Exception:
                 pass  # telemetry must never fail a task
 
-    def _export_spans(self, fragment: P.PlanFragment) -> None:
+    def _pages_of(self, src):
+        """The fragment's output as host Pages.  `pipelineDrain` is the
+        time the compiled pipeline takes to hand one batch up (tracing,
+        executable loads, dispatch and in-pipeline syncs are inside it);
+        `taskSerialize` covers the batch's way out of the task:
+        batch_to_page here (its device fetch is `hostSync.page_fetch*`
+        inside it), then serialize_page and buffers.add in the page loop."""
+        from ..exec.batch import batch_to_page
+        it = iter(src.batches())
+        while True:
+            with self.stats.span("pipelineDrain"):
+                batch = next(it, None)
+            if batch is None:
+                return
+            with self.stats.span("taskSerialize"):
+                page = batch_to_page(batch, src.names, src.types)
+            if page.position_count:
+                yield page
+
+    def _export_spans(self, fragment: P.PlanFragment, ctx) -> None:
         """Export this task's span subtree into the process telemetry
         exporter.  Span names embed the task id and parent the owning
         fragment's span by NAME — span ids are derived from
@@ -657,12 +701,11 @@ class TpuTask:
             return
         import time as _t
         from ..utils.runtime_stats import Span
-        end = _t.time()
         task_name = f"task {self.task_id}"
         spans = [Span(
             name=task_name,
             parent=f"fragment {fragment.fragment_id}",
-            start=self.created_at, end=end,
+            start=self.created_at, end=_t.time(),
             attributes={
                 "presto.task_id": self.task_id,
                 "presto.state": self.state,
@@ -672,16 +715,28 @@ class TpuTask:
                 "presto.cpu_nanos": getattr(self, "_driver_cpu_nanos", 0),
                 "presto.peak_memory_bytes": self.memory_peak,
             })]
+        # the spans RuntimeStats.span recorded while the task ran: real
+        # intervals, each under the span that enclosed it
+        tracer = self.stats.tracer
+        if tracer is not None:
+            spans.extend(tracer.spans)
+            if tracer.dropped:
+                spans[0].attributes["presto.spans_dropped"] = tracer.dropped
+        # operator spans only where operator stats were collected, over
+        # the interval in which _instrument saw the node produce
         for op in self.plan_nodes:
+            nid = op.get("planNodeId", "")
+            times = ctx.operator_times.get(nid)
+            if not op.get("stats") or times is None:
+                continue
             attrs = {"presto.operator": op.get("operatorType", ""),
-                     "presto.plan_node_id": op.get("planNodeId", "")}
-            for k, v in (op.get("stats") or {}).items():
+                     "presto.plan_node_id": nid}
+            for k, v in op["stats"].items():
                 if isinstance(v, (bool, int, float, str)):
                     attrs[k] = v
             spans.append(Span(
-                name=f"operator {self.task_id}.{op.get('planNodeId', '')}",
-                parent=task_name,
-                start=self.created_at, end=end, attributes=attrs))
+                name=f"operator {self.task_id}.{nid}", parent=task_name,
+                start=times[0], end=times[1], attributes=attrs))
         exp.export_spans(self.trace_token, spans,
                          resource={"presto.role": "worker",
                                    "presto.task_uri": self.self_uri})

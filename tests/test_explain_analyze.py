@@ -178,7 +178,12 @@ def test_query_info_schema_golden(cluster):
     # process metrics ride along for a single-snapshot health read
     assert set(info["processMetrics"]) == {"exchange", "fabric", "serving",
                                            "storage", "kernel", "memory",
-                                           "adaptive"}
+                                           "adaptive", "programs"}
+    # the program table names what JAX traced and loaded, by program
+    programs = info["processMetrics"]["programs"]
+    assert programs and all(
+        {"traces", "trace_s", "loads", "load_s", "true_compiles"} == set(r)
+        for r in programs.values())
     assert "resident_bytes" in info["processMetrics"]["storage"]
     assert "spilled_bytes" in info["processMetrics"]["memory"]
     assert "filters_applied" in info["processMetrics"]["adaptive"]

@@ -47,9 +47,18 @@ def test_simple_tracer_through_runner():
     r.execute(sql)
     trace = tp.get_trace(sql)
     assert isinstance(trace, SimpleTracer)
-    anns = trace.annotations()
-    assert anns[0] == "query parsed"
-    assert anns[-1] == "query finished"
+    # the query's phases are spans under the `query` root, in the order
+    # they began, each inside its parent's interval
+    by_name = {s.name: s for s in trace.spans}
+    assert trace.spans[0].name == "query" and trace.spans[0].parent == ""
+    for phase in ("queryParse", "queryPlan", "queryExecute"):
+        assert by_name[phase].parent == "query", phase
+    assert by_name["pipelineBuild"].parent == "queryExecute"
+    starts = [by_name[n].start for n in ("query", "queryParse", "queryPlan",
+                                         "queryExecute", "pipelineBuild")]
+    assert starts == sorted(starts)
+    assert all(s.end >= s.start for s in trace.spans)
+    assert by_name["queryExecute"].end <= by_name["query"].end + 1e-3
 
 
 def test_runtime_stats_in_query_info():

@@ -427,6 +427,20 @@ def test_end_to_end_distributed_trace(traced_cluster):
                 if a["key"] == "presto.role":
                     roles.add(a["value"]["stringValue"])
     assert {"coordinator", "worker"} <= roles
+    # real intervals: a task's recorded phases are spans of their own
+    # inside it (the old export stamped the task's interval on every one)
+    def interval(s):
+        return int(s["startTimeUnixNano"]), int(s["endTimeUnixNano"])
+    for t in tasks:
+        phases = [s for s in spans if s["parentSpanId"] == t["spanId"]
+                  and not s["name"].startswith("operator ")]
+        assert {p["name"].split(" ")[0] for p in phases} >= {
+            "pipelineBuild", "pipelineDrain", "taskSerialize"}
+        assert interval(t) not in {interval(p) for p in phases}
+        assert len({interval(p) for p in phases}) == len(phases)
+        assert all(interval(t)[0] - 50_000_000 <= interval(p)[0]
+                   <= interval(p)[1] <= interval(t)[1] + 50_000_000
+                   for p in phases)
     c = coordinator.telemetry.counters()
     assert c["dropped"] == 0 and c["dropped_after_retry"] == 0
 
