@@ -182,8 +182,11 @@ class FusedChain:
 
     def __init__(self, compiler, steps: List[tuple], scan_meta: dict,
                  step_ids: Optional[List[str]] = None,
-                 scan_id: Optional[str] = None):
+                 scan_id: Optional[str] = None,
+                 root: Optional[P.PlanNode] = None):
         self.compiler = compiler
+        # the subtree this chain compiles: what its programs are keyed on
+        self.root = root
         self.steps = steps
         self.scan_meta = scan_meta
         # plan-node ids for EXPLAIN ANALYZE row counters: node_ids[0] is
@@ -214,7 +217,10 @@ class FusedChain:
         self.chunks = self.chunks_for((1,) * sum(
             1 for s in steps if s[0] in ("join", "semi")))
         self.total_rows = sum(n for _, n in self.chunks)
-        self._leaf_make: Dict[int, Callable] = {}
+        # what a jitted program closes over instead of this chain
+        self.program = ChainProgram(steps, scan_meta, compiler.lowering,
+                                    compiler.ctx.task_index,
+                                    self.has_params)
 
     def chunks_for(self, expands: Tuple[int, ...],
                    meter: bool = False) -> List[Tuple[int, int]]:
@@ -341,26 +347,63 @@ class FusedChain:
 
     def make(self, pos, valid, aux, expands: Tuple[int, ...],
              leaf_cap: int, with_counts: bool = False):
+        """`ChainProgram.make`: one scan chunk through the chain."""
+        return self.program.make(pos, valid, aux, expands, leaf_cap,
+                                 with_counts)
+
+
+class ChainProgram:
+    """The traceable half of a FusedChain: what `make` reads while JAX
+    traces it, and nothing else.  Jitted programs close over THIS, not
+    over the chain, because they outlive the task that built them in the
+    process-wide program cache (serving/fragments.py): the chain holds
+    the PlanCompiler and through it the TaskContext (memory context,
+    runtime stats, exchange clients, dynamic filters); this holds plan
+    expressions, the scan's pure kernel factory, the static dictionaries
+    of the scanned columns, a stateless Lowering and two host constants.
+    Every one of them is determined by (subtree, variable names, config)
+    but `task_index`, which `signature` hands to the cache key when the
+    chain assigns unique ids."""
+
+    def __init__(self, steps: List[tuple], scan_meta: dict, lowering,
+                 task_index: int, has_params: bool):
+        self.steps = steps
+        self.cap = scan_meta["cap"]
+        self.table = str(scan_meta.get("table", ""))
+        self.dicts = scan_meta["dicts"]
+        self.lowering = lowering
+        self.has_params = has_params
+        self.task_index = task_index
+        self.assigns_ids = any(s[0] == "uid" for s in steps)
+        self._make_factory = scan_meta["make_factory"]
+        self._leaf_make: Dict[int, Callable] = {self.cap: scan_meta["make"]}
+
+    def signature(self, expands: Tuple[int, ...], leaf_cap: int) -> tuple:
+        """The host constants a program over this chain bakes in beyond
+        its subtree and config: join fanouts, the leaf capacity and,
+        where a step assigns unique ids, the task's index."""
+        return (expands, leaf_cap,
+                self.task_index if self.assigns_ids else None)
+
+    def make(self, pos, valid, aux, expands: Tuple[int, ...],
+             leaf_cap: int, with_counts: bool = False):
         """Apply the chain to one scan chunk.  With with_counts=True the
         return value is (Batch, int64[1+len(steps)]) where counts[0] is
         the scan's live rows and counts[i+1] the live rows after step i —
         the device-side OperatorStats row counters EXPLAIN ANALYZE reads
         (they ride the jitted program's outputs; no host syncs in-loop)."""
-        meta = self.scan_meta
         mk = self._leaf_make.get(leaf_cap)
         if mk is None:
-            mk = meta["make"] if leaf_cap == self.cap \
-                else meta["make_factory"](leaf_cap)
-            self._leaf_make[leaf_cap] = mk
+            mk = self._leaf_make[leaf_cap] = self._make_factory(leaf_cap)
         # one named scope per operator type and table: a `profile=true`
         # capture maps the fused ops back to plan operators
-        with jax.named_scope("scan:" + str(meta.get("table", ""))):
+        with jax.named_scope("scan:" + self.table):
             outs, live = mk(pos, valid, aux[0])
-        dicts = meta["dicts"]
+        dicts = self.dicts
         batch = Batch({n: Column(v, None, dicts.get(n))
                        for n, v in outs.items()}, live)
         counts = [jnp.sum(live)] if with_counts else None
-        low = self.compiler.lowering
+        low = self.lowering
         params = aux[-1] if self.has_params else None
 
         def _pb(b):
@@ -404,7 +447,7 @@ class FusedChain:
                         kprod *= expands[j]
                     cap_here = batch.mask.shape[0]
                     leaf_c = cap_here // kprod
-                    base = self.compiler.ctx.task_index << 40
+                    base = self.task_index << 40
                     # id keyed by (global leaf row, expansion branch): the
                     # join-expand layout is slot = j*C + i, so slot s maps to
                     # leaf row s % leaf_c and branch s // leaf_c — unique even
@@ -627,7 +670,8 @@ def assemble_chain(compiler, node: P.PlanNode) -> Optional[FusedChain]:
                 return None
             steps.reverse()
             step_ids.reverse()
-            return FusedChain(compiler, steps, meta, step_ids, nd.id)
+            return FusedChain(compiler, steps, meta, step_ids, nd.id,
+                              root=node)
         else:
             return None
 
@@ -700,14 +744,17 @@ def fused_materialize(compiler, node: P.PlanNode,
     key = ("fmat", node.id, expands)
     run_all = compiler._jit_cache.get(key)
     if run_all is None:
-        @jit_as("chain_materialize")
+        prog = chain.program
+
         def run_all(pos_arr, cnt_arr, aux):
             def step(pc):
-                return chain.make(pc[0], pc[1], aux, expands, leaf_cap)
+                return prog.make(pc[0], pc[1], aux, expands, leaf_cap)
             stacked = jax.lax.map(step, (pos_arr, cnt_arr))
             return jax.tree_util.tree_map(
                 lambda a: a.reshape((-1,) + a.shape[2:]), stacked)
-        compiler._jit_cache[key] = run_all
+        run_all = compiler._jit_cache[key] = compiler.shared_jit(
+            node, "chain_materialize", run_all,
+            extra=prog.signature(expands, leaf_cap))
     from .pipeline import _maybe_compact
     from .memory import batch_bytes
     out = _maybe_compact(run_all(pos_arr, cnt_arr, aux))
@@ -740,16 +787,19 @@ def chain_counts_fn(chain: "FusedChain", expands: Tuple[int, ...],
     counters in its loop state (sort-agg stacking, runtime-span)."""
     fn = cache.get(cache_key)
     if fn is None:
-        @jit_as("chain_counts")
+        prog = chain.program
+
         def fn(pos_arr, cnt_arr, aux):
             def body(i, acc):
-                _b, c = chain.make(pos_arr[i], cnt_arr[i], aux, expands,
-                                   leaf_cap, with_counts=True)
+                _b, c = prog.make(pos_arr[i], cnt_arr[i], aux, expands,
+                                  leaf_cap, with_counts=True)
                 return acc + c
             return jax.lax.fori_loop(
                 0, pos_arr.shape[0], body,
-                jnp.zeros(1 + len(chain.steps), dtype=jnp.int64))
-        cache[cache_key] = fn
+                jnp.zeros(1 + len(prog.steps), dtype=jnp.int64))
+        fn = cache[cache_key] = chain.compiler.shared_jit(
+            chain.root, "chain_counts", fn,
+            extra=prog.signature(expands, leaf_cap))
     return fn
 
 
@@ -838,12 +888,16 @@ def fused_stream(compiler, node: P.PlanNode):
             compiler._jit_cache[key] = None
             return None
 
-        @jit_as("chain_stream_step")
+        prog = chain.program
+
         def step(pos, valid, aux):
             # under EXPLAIN ANALYZE the per-step row counters ride the
             # same jitted program as extra outputs (zero host syncs)
-            return chain.make(pos, valid, aux, expands, leaf_cap,
-                              with_counts=analyzing)
+            return prog.make(pos, valid, aux, expands, leaf_cap,
+                             with_counts=analyzing)
+        step = compiler.shared_jit(
+            node, "chain_stream_step", step,
+            extra=prog.signature(expands, leaf_cap) + (analyzing,))
         ent = (step, aux, chunks, chain, expands,
                compiler.ctx.params_fingerprint)
         compiler._jit_cache[key] = ent

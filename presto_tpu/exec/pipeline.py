@@ -377,12 +377,14 @@ class ExecutionConfig:
     # fingerprint re-keys compiled plans on a changed hint.
     history_agg_groups: Optional[int] = None
     # -- serving plane (presto_tpu/serving) -------------------------------
-    # share jitted scan/filter/project step callables across DIFFERENT
-    # plans by subtree structural key (serving/fragments.py): queries
-    # sharing a scan→filter→agg subchain reuse one compiled artifact.
-    # Only engages for local compilers (task-scoped shared-jit caches
-    # keep their node-id keys); a fingerprinted field, so flipping it
-    # re-keys the canonical plan cache
+    # take every jitted program from the process-wide cache
+    # (serving/fragments.py) under its subtree's structural key: a
+    # worker's tasks, the next query with the same text, rebuilt pooled
+    # compilers and different plans sharing a scan→filter→agg subchain
+    # reuse one jax.jit object (PlanCompiler.shared_jit).  Off: a fresh
+    # jit per compiler.  The in-process batch scheduler's stage cache
+    # keeps its node-id keys either way; a fingerprinted field, so
+    # flipping it re-keys the canonical plan cache
     fragment_share: bool = True
 
 
@@ -611,7 +613,7 @@ class _RevocableBuildBuffer:
 def _fragment_batch_sig(batch: Batch) -> tuple:
     """Hashable digest of the first-batch column structure a step's
     expression resolution depends on (laziness, dictionary presence,
-    dtypes) — part of the fragment_jit cache key, so structurally equal
+    dtypes) — part of the shared_jit cache key, so structurally equal
     subtrees whose resolution would differ never share a callable.
     Shape is deliberately EXCLUDED: jax.jit retraces per aval."""
     out = []
@@ -636,45 +638,59 @@ class PlanCompiler:
         self._sources: Dict[str, BatchSource] = {}
         self.lowering = Lowering()
         self._jit_cache: Dict = {}
+        # shared_jit's memos: id(node) -> (node, named structural key),
+        # and the config's fingerprint
+        self._structures: Dict[int, tuple] = {}
+        self._config_fp: Optional[str] = None
         # batch buffers of shared (multi-consumer) sources; cleared per
         # execution (see _share)
         self._shared_states: List[dict] = []
 
-    def shared_jit(self, key, fn, **kw):
-        """jax.jit with a per-stage shared cache: tasks of one stage share
-        ONE traced program per (node id, purpose) key instead of each
-        re-tracing an identical closure (TaskContext.shared_jits).  Falls
-        back to a plain jit when no stage cache is installed."""
-        # key = (node id, purpose, ...): the purpose alone names the
-        # program (structural: the node id never enters a program name)
-        cache = self.ctx.shared_jits
-        if cache is None:
-            return named_jit(key[1], fn, **kw)
-        ent = cache.get(key)
-        if ent is None:
-            ent = cache.setdefault(key, named_jit(key[1], fn, **kw))
-        return ent
+    def shared_jit(self, node, purpose: str, fn, extra=(), **kw):
+        """`named_jit(purpose, fn)` for a program compiled from `node`,
+        shared with everyone who compiles the same thing.
 
-    def fragment_jit(self, node, purpose: str, fn, extra=(), **kw):
-        """Fragment-level executable sharing (serving/fragments.py):
-        jitted step callables for linear scan/filter/project fragments
-        are cached PROCESS-GLOBALLY on the subtree's structural key, so
-        two different plans sharing a scan→filter subchain share one
-        compiled artifact.  Falls back to shared_jit whenever a stage
-        cache is installed (distributed tasks) or the fragment_share
-        knob is off.  `extra` must carry every host constant the traced
-        closure bakes in beyond (subtree, config) — chunk capacity,
-        first-batch laziness/dictionary signature — since a false share
-        would execute the wrong program, while a missed share only costs
-        one retrace."""
+        In the in-process batch scheduler the tasks of one stage share
+        ONE traced program per (node id, purpose, extra) through the
+        stage's `TaskContext.shared_jits`.  Everywhere else -- a worker
+        task (a new PlanCompiler every task), the single-node runner's
+        pooled compilers -- the program comes from the process-wide
+        cache (serving/fragments.py) under the STRUCTURAL key
+        `(purpose, subtree with node ids blanked, its real variable
+        names, extra, config fingerprint)`: a second task of the stage,
+        or the next query with the same text, gets the same jax.jit
+        object and traces, lowers and loads nothing.  The
+        `fragment_share` knob off means a fresh jit per compiler.
+
+        `extra` must carry every host constant the traced closure bakes
+        in beyond (subtree, names, config) -- chunk capacity, first-batch
+        laziness/dictionary signature, join fanouts, the direct mode's
+        G and strides, `ctx.task_index` where the program reads it, the
+        operator-stats variant -- since a false share would execute the
+        wrong program, while a missed share only costs one retrace.  And
+        `fn` must reach nothing of this task: no `self`, no `self.ctx`
+        (the cache outlives the task and must neither pin it nor write
+        into it)."""
+        cache = self.ctx.shared_jits
+        if cache is not None:
+            key = (node.id, purpose) + tuple(extra)
+            ent = cache.get(key)
+            if ent is None:
+                ent = cache.setdefault(key, named_jit(purpose, fn, **kw))
+            return ent
         cfg = self.ctx.config
-        if self.ctx.shared_jits is not None or not cfg.fragment_share:
-            return self.shared_jit((node.id, purpose) + tuple(extra), fn,
-                                   **kw)
+        if not cfg.fragment_share:
+            return named_jit(purpose, fn, **kw)
         from ..serving.fragments import FRAGMENT_JIT_CACHE
-        from ..sql.canonical import config_fingerprint
-        key = (purpose, P.structural_key(node), tuple(extra),
-               config_fingerprint(cfg))
+        if self._config_fp is None:
+            from ..sql.canonical import config_fingerprint
+            self._config_fp = config_fingerprint(cfg)
+        structure = self._structures.get(id(node))
+        if structure is None:
+            # the node rides along so its id() cannot be reused
+            structure = self._structures[id(node)] = \
+                (node, P.named_structural_key(node))
+        key = (purpose,) + structure[1] + (tuple(extra), self._config_fp)
         return FRAGMENT_JIT_CACHE.get_or_build(
             key, lambda: named_jit(purpose, fn, **kw))
 
@@ -942,7 +958,7 @@ class PlanCompiler:
         # so plans sharing this scan share one compiled program.  The
         # ACTUAL output variable names are baked into the closure but
         # canonicalized away by the structural key, so they join the key
-        dev_make = self.fragment_jit(node, "scan_make", make,
+        dev_make = self.shared_jit(node, "scan_make", make,
                                      extra=(cap, tuple(names)))
 
         def split_chunks(split):
@@ -982,7 +998,7 @@ class PlanCompiler:
                 c = batch.columns[name]
                 keep = batch.mask & (c.values >= lo) & (c.values <= hi)
                 return batch.with_mask(keep), keep.sum(), batch.mask.sum()
-            return self.shared_jit((node.id, "rf", name), _step)
+            return self.shared_jit(node, "rf", _step, extra=(name,))
 
         def apply_runtime_filters(batches):
             engaged = False
@@ -1196,6 +1212,11 @@ class PlanCompiler:
                 for r, f in zip(rows, frags):
                     total += int(r)
                     conn.staged(f).commit()
+            # the table changed under every cached program that was
+            # probed against its old contents: on a worker this commit is
+            # the DDL (the runner's own is _invalidate_plans)
+            from ..serving.fragments import FRAGMENT_JIT_CACHE
+            FRAGMENT_JIT_CACHE.invalidate_all()
             cols = {node.outputs[0].name:
                     Column(jnp.asarray(np.array([total], dtype=np.int64)))}
             yield Batch(cols, jnp.asarray(np.array([True])))
@@ -1266,15 +1287,15 @@ class PlanCompiler:
                     def pstep(batch, params, _pred=pred):
                         return ops.apply_filter(
                             batch, low.eval(_pred, batch.with_params(params)))
-                    jitted = self.fragment_jit(node, "filter_p", pstep,
-                                               extra=(sig,))
+                    jitted = self.shared_jit(node, "filter_p", pstep,
+                                             extra=(sig,))
                     cache["step"] = \
                         lambda b, _j=jitted: _j(b, self.ctx.params)
                 else:
                     def step(batch, _pred=pred):
                         return ops.apply_filter(batch, low.eval(_pred, batch))
-                    cache["step"] = self.fragment_jit(node, "filter", step,
-                                                      extra=(sig,))
+                    cache["step"] = self.shared_jit(node, "filter", step,
+                                                    extra=(sig,))
                 cache["hoisted"] = hoisted
             step, hoisted = cache["step"], cache["hoisted"]
             for b in itertools.chain([first], it):
@@ -1304,8 +1325,8 @@ class PlanCompiler:
                         cols = {v.name: low.eval(e, pb)
                                 for (v, _), e in zip(items, _exprs)}
                         return Batch(cols, batch.mask)
-                    jitted = self.fragment_jit(node, "project_p", pstep,
-                                               extra=(sig, tuple(names)))
+                    jitted = self.shared_jit(node, "project_p", pstep,
+                                             extra=(sig, tuple(names)))
                     cache["step"] = \
                         lambda b, _j=jitted: _j(b, self.ctx.params)
                 else:
@@ -1313,7 +1334,7 @@ class PlanCompiler:
                         cols = {v.name: low.eval(e, batch)
                                 for (v, _), e in zip(items, _exprs)}
                         return Batch(cols, batch.mask)
-                    cache["step"] = self.fragment_jit(
+                    cache["step"] = self.shared_jit(
                         node, "project", step, extra=(sig, tuple(names)))
                 cache["hoisted"] = hoisted
             step, hoisted = cache["step"], cache["hoisted"]
@@ -1391,7 +1412,7 @@ class PlanCompiler:
                     & (j[None, :] < rowlen[:, None])).reshape(cap * W)
             return Batch(cols, mask)
 
-        step = self.shared_jit((node.id, "unnest"), step)
+        step = self.shared_jit(node, "unnest", step)
 
         def gen():
             for b in src.batches():
@@ -1405,7 +1426,7 @@ class PlanCompiler:
         n = node.count
 
         step = self.shared_jit(
-            (node.id, "limit"),
+            node, "limit",
             lambda batch, consumed: ops.limit(batch, n, consumed))
 
         def gen():
@@ -1426,8 +1447,8 @@ class PlanCompiler:
             merged = _concat_batches([buffer, batch])
             return ops.topn(merged, keys, n)
 
-        step = self.shared_jit((node.id, "topn_step"), _step)
-        first = self.shared_jit((node.id, "topn_first"),
+        step = self.shared_jit(node, "topn_step", _step)
+        first = self.shared_jit(node, "topn_first",
                                 lambda batch: ops.topn(batch, keys, n))
 
         def gen():
@@ -1716,8 +1737,8 @@ class PlanCompiler:
                                          if expr is not None else None)
                     return ops.agg_direct_update(state, batch, codes,
                                                  agg_cols, specs, G)
-                fn = self.shared_jit((node.id, "agg_direct", G, strides),
-                                     fn)
+                fn = self.shared_jit(node, "agg_direct", fn,
+                                     extra=(G, strides))
                 update_cache[("direct", G, strides)] = fn
             return fn
 
@@ -1735,8 +1756,8 @@ class PlanCompiler:
                     return ops.agg_update(state, batch, key_cols, agg_cols,
                                           specs, num_slots, salt, key_names,
                                           agg_cols2)
-                fn = self.shared_jit((node.id, "agg_upd", num_slots, salt),
-                                     fn)
+                fn = self.shared_jit(node, "agg_upd", fn,
+                                     extra=(num_slots, salt))
                 update_cache[(num_slots, salt)] = fn
             return fn
 
@@ -1999,46 +2020,59 @@ class PlanCompiler:
                                         leaf_cap)
             counts_out["n_chunks"] = len(chunks)
 
-            def loop(key, update, init_state):
-                """fori_loop over scan chunks; the jitted program is cached
-                under `key` so re-executions of the plan skip retracing.
-                Under EXPLAIN ANALYZE the per-operator row counters ride
-                the SAME program as an extra loop-carry output."""
-                key = key + (expands, analyzing)
+            prog = chain.program
+            # what every program below bakes in beyond the aggregation's
+            # subtree and the config: the chain's run-time constants and
+            # what the probe said of the columns it produces
+            chain_sig = prog.signature(expands, leaf_cap) \
+                + (_fragment_batch_sig(probe),)
+
+            def loop(mode, update, init_state, *agg_sig):
+                """fori_loop over scan chunks.  The jitted program comes
+                from shared_jit -- so a second task, or the next query with
+                this text, traces nothing -- and is remembered here so
+                re-executions of this plan skip even the lookup.  `agg_sig`
+                is what `update` bakes in (slot counts, strides).  Under
+                EXPLAIN ANALYZE the per-operator row counters ride the
+                SAME program as an extra loop-carry output."""
+                key = (mode, expands, analyzing) + agg_sig
                 run_all = fused_cache.get(key)
                 if run_all is None:
                     # scan_agg_direct | _static_span | _hash, and the
                     # EXPLAIN ANALYZE variant `<...>_counted`
-                    program = "scan_agg_" + key[0]
+                    program = "scan_agg_" + mode
                     if analyzing:
-                        @jit_as(program + "_counted")
+                        program += "_counted"
+
                         def run_all(pos_arr, cnt_arr, state, aux):
                             def body(i, carry):
                                 st, cnts = carry
-                                b, c = chain.make(
+                                b, c = prog.make(
                                     pos_arr[i], cnt_arr[i], aux, expands,
                                     leaf_cap, with_counts=True)
                                 with jax.named_scope("agg"):
                                     return update(st, b), cnts + c
                             return jax.lax.fori_loop(
                                 0, pos_arr.shape[0], body,
-                                (state, jnp.zeros(1 + len(chain.steps),
+                                (state, jnp.zeros(1 + len(prog.steps),
                                                   dtype=jnp.int64)))
                     else:
-                        @jit_as(program)
                         def run_all(pos_arr, cnt_arr, state, aux):
                             def body(i, st):
-                                b = chain.make(pos_arr[i], cnt_arr[i], aux,
-                                               expands, leaf_cap)
+                                b = prog.make(pos_arr[i], cnt_arr[i], aux,
+                                              expands, leaf_cap)
                                 with jax.named_scope("agg"):
                                     return update(st, b)
                             # chunk count from the traced shape, NOT a
                             # closure constant: param-aware pruning may
-                            # change it between executions (shape change
-                            # -> retrace)
+                            # change it between executions, and the two
+                            # tasks of a stage own different splits
+                            # (shape change -> retrace in the same jit)
                             return jax.lax.fori_loop(0, pos_arr.shape[0],
                                                      body, state)
-                    fused_cache[key] = run_all
+                    run_all = fused_cache[key] = self.shared_jit(
+                        node, program, run_all,
+                        extra=chain_sig + agg_sig)
                 out = run_all(pos_arr, cnt_arr, init_state, aux)
                 if analyzing:
                     out, counts_out["counts"] = out
@@ -2107,8 +2141,8 @@ class PlanCompiler:
                     return ops.agg_direct_update(
                         st, b, stride_codes(b, strides, G),
                         _agg_exprs(b), specs, G)
-                state = loop(("direct",), update,
-                             ops.agg_direct_init(G, specs))
+                state = loop("direct", update,
+                             ops.agg_direct_init(G, specs), G, strides)
                 return ops.agg_direct_finalize(
                     state, specs, key_names, doms, kdts, kdicts,
                     force_row=not key_names)
@@ -2147,8 +2181,8 @@ class PlanCompiler:
                         return ops.agg_span_update(
                             st, b, stride_codes(b, strides, G),
                             _agg_exprs(b), specs, G)
-                    state = loop(("static_span",), update,
-                                 ops.agg_span_init(G, specs))
+                    state = loop("static_span", update,
+                                 ops.agg_span_init(G, specs), G, strides)
                     slot = jnp.arange(G, dtype=jnp.int64)
                     key_arrays = {}
                     stride = G
@@ -2176,11 +2210,10 @@ class PlanCompiler:
                 cand_names = tuple(key_names[i] for i in candidates)
                 spanp = fused_cache.get(("span_probe", cand_names, expands))
                 if spanp is None:
-                    @jit_as("scan_agg_span_probe")
                     def spanp(pos_arr, cnt_arr, aux):
                         def body(i, mm):
-                            b = chain.make(pos_arr[i], cnt_arr[i], aux,
-                                           expands, leaf_cap)
+                            b = prog.make(pos_arr[i], cnt_arr[i], aux,
+                                          expands, leaf_cap)
                             los, his = mm
                             vs = jnp.stack(
                                 [b.columns[k].values.astype(jnp.int64)
@@ -2197,7 +2230,10 @@ class PlanCompiler:
                             0, pos_arr.shape[0], body,
                             (jnp.full(k, ops.INT64_MAX, dtype=jnp.int64),
                              jnp.full(k, ops.INT64_MIN, dtype=jnp.int64)))
-                    fused_cache[("span_probe", cand_names, expands)] = spanp
+                    spanp = fused_cache[
+                        ("span_probe", cand_names, expands)] = \
+                        self.shared_jit(node, "scan_agg_span_probe", spanp,
+                                        extra=chain_sig + (cand_names,))
                 # data-dependent (not shape-only) results are a function
                 # of the bound parameters: key them by fingerprint
                 span_key = ("span_range", cand_names, expands, pfp)
@@ -2247,11 +2283,10 @@ class PlanCompiler:
                         run = fused_cache.get(
                             ("span", G, kname, dep_names, expands))
                         if run is None:
-                            @jit_as("scan_agg_runtime_span")
                             def run(pos_arr, cnt_arr, state, aux, base):
                                 def body(i, st):
-                                    b = chain.make(pos_arr[i], cnt_arr[i],
-                                                   aux, expands, leaf_cap)
+                                    b = prog.make(pos_arr[i], cnt_arr[i],
+                                                  aux, expands, leaf_cap)
                                     codes = b.columns[kname].values \
                                         .astype(jnp.int64) - base
                                     st = ops.agg_span_update(
@@ -2266,8 +2301,11 @@ class PlanCompiler:
                                 dep_ok = ops.depkey_verify(
                                     state, state["__seen"], dep_names)
                                 return state, dep_ok
-                            fused_cache[("span", G, kname, dep_names,
-                                         expands)] = run
+                            run = fused_cache[
+                                ("span", G, kname, dep_names, expands)] = \
+                                self.shared_jit(
+                                    node, "scan_agg_runtime_span", run,
+                                    extra=chain_sig + (G, kname, dep_names))
                         init = {**ops.agg_span_init(G, specs),
                                 **ops.depkey_init(G, dep_names)}
                         state, dep_ok = run(pos_arr, cnt_arr, init,
@@ -2311,11 +2349,10 @@ class PlanCompiler:
                     and pool.try_reserve(est_mat):
                 run = fused_cache.get(("sortagg", expands))
                 if run is None:
-                    @jit_as("scan_agg_sort")
                     def run(pos_arr, cnt_arr, aux):
                         def step(pc):
-                            b = chain.make(pc[0], pc[1], aux, expands,
-                                           leaf_cap)
+                            b = prog.make(pc[0], pc[1], aux, expands,
+                                          leaf_cap)
                             cols = {k: b.columns[k] for k in key_names}
                             for out, col in _agg_exprs(b).items():
                                 if col is not None:
@@ -2337,7 +2374,9 @@ class PlanCompiler:
                             Batch({k: flat.columns[k] for k in key_names},
                                   flat.mask),
                             key_names, inputs, specs, inputs2)
-                    fused_cache[("sortagg", expands)] = run
+                    run = fused_cache[("sortagg", expands)] = \
+                        self.shared_jit(node, "scan_agg_sort", run,
+                                        extra=chain_sig)
                 try:
                     return _maybe_compact(run(pos_arr, cnt_arr, aux))
                 finally:
@@ -2367,9 +2406,9 @@ class PlanCompiler:
                         return ops.agg_update(st, b, kc, _agg_exprs(b),
                                               specs, _n, _s, key_names,
                                               _agg_exprs2(b))
-                    state = loop(("hash", num_slots, salt), update,
+                    state = loop("hash", update,
                                  ops.agg_init(num_slots, specs, key_names,
-                                              key_dtypes))
+                                              key_dtypes), num_slots, salt)
                     if not bool(host_get(state["__collision"],
                                          "agg_hash_collision")):
                         if not key_names and not bool(host_get(
@@ -3128,7 +3167,7 @@ class PlanCompiler:
                 filter_fn=filter_fn, matched=matched)
             return joined, overflow, total, matched
 
-        step = self.shared_jit((node.id, "join_step"), _jstep)
+        step = self.shared_jit(node, "join_step", _jstep)
 
         def shrink(joined, live):
             """Compact a joined batch whose out_capacity padding dominates:
@@ -3209,8 +3248,10 @@ class PlanCompiler:
                     return batch.with_mask(keep)
 
                 df_cache["fn"] = (
-                    self.shared_jit((node.id, "df_bounds"), _bounds),
-                    self.shared_jit((node.id, "df_apply"), _apply))
+                    self.shared_jit(node, "df_bounds", _bounds,
+                                    extra=(names,)),
+                    self.shared_jit(node, "df_apply", _apply,
+                                    extra=(probe_names,)))
             bounds, apply = df_cache["fn"]
             bnds = bounds(build_batch)
             return lambda batch: apply(batch, bnds)
@@ -3241,8 +3282,7 @@ class PlanCompiler:
                     join_type="LEFT" if full else node.join_type,
                     filter_fn=filter_fn, matched=matched)
 
-            step_direct = self.shared_jit((node.id, "join_direct"),
-                                          _jdirect)
+            step_direct = self.shared_jit(node, "join_direct", _jdirect)
 
             def probe_stream_direct(dt, batches, build_batch,
                                     dyn_filter=None):
@@ -3453,18 +3493,23 @@ class PlanCompiler:
         key = node.source_join_variable.name
         fkey = node.filtering_source_join_variable.name
 
-        @jit_as("semi_join_step", static_argnames=("build_has_null",))
+        marker_name = node.semi_join_output.name
+
         def step(batch, table, build_has_null):
             marker = ops.semi_join_mark(batch, table, [key],
                                         build_has_null=build_has_null)
-            return batch.with_columns({node.semi_join_output.name: marker})
+            return batch.with_columns({marker_name: marker})
 
-        @jit_as("semi_join_step_direct",
-                static_argnames=("build_has_null",))
         def step_direct(batch, dt, build_has_null):
             marker = ops.semi_join_mark_direct(
                 batch, dt, key, build_has_null=build_has_null)
-            return batch.with_columns({node.semi_join_output.name: marker})
+            return batch.with_columns({marker_name: marker})
+
+        step = self.shared_jit(node, "semi_join_step", step,
+                               static_argnames=("build_has_null",))
+        step_direct = self.shared_jit(node, "semi_join_step_direct",
+                                      step_direct,
+                                      static_argnames=("build_has_null",))
 
         def gen():
             from .fused import fused_stream

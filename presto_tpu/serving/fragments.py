@@ -1,29 +1,49 @@
-"""Fragment-level executable sharing.
+"""The process-wide cache of jitted programs.
 
-The canonical PlanCache shares compiled state between executions of the
-SAME whole plan.  Queries that differ above a common scan→filter→agg
-subchain still recompile every jitted step from scratch, because the
-per-compiler jit caches key on plan-node ids.  This module is the
-process-global complement: jitted step callables keyed on the
-STRUCTURAL key of the subtree they compile (`spi.plan.structural_key` —
-node ids blanked, variables renamed) plus the execution-config
-fingerprint, so two different plans sharing a fragment share one
-compiled artifact.  `PlanCompiler.fragment_jit` (exec/pipeline.py)
-routes the scan/filter/project step sites here when the
-`fragment_share` config knob is on and the compiler is not running
-under a task-scoped shared-jit cache (distributed tasks keep their
-node-id keyed cache: their fragments are already deduplicated by the
-fragmenter).
+Every jitted program a PlanCompiler builds for a plan node goes through
+`PlanCompiler.shared_jit` (exec/pipeline.py), and -- unless the
+in-process batch scheduler installed its per-stage cache, or the
+`fragment_share` config knob is off -- lands here: ONE `jax.jit` object
+per STRUCTURE, whoever compiles it.  The stage's other task, the next
+query with the same text, a pooled compiler rebuilt after eviction and a
+different plan sharing a scan->filter->agg subchain all get the same
+object back and dispatch through JAX's fast path: no trace, no lowering,
+no executable load.  A worker task builds a new PlanCompiler every time,
+so on coordinator -> worker this cache is what keeps a warm query from
+re-tracing its programs a task.
 
-Safety: a cached callable is a PURE function of its traced arguments —
-bound parameters, scan chunk positions, HBM-resident columns all ride
-as arguments — plus host constants fully determined by (subtree
-structural key, config fingerprint, first-batch signature), which is
-exactly the cache key.  jax.jit's own per-aval retracing handles shape
-and dtype drift between sharers.  DDL clears the cache alongside the
-plan cache (runner._invalidate_plans): generated-connector fragments
-are immutable, but a dropped-and-recreated stored table must not
-resurrect callables probed against the old data's encodings.
+The key is `(purpose, structural key of the subtree, its real variable
+names, extras, config fingerprint)` (`spi.plan.named_structural_key`:
+node ids blanked, literals kept; `sql.canonical.config_fingerprint`).
+What a key must hold: EVERY host constant the traced closure bakes in.
+The subtree and the config cover the plan's own constants (literals,
+expressions, table identity and scale factor, chunk capacity); `extras`
+carry what the site derived at run time -- join fanouts, the leaf
+capacity, the `_counted` (operator stats) variant, the direct/span
+mode's G, strides and domains, the shape probe's column signature, hash
+slots and salt, and the task index where the chain assigns unique ids.
+Split assignment, bound parameters, chunk positions, HBM-resident
+columns and build tables are NOT baked: they ride as traced arguments,
+and jax.jit's own per-aval / per-treedef retracing (column names,
+dictionaries and nullability are part of a Batch's treedef) handles
+their drift inside the one object.  A false share executes the wrong
+program; a missed share costs one retrace.
+
+What a cached callable must NOT hold: the task that built it.  Nothing
+reachable from the traced function may reach a TaskContext (memory
+context, runtime stats, exchange clients, dynamic filters) or the
+PlanCompiler; fused chains hand their closures a `ChainProgram`
+(exec/fused.py) for that reason.  The LRU bounds the plan nodes and
+expressions the closures do keep alive.
+
+Each lookup counts `programCacheHits` / `programCacheMisses` into the
+RuntimeStats that owns the calling thread (rolled up task -> query like
+every other key) and `SERVING_METRICS.fragment_jit_hits/misses`
+process-wide.  A callable a compiler already holds stays valid through
+eviction and `invalidate_all`.  DDL clears the cache alongside the plan
+cache (runner._invalidate_plans): generated-connector fragments are
+immutable, but a dropped-and-recreated stored table must not resurrect
+callables probed against the old data's encodings.
 """
 from __future__ import annotations
 
@@ -31,6 +51,7 @@ from collections import OrderedDict
 from typing import Callable
 
 from ..common.locks import OrderedLock
+from ..utils.runtime_stats import current_stats
 from .metrics import SERVING_METRICS
 
 DEFAULT_FRAGMENT_ENTRIES = 512
@@ -51,16 +72,19 @@ class FragmentJitCache:
         CALL, outside this lock."""
         with self._lock:
             fn = self._entries.get(key)
-            if fn is not None:
+            hit = fn is not None
+            if hit:
                 self._entries.move_to_end(key)
-                SERVING_METRICS.incr("fragment_jit_hits")
-                return fn
-            fn = build()
-            self._entries[key] = fn
-            SERVING_METRICS.incr("fragment_jit_misses")
-            while len(self._entries) > self.max_entries:
-                self._entries.popitem(last=False)
-            return fn
+            else:
+                fn = self._entries[key] = build()
+                while len(self._entries) > self.max_entries:
+                    self._entries.popitem(last=False)
+            SERVING_METRICS.incr(
+                "fragment_jit_hits" if hit else "fragment_jit_misses")
+        stats = current_stats()
+        if stats is not None:
+            stats.add("programCacheHits" if hit else "programCacheMisses", 1)
+        return fn
 
     def invalidate_all(self) -> int:
         with self._lock:

@@ -1011,6 +1011,22 @@ def structural_key(node: PlanNode, canonical_params: bool = False) -> str:
     result caches must NOT — two subtrees bound to different slots of the
     same execution can carry different values, and params_fingerprint
     (whole-vector) would not disambiguate them."""
+    return _structural(node, canonical_params)[0]
+
+
+def named_structural_key(node: PlanNode) -> Tuple[str, Tuple[str, ...]]:
+    """`structural_key(node)` and the subtree's REAL variable names in the
+    order the key numbered them: together they identify a subtree up to
+    node ids alone.  What a process-wide cache of traced closures keys on
+    (`PlanCompiler.shared_jit`): a closure looks columns up, and names
+    its output pytrees, by the real names, so two subtrees that differ
+    only in names are one structure but two programs."""
+    return _structural(node, False)
+
+
+def _structural(node: PlanNode, canonical_params: bool
+                ) -> Tuple[str, Tuple[str, ...]]:
+    """(canonical text, variable names by first occurrence)."""
     rename: Dict[str, str] = {}
     param_rename: Dict[int, int] = {}
 
@@ -1062,4 +1078,5 @@ def structural_key(node: PlanNode, canonical_params: bool = False) -> str:
         return x
 
     import json as _json
-    return _json.dumps(canon(node.to_dict()), sort_keys=True, default=str)
+    text = _json.dumps(canon(node.to_dict()), sort_keys=True, default=str)
+    return text, tuple(rename)

@@ -219,9 +219,12 @@ def _programs_of(sql):
     def recording_jit(fun, *a, **k):
         names.append(fun.__name__)
         return real_jit(fun, *a, **k)
-    from presto_tpu.serving import PlanCache
+    from presto_tpu.serving import FRAGMENT_JIT_CACHE, PlanCache
     runner = LocalQueryRunner("sf0.01", plan_cache=PlanCache(),
                               config=ExecutionConfig(batch_rows=1 << 13))
+    # a program the process has built once is not built again, whoever
+    # asks (serving/fragments.py): start from none to see every name
+    FRAGMENT_JIT_CACHE.invalidate_all()
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "jit", recording_jit)
         runner.execute(sql)
@@ -246,9 +249,10 @@ def test_program_names_are_structural():
 def test_repeated_in_process_query_traces_nothing():
     from presto_tpu.telemetry import jax_events
     jax_events.install()
-    from presto_tpu.serving import PlanCache
+    from presto_tpu.serving import FRAGMENT_JIT_CACHE, PlanCache
     r = LocalQueryRunner("sf0.01", plan_cache=PlanCache(),
                          config=ExecutionConfig(batch_rows=1 << 13))
+    FRAGMENT_JIT_CACHE.invalidate_all()    # `first` builds its programs
     first = r.execute(Q6).runtime_stats
     before = jax_events.PROGRAMS.snapshot()
     second = r.execute(Q6).runtime_stats
