@@ -200,8 +200,8 @@ def generated_lineitem(sf: float, columns) -> dict:
              in get_store(cfg.storage_budget_bytes,
                           cfg.storage_max_column_bytes).entries}
     n = tpch.table_row_count("lineitem", sf)
-    return {col: np.asarray(store._build_full(
-        "tpch", "lineitem", col, sf, n, 0,
+    return {col: np.asarray(store._build_rows(
+        "tpch", "lineitem", col, sf, 0, n, 0,
         built[("lineitem", col, float(sf))])) for col in columns}
 
 
@@ -316,22 +316,29 @@ def run_one_chip(args, device, on_chip: bool):
 
 def run_four_chips(args, devices, on_chip: bool):
     """Q1 (and Q3: see Q3_MAX_SF) through the in-process distributed
-    scheduler on a four-device mesh, rows equal to one device's
-    LocalQueryRunner; the hashed stages must resolve to fabric ici, the
-    all_to_all must engage, and every device must hold data (make_mesh
-    takes jax.devices() in order; code that has only seen a virtual CPU
-    mesh may have left every array on device 0)."""
+    scheduler on a four-device mesh, cold and then warm, rows equal to
+    one device's LocalQueryRunner; the hashed stages must resolve to
+    fabric ici, the all_to_all must engage, every device must hold data
+    (make_mesh takes jax.devices() in order; code that has only seen a
+    virtual CPU mesh may have left every array on device 0) and none may
+    hold the whole of the resident tables: a shard each, which a store
+    that lets every pinned task build the whole column does not leave."""
     from presto_tpu.exec import scheduler as S
     from presto_tpu.exec.runner import (DistributedQueryRunner,
                                         LocalQueryRunner, _assert_rows_equal)
     from presto_tpu.parallel.fabric import FABRIC_METRICS
     from presto_tpu.parallel.mesh import make_mesh
+    from presto_tpu.exec.pipeline import tuned_config
+    from presto_tpu.storage import get_store
     schema = schema_of(args.sf)
     dist = DistributedQueryRunner(schema, n_tasks=4, mesh=make_mesh(4))
     local = LocalQueryRunner(schema)
+    cfg = tuned_config()
+    store = get_store(cfg.storage_budget_bytes, cfg.storage_max_column_bytes)
 
     engaged = []
-    in_use = []      # per query, per device: bytes held after the mesh run
+    in_use = []      # per query, per device: bytes held after the mesh runs
+    resident = []    # per query: bytes of the resident columns, all shards
     ici_exchange = S.InProcessScheduler._ici_exchange
 
     def counting(self, stage, task_batches, keys):
@@ -347,19 +354,28 @@ def run_four_chips(args, devices, on_chip: bool):
             t0 = time.perf_counter()
             got = dist.execute(sql)
             wall = time.perf_counter() - t0
+            # a second, warm run: every task finds its shard where the
+            # first run built it
+            t0 = time.perf_counter()
+            again = dist.execute(sql)
+            warm_wall = time.perf_counter() - t0
             fabric = FABRIC_METRICS.snapshot()["ici"]
             in_use.append([(d.memory_stats() or {}).get("bytes_in_use")
                            for d in devices])
+            resident.append(sum(e.nbytes for e in store.entries.values()))
+            # (the one-device run rebuilds the columns whole on device 0)
             want = local.execute(sql)
             _assert_rows_equal(got, want, ordered=True)
+            _assert_rows_equal(again, want, ordered=True)
             emit(phase="four_chips", query=name, schema=schema,
                  rows=len(got.rows), equals_one_device=True,
-                 wall_s_including_compile=wall,
+                 wall_s_including_compile=wall, warm_wall_s=warm_wall,
+                 resident_column_bytes=resident[-1],
                  ici_stages=[list(e) for e in engaged], ici=fabric)
             assert engaged and all(
                 f == "ici" and ok for _fid, f, ok in engaged), \
                 f"{name}: hashed stages did not ride ici: {engaged}"
-            assert fabric["exchanges"] >= 1 and fabric["fallbacks"] == 0 \
+            assert fabric["exchanges"] >= 2 and fabric["fallbacks"] == 0 \
                 and fabric["host_bytes"] == 0, fabric
     finally:
         S.InProcessScheduler._ici_exchange = ici_exchange
@@ -370,6 +386,12 @@ def run_four_chips(args, devices, on_chip: bool):
     if on_chip:
         assert all(b is not None and b > 0 for q in in_use for b in q), \
             f"a device of the mesh holds nothing: bytes_in_use {in_use}"
+        # a quarter of the resident bytes and what the programs keep; the
+        # whole of them on every device is the replicated build
+        assert all(b < held / 2 for q, held in zip(in_use, resident)
+                   for b in q), \
+            f"a device holds more than its shard: bytes_in_use {in_use} " \
+            f"of resident columns {resident}"
 
 
 def main():

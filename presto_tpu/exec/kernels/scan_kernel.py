@@ -572,6 +572,10 @@ def chain_eligible(chain, aux, declined, allow_joins: bool = False):
     if not colmap or any(colmap[n] not in cached for n in colmap):
         declined("ColumnsNotResident")
         return None
+    if any(cached[colmap[n]].base is not None for n in colmap):
+        # a mesh shard: the kernels index the arrays by table position
+        declined("ColumnsNotResident")
+        return None
     return cached, colmap
 
 

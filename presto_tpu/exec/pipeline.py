@@ -423,6 +423,11 @@ class TaskContext:
     remote_batches: Dict[str, Callable[[], Iterator["Batch"]]] = field(default_factory=dict)
     # this task's index in its stage: namespaces AssignUniqueId across tasks
     task_index: int = 0
+    # the mesh's devices when this task is one of a source stage pinned
+    # task i -> device i (exec/scheduler.py): its scans then read shard
+    # `task_index` of the resident columns, which holds exactly the rows
+    # of its splits (storage/store.py)
+    mesh_devices: Optional[tuple] = None
     # per-STAGE shared jitted-program cache (scheduler-provided): the N
     # tasks of a stage compile byte-identical step closures, and Python
     # tracing is GIL-serialized — without sharing, an N-task stage pays
@@ -728,14 +733,6 @@ class PlanCompiler:
     def run_to_pages(self, root: P.PlanNode) -> Iterator[Page]:
         yield from self.source_to_pages(self.compile_root(root))
 
-    def run_to_batches(self, root: P.PlanNode) -> Iterator[Batch]:
-        """Device-resident drain of the fragment (the ICI exchange path:
-        output rows stay in HBM for the cross-device shuffle)."""
-        for st in self._shared_states:
-            st.update(buf=[], it=None, done=False)
-        src = self.compile(root)
-        yield from src.batches()
-
     # -- dispatch ---------------------------------------------------------
     def _compile(self, node: P.PlanNode) -> BatchSource:
         # memoized per node id: replayed subtrees (decorrelation deep
@@ -892,16 +889,26 @@ class PlanCompiler:
             store = get_store(cfg.storage_budget_bytes,
                               cfg.storage_max_column_bytes)
             n_rows = catalog.table_row_count(table, sf, cid)
-            for _name, colname, kind in dev:
-                if kind != "gen":
-                    continue
+            mesh_devices = self.ctx.mesh_devices
+            shard = self.ctx.task_index if mesh_devices else 0
+            wanted = [colname for _name, colname, kind in dev
+                      if kind == "gen"]
+            # each task of a pinned stage starts at another column, so a
+            # cold store builds (and compiles the generators of) as many
+            # columns at once as there are tasks
+            for colname in wanted[shard:] + wanted[:shard]:
                 ent = store.get_or_build(
                     cid, table, colname, sf, n_rows, cap, i32[colname],
                     zone_rows=cfg.storage_zone_rows,
-                    encodings=cfg.storage_encodings)
-                if ent is not None:
-                    cached_cols[colname] = ent.column
-                    zone_maps[colname] = ent.zones
+                    encodings=cfg.storage_encodings, devices=mesh_devices)
+                if ent is None:
+                    continue
+                col, zones = ent.shards[shard]
+                if all(zones.base <= s.start
+                       and s.end <= zones.base + col.n_rows
+                       for s in splits):
+                    cached_cols[colname] = col
+                    zone_maps[colname] = zones
         # advisory chunk-skip metadata: conjuncts the optimizer pushed
         # down (plan_scan_pushdown) — the parent FilterNode still runs,
         # so pruning only has to be conservative, not exact

@@ -754,12 +754,13 @@ class DistributedQueryRunner(LocalQueryRunner):
             mesh_size=mesh_size(self.mesh),
             batch_mode=self._batch_mode)
 
-    def plan_subplan(self, sql: str, ast=None):
+    def plan_subplan(self, sql: str, ast=None, bound_params=None):
         from ..sql.fragmenter import plan_distributed
         with self._validation():
             if ast is not None:
                 output = Planner(default_schema=self.schema,
-                                 default_catalog=self.catalog) \
+                                 default_catalog=self.catalog,
+                                 bound_params=bound_params) \
                     .plan_query_to_output(ast)
             else:
                 output = self.plan(sql)
@@ -824,23 +825,48 @@ class DistributedQueryRunner(LocalQueryRunner):
         return QueryResult(["Query Plan"], [VarcharType(max(1, len(text)))],
                            [[text]])
 
-    def execute(self, sql: str) -> QueryResult:
+    def execute_streaming(self, sql: str, prepared=None):
+        """Stages hand their output over whole: nothing streams, the
+        statement layer takes `execute`'s rows."""
+        return None
+
+    def execute_prepared_batch(self, sqls, prepared=None):
+        """No lane is batched: every EXECUTE runs its own stages."""
+        return None
+
+    def execute(self, sql: str, prepared: Optional[Dict[str, str]] = None
+                ) -> QueryResult:
         from ..sql import parser as A
-        ast = A.parse_sql(sql)
+        from ..utils.runtime_stats import RuntimeStats, current_stats
+        # the statement executor's stats when it set one (the query's
+        # RuntimeStats in QueryInfo), else this execution's own
+        stats = current_stats() or RuntimeStats()
+        with stats.span("queryParse"):
+            ast = A.parse_sql(sql)
         if isinstance(ast, A.Explain):
             return self._explain_distributed(ast, sql=sql)
         if isinstance(ast, (A.CreateTableAs, A.InsertInto, A.DropTable)):
             # writes run single-task through the local pipeline (the
             # reference's scaled-writer distribution is future work)
             return self._execute_ddl(ast)
+        if isinstance(ast, (A.Prepare, A.Deallocate)):
+            return super().execute(sql, prepared)    # the registry only
+        bound = None
+        if isinstance(ast, A.ExecuteStmt):
+            from ..serving import PREPARED_REGISTRY
+            bound = list(ast.values)
+            ast = PREPARED_REGISTRY.get_or_parse(
+                self._prepared_text(ast.name, prepared)).statement
         from contextlib import nullcontext
 
         from ..telemetry import profile_capture
         from .scheduler import InProcessScheduler
         restore = self._apply_history_sizing(ast)
         try:
-            subplan, names, types = self.plan_subplan(sql, ast=ast)
-            sched = InProcessScheduler(self._scheduler_config())
+            with stats.span("queryPlan"):
+                subplan, names, types = self.plan_subplan(
+                    sql, ast=ast, bound_params=bound)
+            sched = InProcessScheduler(self._scheduler_config(), stats)
             tracer = self.tracer_provider.new_tracer(sql) \
                 if self.tracer_provider else None
             if tracer is not None:
@@ -849,7 +875,8 @@ class DistributedQueryRunner(LocalQueryRunner):
                   else nullcontext()):
                 with profile_capture(self.config.profile_dir, "query",
                                      enabled=self.config.profile) \
-                        as trace_dir:
+                        as trace_dir, stats.activate(), \
+                        stats.span("queryExecute"):
                     result = pages_to_result(sched.execute(subplan),
                                              names, types)
         finally:

@@ -45,13 +45,15 @@ class SchedulerConfig:
     # (exec/adaptive.decide_exchange) — mirrors the fragmenter's
     # plan-time FragmenterConfig.broadcast_threshold
     broadcast_threshold: int = 600_000
-    # jax.sharding.Mesh over parallel.mesh.WORKER_AXIS: when set and a
-    # hashed stage's task count equals the mesh size, tasks are pinned
-    # 1:1 to mesh devices and the hash exchange runs as a jitted
-    # all_to_all over ICI (parallel/exchange.py) instead of host-side
-    # page splitting; other edges (gather/broadcast/cross-process) keep
-    # the page path (SURVEY.md §5.8: HTTP stays for the coordinator and
-    # cross-pod edges)
+    # jax.sharding.Mesh over parallel.mesh.WORKER_AXIS: when set, a
+    # source or hashed stage whose task count equals the mesh size has
+    # its tasks pinned 1:1 to mesh devices -- a source task scans the
+    # shard of the resident tables that lives on its device
+    # (storage/store.py), and a hash exchange between two such stages
+    # runs as a jitted all_to_all over ICI (parallel/exchange.py)
+    # instead of host-side page splitting; other edges
+    # (gather/broadcast/cross-process) keep the page path (SURVEY.md
+    # §5.8: HTTP stays for the coordinator and cross-pod edges)
     mesh: object = None
     # BATCH MODE — the Presto-on-Spark analog (SURVEY.md §2.7,
     # PrestoSparkQueryExecutionFactory.java:164): stage outputs
@@ -325,15 +327,16 @@ class InProcessScheduler:
     worker runtime (worker/) and the ICI exchange (parallel/) distribute the
     same stage graph across processes/chips."""
 
-    def __init__(self, config: Optional[SchedulerConfig] = None):
+    def __init__(self, config: Optional[SchedulerConfig] = None,
+                 stats=None):
         import threading
         self.config = config or SchedulerConfig()
         from ..utils.runtime_stats import RuntimeStats
-        # per-query fabric-tagged exchange stats (bytes moved, dispatch /
-        # wait / drain walls), merged into QueryResult.runtime_stats by
-        # DistributedQueryRunner — the RuntimeStats face of the same
-        # surface FABRIC_METRICS exposes process-wide
-        self.stats = RuntimeStats()
+        # the query's RuntimeStats (the caller's, where it has one): the
+        # tasks' spans and counters and the fabric-tagged exchange stats
+        # (bytes moved, dispatch / wait / drain walls) -- the RuntimeStats
+        # face of the surface FABRIC_METRICS exposes process-wide
+        self.stats = stats if stats is not None else RuntimeStats()
         # EXPLAIN ANALYZE sink: set to {} by the caller to collect the
         # per-plan-node operator stats of EVERY task, merged across tasks
         # (rows/bytes/batches/walls summed) — the coordinator-side rollup
@@ -588,14 +591,32 @@ class InProcessScheduler:
                and stage.n_tasks == stage.n_partitions
                and stage.n_tasks == self._mesh_size())
 
+        # lifespan sharding: a grouped-eligible source stage gives every
+        # task the FULL split set plus a disjoint round-robin subset of
+        # the bucket layout — K lifespans spread over N tasks instead of
+        # each task re-bucketing a split subset (which _full_coverage
+        # would reject, forfeiting grouped execution entirely)
+        from .grouped import stage_shards_lifespans
+        grouped_shards = (
+            stage.n_tasks > 1
+            and frag.partitioning == P.SOURCE_DISTRIBUTION
+            and stage_shards_lifespans(frag.root,
+                                       self.config.exec_config))
+        # under a mesh a source stage runs where its data lives: task i
+        # on device i, over the one split that is shard i of the
+        # resident columns (storage/store.py makes the same call)
+        on_shards = (frag.partitioning == P.SOURCE_DISTRIBUTION
+                     and not grouped_shards
+                     and stage.n_tasks == self._mesh_size())
+
         # split assignment per scan node: task i takes splits[i::n]
         scan_splits: Dict[str, List] = {}
         for node in P.walk_plan(frag.root):
             if isinstance(node, P.TableScanNode):
                 th = node.table
                 sf = dict(th.extra).get("scaleFactor", 0.01)
-                n_splits = max(stage.n_tasks,
-                               self.config.exec_config.splits_per_scan)
+                n_splits = stage.n_tasks if on_shards else max(
+                    stage.n_tasks, self.config.exec_config.splits_per_scan)
                 scan_splits[node.id] = catalog.make_splits(
                     th.table_name, sf, n_splits, th.connector_id)
 
@@ -614,7 +635,7 @@ class InProcessScheduler:
             device_inputs[rnode.id] = (
                 all(s.device_out is not None for s in sources)
                 and _device_dicts_agree(sources))
-        pin = (ici or any(device_inputs.values())) \
+        pin = (ici or on_shards or any(device_inputs.values())) \
             and stage.n_tasks == self._mesh_size()
         devices = (list(mesh.devices.flat)
                    if pin or ici else [None] * stage.n_tasks)
@@ -623,6 +644,7 @@ class InProcessScheduler:
         import threading
         import time as _time
         import jax
+        from ..utils.runtime_stats import RuntimeStats
 
         # first terminal task failure aborts siblings and any in-flight
         # ICI consumption promptly (the in-process analog of the worker
@@ -632,20 +654,10 @@ class InProcessScheduler:
         # one traced program per stage, shared by its tasks (the tasks
         # compile byte-identical step closures; Python tracing is
         # GIL-serialized, so without sharing an N-task stage pays N
-        # traces on one core — PlanCompiler.shared_jit)
-        stage_jits: Dict = {}
-
-        # lifespan sharding: a grouped-eligible source stage gives every
-        # task the FULL split set plus a disjoint round-robin subset of
-        # the bucket layout — K lifespans spread over N tasks instead of
-        # each task re-bucketing a split subset (which _full_coverage
-        # would reject, forfeiting grouped execution entirely)
-        from .grouped import stage_shards_lifespans
-        grouped_shards = (
-            stage.n_tasks > 1
-            and frag.partitioning == P.SOURCE_DISTRIBUTION
-            and stage_shards_lifespans(frag.root,
-                                       self.config.exec_config))
+        # traces on one core — PlanCompiler.shared_jit).  Under a mesh
+        # (a served node's) tasks take theirs from the process-wide
+        # cache instead, where the next query finds them too
+        stage_jits: Optional[Dict] = None if mesh is not None else {}
 
         def run_task(task_index: int):
             """One task's fragment execution; returns (batch-or-None for
@@ -673,11 +685,16 @@ class InProcessScheduler:
                 else:
                     task_mem = self.memory.new_child(
                         f"task/{stage.fragment.fragment_id}.{task_index}")
+            # a pinned task records into stats of its own, rolled up into
+            # the query's when it ends: what ran on which chip is kept
+            stats = RuntimeStats() if pin else self.stats
             ctx = TaskContext(config=self.config.exec_config,
                               task_index=task_index,
+                              mesh_devices=(tuple(devices) if on_shards
+                                            else None),
                               shared_jits=stage_jits,
                               memory=task_mem,
-                              runtime_stats=self.stats,
+                              runtime_stats=stats,
                               dynamic_filters=self._dyn_filters)
             if self.node_stats is not None:
                 # EXPLAIN ANALYZE: per-node operator stats, merged into
@@ -694,7 +711,7 @@ class InProcessScheduler:
                 if device_inputs[rnode.id] and pin:
                     ctx.remote_batches[rnode.id] = _device_reader(
                         sources, task_index, rnode, abort=abort,
-                        stats=self.stats)
+                        stats=stats)
                 else:
                     ctx.remote_pages[rnode.id] = _remote_reader(
                         sources, task_index,
@@ -711,20 +728,21 @@ class InProcessScheduler:
             out = None
             split_wall, split_bytes = 0.0, 0
             task_sums: Dict[str, object] = {}
-            # the query's stats own this task thread: the pipeline's
-            # launches and host syncs and JAX's events record into them
-            with span_ctx, dev_ctx, self.stats.activate():
+            # the stats own this task thread: the pipeline's launches
+            # and host syncs and JAX's events record into them
+            with span_ctx, dev_ctx, stats.activate():
+                with stats.span("pipelineBuild"):
+                    src = compiler.compile_root(frag.root)
                 if ici:
                     # device path: output stays device-resident; a host
                     # summarization sync here would serialize the async
                     # exchange dispatch, so ICI edges publish nothing
                     # (absent summary == unknown == prune nothing)
                     from .pipeline import _compact_concat
-                    batches = [b for b in
-                               compiler.run_to_batches(frag.root)]
+                    batches = list(src.batches())
                     out = _compact_concat(batches) if batches else None
                 else:
-                    for page in compiler.run_to_pages(frag.root):
+                    for page in compiler.source_to_pages(src):
                         if abort.is_set():
                             raise StageAbortedError(
                                 f"sibling task of stage "
@@ -787,14 +805,19 @@ class InProcessScheduler:
                 FABRIC_METRICS.record(
                     "http", exchanges=1, chunks=1, bytes_moved=split_bytes,
                     host_bytes=split_bytes, exchange_wall_s=split_wall)
-                self.stats.add("exchangeFabricHttpBytes", split_bytes,
-                               "BYTE")
-                self.stats.add("exchangeFabricHttpExchangeWallNanos",
-                               split_wall * 1e9, "NANO")
+                stats.add("exchangeFabricHttpBytes", split_bytes, "BYTE")
+                stats.add("exchangeFabricHttpExchangeWallNanos",
+                          split_wall * 1e9, "NANO")
             wall = _time.perf_counter() - t0  # lint: allow-wall-clock
-            self.stats.add("driverCpuNanos",
-                           (_time.thread_time() - c0) * 1e9, "NANO")
-            self.stats.add("driverWallNanos", wall * 1e9, "NANO")
+            stats.add("driverCpuNanos",
+                      (_time.thread_time() - c0) * 1e9, "NANO")
+            stats.add("driverWallNanos", wall * 1e9, "NANO")
+            if pin:
+                launches = stats.get("pipelineLaunches")
+                stats.add("taskDevice", task_index)
+                stats.add(f"meshTaskLaunches.{task_index}",
+                          launches.sum if launches else 0)
+                self.stats.merge(stats)
             return out, wall
 
         def run_task_retrying(task_index: int):
@@ -854,7 +877,12 @@ class InProcessScheduler:
                                "fabric", None) or "http"))
                      if self.tracer is not None
                      else contextlib.nullcontext())
-        with frag_span:
+        # the root's pull of what the chips computed: from the launch of
+        # a pinned stage's tasks to holding their output as host pages
+        # (an ICI stage's stays on the chips and is no gather)
+        gather = (self.stats.span("meshGather", stage=frag.fragment_id)
+                  if pin and not ici else contextlib.nullcontext())
+        with frag_span, gather:
             if not concurrent:
                 results = [run_task_retrying(i)
                            for i in range(stage.n_tasks)]
@@ -1041,10 +1069,13 @@ class InProcessScheduler:
         FABRIC_METRICS.record("ici", exchanges=1, chunks=n_chunks,
                               bytes_moved=bytes_moved,
                               exchange_wall_s=wall)
-        if rows_cfg < 1:
+        if rows_cfg < 1 and n_chunks > 1:
             # auto-tune feedback: the consumer-side walls land in
             # FABRIC_METRICS as the stage drains, so the fraction seen
-            # here reflects completed exchanges up to this one
+            # here reflects completed exchanges up to this one.  A
+            # one-chunk exchange (Q1's few groups) has nothing to overlap
+            # with and teaches nothing: left to move the size, it walked
+            # a served mesh through every size, a compile each
             ICI_CHUNK_TUNER.observe(FABRIC_METRICS.overlap_fraction("ici"))
         self.stats.add("exchangeFabricIciBytes", bytes_moved, "BYTE")
         self.stats.add("exchangeFabricIciChunks", n_chunks)

@@ -58,18 +58,29 @@ class ResidentColumn:
     columns ride jit argument lists — closing over them would inline
     hundreds of MB as XLA literal constants), the encoding shape is
     static aux data (so the jit cache keys on it).
+
+    A SHARD of a table held over a mesh (store.py) carries `base`, the
+    table row of its row 0, as one more device child: scans keep table
+    positions and `slice_decode` makes them local, so the shards of all
+    devices share one traced program.
     """
 
-    def __init__(self, kind: str, arrays: Tuple, n_rows: int):
+    def __init__(self, kind: str, arrays: Tuple, n_rows: int, base=None):
         self.kind = kind          # "plain" | "dict" | "rle"
         self.arrays = tuple(arrays)
         self.n_rows = int(n_rows)
+        self.base = base          # None: the whole table, row 0 first
 
     # -- chunk decode (traceable; pos may be a tracer) --------------------
     def slice_decode(self, pos, cap: int):
-        """Decode rows [pos, pos+cap) to logical values.  Arrays are
-        tail-padded past n_rows at build time so the dynamic_slice never
-        clamp-shifts at the table edge."""
+        """Decode table rows [pos, pos+cap) to logical values.  Arrays
+        are tail-padded past n_rows at build time so the dynamic_slice
+        never clamp-shifts at the table (or shard) edge."""
+        return self._decode(pos if self.base is None else pos - self.base,
+                            cap)
+
+    def _decode(self, pos, cap: int):
+        """`slice_decode` at a position local to these arrays."""
         if self.kind == "plain":
             (data,) = self.arrays
             return jax.lax.dynamic_slice(data, (pos,), (cap,))
@@ -90,7 +101,7 @@ class ResidentColumn:
         if self.kind == "dict":
             codes, values = self.arrays
             return values[codes.astype(jnp.int32)]
-        return self.slice_decode(jnp.int64(0), self.n_rows)
+        return self._decode(jnp.int64(0), self.n_rows)
 
     # -- accounting -------------------------------------------------------
     @property
@@ -121,12 +132,17 @@ class ResidentColumn:
 
 
 def _rescol_flatten(rc: ResidentColumn):
-    return rc.arrays, (rc.kind, rc.n_rows)
+    if rc.base is None:
+        return rc.arrays, (rc.kind, rc.n_rows, False)
+    return rc.arrays + (rc.base,), (rc.kind, rc.n_rows, True)
 
 
 def _rescol_unflatten(aux, children):
-    kind, n_rows = aux
-    return ResidentColumn(kind, tuple(children), n_rows)
+    kind, n_rows, sharded = aux
+    children = tuple(children)
+    if sharded:
+        return ResidentColumn(kind, children[:-1], n_rows, children[-1])
+    return ResidentColumn(kind, children, n_rows)
 
 
 jax.tree_util.register_pytree_node(
@@ -269,7 +285,7 @@ class ZoneMaps:
     pushdown.prune_chunks with pure numpy — pruning never touches the
     device."""
 
-    __slots__ = ("zmin", "zmax", "null_count", "zone_rows")
+    __slots__ = ("zmin", "zmax", "null_count", "zone_rows", "base")
 
     def __init__(self, zmin: np.ndarray, zmax: np.ndarray,
                  null_count: np.ndarray, zone_rows: int):
@@ -277,9 +293,13 @@ class ZoneMaps:
         self.zmax = zmax
         self.null_count = null_count
         self.zone_rows = int(zone_rows)
+        # table row of zone 0's first row (a shard's: set by the store)
+        self.base = 0
 
     def chunk_bounds(self, pos: int, count: int):
-        """Aggregate (min, max) over the zones covering [pos, pos+count)."""
+        """Aggregate (min, max) over the zones covering table rows
+        [pos, pos+count)."""
+        pos -= self.base
         z0 = pos // self.zone_rows
         z1 = (pos + count - 1) // self.zone_rows
         z1 = min(z1, len(self.zmin) - 1)

@@ -25,6 +25,10 @@ def main(argv=None) -> int:
                         default=None,
                         help="also host the embedded discovery service")
     parser.add_argument("--environment", default=None)
+    parser.add_argument("--devices", type=int, default=None,
+                        help="chips this node owns: more than one makes a "
+                             "single-node coordinator run its statements "
+                             "over a mesh of them")
     parser.add_argument("--hive-warehouse", default=None, metavar="DIR",
                         help="mount a Parquet warehouse directory as the "
                              "'hive' catalog (CREATE TABLE AS / INSERT)")
@@ -53,7 +57,8 @@ def main(argv=None) -> int:
     for k, v in (("port", args.http_port), ("node_id", args.node_id),
                  ("coordinator", args.coordinator),
                  ("discovery_uri", args.discovery_uri),
-                 ("environment", args.environment)):
+                 ("environment", args.environment),
+                 ("devices", args.devices)):
         if v is not None:
             kwargs[k] = v
     if args.etc_dir:

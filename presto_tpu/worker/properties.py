@@ -226,6 +226,8 @@ class SystemConfig:
         ("node.id", str, ""),
         ("node.location", str, ""),
         ("node.pool", str, "DEFAULT"),               # NodePoolType.java
+        # chips this node owns (WorkerServer `devices`): > 1 = a mesh
+        ("node.devices", int, 1),
         ("task.max-drivers-per-task", int, 16),
         ("task.concurrent-lifespans-per-task", int, 1),
         ("task.writer-count", int, 1),
@@ -369,6 +371,11 @@ def server_kwargs_from_etc(etc_dir: str) -> Tuple[dict, Dict[str, str]]:
         kwargs["coordinator"] = _bool(props["coordinator"])
     if "discovery.uri" in props:
         kwargs["discovery_uri"] = props["discovery.uri"]
+    if "node.devices" in props:
+        n = int(props["node.devices"])
+        if n < 1:
+            raise ValueError(f"node.devices must be >= 1, got {n}")
+        kwargs["devices"] = n
     if "announcement-interval-ms" in props:
         kwargs["announce_interval_s"] = \
             int(props["announcement-interval-ms"]) / 1000.0

@@ -1006,9 +1006,15 @@ class WorkerServer:
                  telemetry_metrics_interval_s: float = 0.0,
                  history_path: Optional[str] = None,
                  history_max_count: int = 200,
-                 history_max_age_s: Optional[float] = None):
+                 history_max_age_s: Optional[float] = None,
+                 devices: int = 1):
         self.environment = environment
         self.coordinator = coordinator
+        # chips this node owns.  More than one, and a coordinator with no
+        # worker announced runs its statements as stages of `devices`
+        # tasks over a mesh of them, task i on chip i over the shard of
+        # the resident tables that lives there (exec/scheduler.py)
+        self.devices = int(devices)
         self.state = "ACTIVE"            # ACTIVE | SHUTTING_DOWN
         self.discovery: Optional[Dict[str, dict]] = {} if coordinator else None
         self.discovery_lock = threading.Lock()
@@ -1246,6 +1252,12 @@ class WorkerServer:
                                              failure_detector=det,
                                              catalog=catalog)
                     self.failure_detector = det
+                elif self.devices > 1:
+                    from ..exec.runner import DistributedQueryRunner
+                    from ..parallel.mesh import make_mesh
+                    runner = DistributedQueryRunner(
+                        schema, config=cfg, n_tasks=self.devices,
+                        catalog=catalog, mesh=make_mesh(self.devices))
                 else:
                     from ..exec.runner import LocalQueryRunner
                     runner = LocalQueryRunner(schema, config=cfg,

@@ -93,8 +93,16 @@ def _capture(sql, **config):
         "sf0.01", plan_cache=PlanCache(),
         config=ExecutionConfig(batch_rows=BATCH_ROWS,
                                join_out_capacity=1 << 21, **config))
-    with pytest.raises(_Captured) as cap:
-        runner.execute(sql)
+    # the launcher has to be BUILT here to be captured: an earlier test of
+    # this process may have left the program in the process-wide cache,
+    # and the stand-in must not stay there for a later one
+    from presto_tpu.serving import FRAGMENT_JIT_CACHE
+    FRAGMENT_JIT_CACHE.invalidate_all()
+    try:
+        with pytest.raises(_Captured) as cap:
+            runner.execute(sql)
+    finally:
+        FRAGMENT_JIT_CACHE.invalidate_all()
     return cap.value.fn, cap.value.args
 
 
