@@ -596,9 +596,11 @@ def agg_direct_update(state: dict, batch: Batch, codes,
                       agg_inputs: Dict[str, Optional[Column]],
                       specs: Tuple[AggSpec, ...], G: int) -> dict:
     """codes: combined group code per row (int, < G).  A Pallas MXU
-    grouped-sum kernel was benchmarked here and DELETED: the one-hot grid
-    below fuses into the surrounding program and measured faster on chip
-    (0.166s vs 0.191s, TPC-H Q1 SF10 warm)."""
+    grouped-sum kernel was tried here and DELETED (no Pallas family
+    compiles for the v5e, kernels.KERNEL_FAMILY_COMPILES): the one-hot
+    grid below fuses into the surrounding program: of Q1's 0.114 s pass
+    over SF10's 60M rows on the v5e, each grouped-sum fusion is 2.6 ms
+    (PERF.md section 5, PR 29)."""
     grid = (codes[None, :] == jnp.arange(G, dtype=codes.dtype)[:, None]) \
         & batch.mask[None, :]
     out = dict(state)

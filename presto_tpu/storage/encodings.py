@@ -2,27 +2,27 @@
 
 A `ResidentColumn` is the device half of the resident storage layer
 (store.py): one whole-table column materialized ONCE into HBM in an
-encoded physical form, decoded per scan chunk INSIDE the fused kernel.
-The point is bandwidth: a fused Q1 scan is HBM-bound, and what streams
-out of HBM is the *encoded* bytes — dictionary codes are int8/int16
-where the logical column is 8 bytes wide, so the same query reads a
-fraction of the traffic.  Decode (a small-table gather, or a
-searchsorted over run starts) happens in vector registers after the
-chunk's `dynamic_slice`, which is the classic late-materialization
-trade: spend VPU cycles, save HBM bytes.
+encoded physical form, decoded per scan chunk INSIDE the fused program,
+after the chunk's `dynamic_slice`.  Selection counts what the decode
+costs on the chip, not only the bytes.  On the v5e the fused scan is
+nowhere near HBM-bound (under 1 % of its roofline, PERF.md), and a
+decode XLA leaves as a per-row gather costs 7 ns a row where the 2 bytes
+it saves stream in 2.4 ps: an int16-coded `l_shipdate` was 0.43 s of the
+0.51 s of a 60M-row Q6 (PR 29).  So a column is encoded only where its
+decode stays elementwise, and is otherwise plain in its logical dtype.
 
 Three encodings, mirroring the engine's host Block hierarchy
 (common/block.py DictionaryBlock / RunLengthBlock / FixedWidthBlock):
 
 - ``plain``  — the padded device array as-is.
-- ``dict``   — sorted distinct values + per-row codes (int8 when the
-  cardinality fits in 7 bits, else int16).  Exact: decode is
-  ``values[codes]``.
+- ``dict``   — sorted distinct values (at most DICT_MAX_NDV) + per-row
+  int8 codes.  Exact: decode is ``values[codes]``, which XLA expands
+  into a select chain over a table this small.
 - ``rle``    — run values + run start offsets for sorted/monotone
   columns (tpcds ``ws_order_number``-style co-bucket layouts).  Decode
   is ``values[searchsorted(starts, row) - 1]`` — log2(runs) gathers per
-  element, so it is only selected when runs compress heavily (the run
-  table then lives in cache) or a connector hint forces it.
+  element (the per-row gather again: ROADMAP queue 1 item 3), so it is
+  only selected when runs compress heavily or a connector hint forces it.
 
 Zone maps (per-zone min/max/null-count at a fixed row granularity) are
 built HERE, from the exact decoded values, on device, and brought to
@@ -39,10 +39,12 @@ import numpy as np
 
 from ..utils.runtime_stats import host_get
 
-# dictionary codes wider than int16 would erase most of the byte win
-DICT_MAX_NDV = 1 << 15
-# cheap cardinality probe before paying a full-column jnp.unique sort
-DICT_PROBE_ROWS = 1 << 18
+# the largest table whose `values[codes]` the TPU compiler expands into
+# selects; one entry more and the decode is a real gather in the scan
+# loop (tests/test_chip_compile.py holds the constant to the compiler)
+DICT_MAX_NDV = 64
+# cheap cardinality probe before paying a full-column check or sort
+DICT_PROBE_ROWS = 1 << 16
 # without a connector hint, RLE must compress >= this factor: decode
 # pays log2(runs) gathers per element, so the run table must be small
 # enough to stay cache-resident
@@ -199,25 +201,25 @@ def encode_column(arr, n_rows: int, encodings: bool = True,
     # tens of seconds per dtype and size
     probe = np.unique(host_get(body[:DICT_PROBE_ROWS],
                                "storage_dict_probe"))
-    if hint == "dict" or probe.shape[0] <= DICT_MAX_NDV:
+    if probe.shape[0] <= DICT_MAX_NDV:
         values = _distinct_values(body, probe)
         ndv = int(values.shape[0])
-        if ndv <= DICT_MAX_NDV:
-            code_dtype = jnp.int8 if ndv <= 127 else jnp.int16
-            dict_bytes = (arr.shape[0] * np.dtype(code_dtype).itemsize
-                          + ndv * itemsize)
-            # the values table is resident too: near-unique columns on a
-            # small table pass the NDV cap yet net MORE bytes than plain
-            if np.dtype(code_dtype).itemsize < itemsize \
-                    and dict_bytes < plain_bytes:
-                # pad rows code to an arbitrary slot (dead rows are
-                # masked by the scan's live predicate); clip keeps the
-                # decode gather in-bounds either way
-                codes = jnp.clip(
-                    jnp.searchsorted(values, arr), 0, ndv - 1
-                ).astype(code_dtype)
-                return ResidentColumn("dict", (codes, values), n_rows)
+        if _dict_pays(ndv, arr.shape[0], n_rows, itemsize):
+            # pad rows code to an arbitrary slot (dead rows are masked by
+            # the scan's live predicate); clip keeps the decode in-bounds
+            codes = jnp.clip(jnp.searchsorted(values, arr), 0, ndv - 1
+                             ).astype(jnp.int8)
+            return ResidentColumn("dict", (codes, values), n_rows)
     return ResidentColumn("plain", (arr,), n_rows)
+
+
+def _dict_pays(ndv: int, padded_rows: int, n_rows: int,
+               itemsize: int) -> bool:
+    """A dictionary of `ndv` values is taken when its decode is a select
+    chain and its int8 codes plus the values table (resident too: a
+    near-unique tiny table can net MORE bytes) are fewer bytes."""
+    return ndv <= DICT_MAX_NDV \
+        and padded_rows + ndv * itemsize < n_rows * itemsize
 
 
 def _distinct_values(body, probe: np.ndarray):
@@ -225,13 +227,11 @@ def _distinct_values(body, probe: np.ndarray):
     DICT_PROBE_ROWS rows.  When every row is one of the probe's values
     (a binary search and a compare, no sort) they are the distinct set;
     only a column that goes on to new values pays the full-column sort."""
-    if probe.shape[0] <= DICT_MAX_NDV:
-        values = jnp.asarray(probe)
-        slot = jnp.clip(jnp.searchsorted(values, body),
-                        0, values.shape[0] - 1)
-        # build-time stat, one sync per column per process
-        if host_get((values[slot] == body).all(), "storage_dict_check"):
-            return values
+    values = jnp.asarray(probe)
+    slot = jnp.clip(jnp.searchsorted(values, body), 0, values.shape[0] - 1)
+    # build-time stat, one sync per column per process
+    if host_get((values[slot] == body).all(), "storage_dict_check"):
+        return values
     return jnp.unique(body)
 
 
@@ -256,21 +256,15 @@ def _encode_column_host(arr, host: np.ndarray, n_rows: int,
         return ResidentColumn("rle", (run_values, run_starts), n_rows)
 
     values_h = np.unique(body[:DICT_PROBE_ROWS])
-    if hint == "dict" or values_h.shape[0] <= DICT_MAX_NDV:
+    if values_h.shape[0] <= DICT_MAX_NDV:
         values_h = np.unique(body)
         ndv = int(values_h.shape[0])
-        if ndv <= DICT_MAX_NDV:
-            code_dtype = np.int8 if ndv <= 127 else np.int16
-            dict_bytes = (host.shape[0] * np.dtype(code_dtype).itemsize
-                          + ndv * itemsize)
-            if np.dtype(code_dtype).itemsize < itemsize \
-                    and dict_bytes < plain_bytes:
-                codes_h = np.clip(
-                    np.searchsorted(values_h, host), 0, ndv - 1
-                ).astype(code_dtype)
-                return ResidentColumn(
-                    "dict", (jnp.asarray(codes_h), jnp.asarray(values_h)),
-                    n_rows)
+        if _dict_pays(ndv, host.shape[0], n_rows, itemsize):
+            codes_h = np.clip(np.searchsorted(values_h, host), 0, ndv - 1
+                              ).astype(np.int8)
+            return ResidentColumn(
+                "dict", (jnp.asarray(codes_h), jnp.asarray(values_h)),
+                n_rows)
     return ResidentColumn("plain", (arr,), n_rows)
 
 

@@ -151,6 +151,11 @@ class ResidentEntry:
     def zones(self) -> ZoneMaps:
         return self.shards[0][1]
 
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The encoding each shard's encoder chose, in shard order."""
+        return tuple(col.kind for col, _zones in self.shards)
+
 
 class ResidentStore:
     """LRU cache of ResidentEntry keyed (connector, table, column, sf,
@@ -217,9 +222,14 @@ class ResidentStore:
                 owner.add("storageShardBuilds", len(ranges))
             with (owner.span("storageBuild", table=table, column=colname)
                   if owner is not None else contextlib.nullcontext()):
-                return self._build(key, cid, table, colname, sf, ranges,
-                                   pad, as_i32, zone_rows, encodings,
-                                   devices)
+                ent = self._build(key, cid, table, colname, sf, ranges,
+                                  pad, as_i32, zone_rows, encodings,
+                                  devices)
+            if owner is not None and ent is not None:
+                # what the encoder chose, a shard: plain / dict / rle
+                for kind in ent.kinds:
+                    owner.add(f"storageEncoding.{kind}", 1)
+            return ent
 
     def _build(self, key, cid, table, colname, sf, ranges, pad, as_i32,
                zone_rows, encodings, devices) -> Optional[ResidentEntry]:

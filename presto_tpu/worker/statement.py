@@ -362,7 +362,11 @@ class DispatchManager:
     (DispatchManager.java:70, createQueryInternal :260)."""
 
     RESULT_CHUNK_ROWS = 4096
-    MAX_QUERY_HISTORY = 200
+    # finished queries kept for GET /v1/query/{id} (query.max-history): a
+    # client that reads QueryInfo after a burst still finds its first
+    # query -- 8 dashboard clients finish ~370 a minute since PR 29, and
+    # the benchmark reads a window's infos when the window is over
+    MAX_QUERY_HISTORY = 1000
 
     def __init__(self, executor: Callable[["ManagedQuery"], "object"],
                  resource_groups: Optional[ResourceGroupManager] = None,

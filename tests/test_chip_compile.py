@@ -88,10 +88,10 @@ def _capturing(fn):
     return stop
 
 
-def _capture(sql, **config):
+def _capture(sql, schema="sf0.01", batch_rows=BATCH_ROWS, **config):
     runner = LocalQueryRunner(
-        "sf0.01", plan_cache=PlanCache(),
-        config=ExecutionConfig(batch_rows=BATCH_ROWS,
+        schema, plan_cache=PlanCache(),
+        config=ExecutionConfig(batch_rows=batch_rows,
                                join_out_capacity=1 << 21, **config))
     # the launcher has to be BUILT here to be captured: an earlier test of
     # this process may have left the program in the process-wide cache,
@@ -163,11 +163,9 @@ def test_kernel_family_matches_static_table(monkeypatch, one_chip, family):
 # what `auto` runs today: the fused XLA chain programs
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("sql", [TPCH_Q6, TPCH_Q1], ids=["q6", "q1"])
-def test_fused_xla_step_compiles_and_fits(monkeypatch, one_chip, sql):
-    """The fused scan -> filter -> project -> agg fori_loop program the
-    default config runs for Q6 / Q1 (exec/pipeline.py `scan_agg_*`) compiles
-    for the v5e at BATCH_ROWS and fits its 16 GB."""
+def _capture_fused(monkeypatch, sql, **capture):
+    """The jitted fused loop of `sql` (exec/pipeline.py `scan_agg_*`) and
+    the arguments of its first call."""
     real_jit = jax.jit
 
     def recording_jit(fun, *a, **k):
@@ -178,14 +176,61 @@ def test_fused_xla_step_compiles_and_fits(monkeypatch, one_chip, sql):
         return jitted
 
     monkeypatch.setattr(jax, "jit", recording_jit)
-    fn, args = _capture(sql)
+    fn, args = _capture(sql, **capture)
     monkeypatch.undo()
+    return fn, args
+
+
+@pytest.mark.parametrize("sql", [TPCH_Q6, TPCH_Q1], ids=["q6", "q1"])
+def test_fused_xla_step_compiles_and_fits(monkeypatch, one_chip, sql):
+    """The fused scan -> filter -> project -> agg fori_loop program the
+    default config runs for Q6 / Q1 compiles for the v5e at BATCH_ROWS and
+    fits its 16 GB.  And at the encodings the served store holds (sf0.1 at
+    the served 64K-row chunk is the least size that takes them: under a
+    1M-row pad every sf0.01 column is plain) no column is decoded by a
+    per-row gather, 7 ns a row on the chip (storage/encodings.py)."""
+    from presto_tpu.storage import ResidentColumn
+    fn, args = _capture_fused(monkeypatch, sql)
     compiled = fn.lower(*_on(one_chip, args)).compile()
     assert "tpu_custom_call" not in compiled.as_text()   # no Pallas in auto
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < need < HBM_BYTES, mem
+
+    fn, args = _capture_fused(monkeypatch, sql, schema="sf0.1",
+                              batch_rows=1 << 16)
+    kinds = [(c.kind, c.dtype) for c in jax.tree_util.tree_leaves(
+        args, is_leaf=lambda x: isinstance(x, ResidentColumn))
+        if isinstance(c, ResidentColumn)]
+    # l_quantity, l_discount, ... as dictionaries; l_shipdate's ~2400 days
+    # and l_extendedprice plain
+    assert ("dict", jnp.int64) in kinds and ("plain", jnp.int32) in kinds
+    assert " gather(" not in fn.lower(*_on(one_chip, args)).compile().as_text()
+
+
+@pytest.mark.parametrize("values_dtype", [jnp.int64, jnp.int32],
+                         ids=["int64", "int32"])
+def test_largest_dictionary_decodes_without_a_gather(one_chip, values_dtype):
+    """DICT_MAX_NDV is the compiler's threshold, not ours: a `dict` column
+    of that many values, decoded chunk by chunk through `slice_decode` as
+    the scan loop does, compiles for the v5e into selects.  A JAX / libtpu
+    upgrade that lowers the threshold fails here, not on the chip."""
+    from presto_tpu.storage.encodings import DICT_MAX_NDV, ResidentColumn
+    chunk, rows = 1 << 16, 1 << 20
+    col = ResidentColumn(
+        "dict", (jnp.zeros(rows + chunk, jnp.int8),
+                 jnp.arange(DICT_MAX_NDV, dtype=values_dtype)), rows,
+        base=jnp.int64(0))
+
+    def scan(col):
+        return jax.lax.fori_loop(
+            0, rows // chunk,
+            lambda i, acc: acc + col.slice_decode(i * chunk, chunk).sum(),
+            jnp.zeros((), jnp.int64))
+
+    compiled = jax.jit(scan).lower(_on(one_chip, col)).compile()
+    assert " gather(" not in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------

@@ -164,6 +164,20 @@ def test_queueing_and_release():
     assert q2.done.wait(5) and q2.state == FINISHED
 
 
+def test_finished_queries_stay_readable_up_to_the_history_limit():
+    gate = threading.Event()
+    gate.set()
+    d = DispatchManager(_slow_executor(gate))
+    assert d.MAX_QUERY_HISTORY >= 400       # benchmark: query_info_limit
+    qs = [d.submit(f"s{i}") for i in range(d.MAX_QUERY_HISTORY + 50)]
+    assert all(q.done.wait(5) for q in qs)
+    d.submit("one more")                    # eviction runs at submit
+    with pytest.raises(KeyError):
+        d.get(qs[0].query_id)               # the oldest finished ones go
+    assert d.get(qs[60].query_id) is qs[60]
+    assert len(d.list_queries()) <= d.MAX_QUERY_HISTORY + 1
+
+
 def test_cancel_queued():
     gate = threading.Event()
     rgm = ResourceGroupManager(

@@ -62,13 +62,59 @@ def test_dict_roundtrip_int8_codes():
     assert col.nbytes < col.logical_nbytes
 
 
-def test_dict_roundtrip_int16_codes():
-    rng = np.random.default_rng(2)
-    body = rng.integers(0, 300, size=1 << 14, dtype=np.int64)
-    col = encode_column(_padded(body), len(body))
-    assert col.kind == "dict"
-    assert col.arrays[0].dtype == jnp.int16  # 127 < ndv <= 32767
-    np.testing.assert_array_equal(_np(col.decode_full())[:len(body)], body)
+# a dictionary only where its decode is a select chain on the chip: NDV
+# at most DICT_MAX_NDV, int8 codes; more distinct values stay plain in the
+# logical dtype (2375 int32: l_shipdate).  (ndv, dtype, kind)
+SELECTION_CASES = [
+    (2, np.int64, "dict"), (64, np.int64, "dict"), (64, np.int32, "dict"),
+    (65, np.int64, "plain"), (65, np.int32, "plain"),
+    (127, np.int64, "plain"), (128, np.int64, "plain"),
+    (2375, np.int32, "plain"), (40000, np.int64, "plain"),
+]
+
+
+@pytest.mark.parametrize("on_host", [False, True], ids=["device", "host"])
+@pytest.mark.parametrize(
+    "ndv,dtype,kind", SELECTION_CASES,
+    ids=[f"ndv{n}-{np.dtype(d).name}" for n, d, _k in SELECTION_CASES])
+def test_encoding_selection_by_ndv(ndv, dtype, kind, on_host):
+    from presto_tpu.storage.encodings import DICT_MAX_NDV
+    assert (ndv <= DICT_MAX_NDV) == (kind == "dict")
+    n = 1 << 17
+    rng = np.random.default_rng(ndv)
+    # every value present, spread so that they are not runs
+    body = (rng.permutation(n) % ndv).astype(dtype) * 3 + 8000
+    arr = _padded(body)
+    col = encode_column(arr, n, host=_np(arr) if on_host else None)
+    assert col.kind == kind
+    assert col.dtype == np.dtype(dtype)
+    if kind == "dict":
+        codes, values = col.arrays
+        assert codes.dtype == jnp.int8
+        assert int(values.shape[0]) == ndv
+        assert col.nbytes < col.logical_nbytes
+    else:
+        assert col.arrays[0].dtype == np.dtype(dtype)   # the logical dtype
+        assert col.nbytes == col.logical_nbytes
+    np.testing.assert_array_equal(_np(col.decode_full())[:n], body)
+    # as a mesh shard: table positions from `base`, an unaligned chunk
+    col.base = jnp.int64(7_000_000)
+    got = _np(col.slice_decode(jnp.int64(7_000_000 + 1234), 512))
+    np.testing.assert_array_equal(got, body[1234:1234 + 512])
+
+
+@pytest.mark.parametrize("ndv,dtype,kind", SELECTION_CASES[1:5],
+                         ids=["ndv64-int64", "ndv64-int32",
+                              "ndv65-int64", "ndv65-int32"])
+def test_host_and_device_encoders_agree(ndv, dtype, kind):
+    n = 1 << 14
+    body = (np.random.default_rng(5).permutation(n) % ndv).astype(dtype)
+    arr = _padded(body)
+    dev, host = encode_column(arr, n), encode_column(arr, n, host=_np(arr))
+    assert dev.kind == host.kind == kind
+    for a, b in zip(dev.arrays, host.arrays):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(_np(a), _np(b))
 
 
 @pytest.mark.parametrize("late_values", [False, True])
@@ -336,6 +382,26 @@ def test_store_hit_reuses_entry():
     e3 = st.get_or_build("tpch", "lineitem", "quantity", 0.01,
                          10_000, 512, False)
     assert e3 is not e1 and e3.pad == 512
+
+
+def test_store_build_counts_the_chosen_encoding():
+    from presto_tpu.utils.runtime_stats import RuntimeStats
+    st = ResidentStore(budget=1 << 24, max_column_bytes=1 << 30)
+    stats = RuntimeStats()
+    with stats.activate():
+        qty = st.get_or_build("tpch", "lineitem", "quantity", 0.01,
+                              10_000, 256, False)
+        ship = st.get_or_build("tpch", "lineitem", "shipdate", 0.01,
+                               10_000, 256, True)
+        st.get_or_build("tpch", "lineitem", "quantity", 0.01,
+                        10_000, 256, False)             # a hit counts none
+    # 50 quantities: a select chain; ~2400 ship dates: plain int32
+    assert qty.kinds == ("dict",) and ship.kinds == ("plain",)
+    assert ship.column.dtype == jnp.int32
+    assert stats.get("storageEncoding.dict").sum == 1
+    assert stats.get("storageEncoding.plain").sum == 1
+    assert stats.get("storageEncoding.rle") is None
+    assert stats.get("storageBuilds").sum == 2
 
 
 # ---------------------------------------------------------------------------

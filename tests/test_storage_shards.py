@@ -115,6 +115,10 @@ def test_tasks_that_miss_a_column_at_once_build_it_once():
     built = [s.get("storageShardBuilds") for s in stats]
     assert sorted(0 if m is None else m.sum for m in built) == [0, N]
     assert sum(s.get("storageBuilds") is not None for s in stats) == 1
+    # the builder counted what each shard's encoder chose
+    chose = [s.get("storageEncoding.dict") for s in stats]
+    assert sorted(0 if m is None else m.sum for m in chose) == [0, N]
+    assert got[0].kinds == ("dict",) * N
     after = STORAGE_METRICS.snapshot()
     assert after["columns_built"] - before["columns_built"] == 1
     assert after["cache_misses"] - before["cache_misses"] == 1
