@@ -13,15 +13,9 @@ vs_baseline = speedup vs the single-threaded numpy reference interpreter
 Env knobs: BENCH_SF (default 10), BENCH_RUNS (default 3),
 BENCH_QUERY (q1|q6|q6z|q3g|q3k|xchg|serve|spill|ft|aqe).
 
-q1/q6/q6z/q1g/q3k lines also carry a "scan_kernel" object: best-of-N
-walls and effective_scan_gbps for the same query pinned to
-scan_kernel=pallas and scan_kernel=xla (plus pallas_vs_xla, the
-xla/pallas wall ratio), so TPU rounds measure the fused Pallas scan
-kernel against the XLA chain directly.
-BENCH_QUERY=q3k is the Q3-shaped probe-join+agg: the orders build
-table rides inside the scan kernel launch (kernels/join.py), so the
-pinned comparison covers the in-kernel join probe alongside the
-scan/agg-only shapes.
+BENCH_QUERY=q3k is the Q3-shaped probe-join+agg without the
+order/limit tail: the fused chain's probe step feeding a grouped
+aggregation.
 
 BENCH_QUERY=serve is the serving-tier benchmark: BENCH_SERVE_CLIENTS
 concurrent statement-protocol clients (default 4) each issuing
@@ -128,7 +122,7 @@ WHERE shipdate >= DATE '1994-01-01'
 """
 
 # high-cardinality grouped Q1 variant: the Q1 aggregate core re-keyed on
-# orderkey % BENCH_Q1G_GROUPS (default 4096), so the scan kernel's
+# orderkey % BENCH_Q1G_GROUPS (default 4096), so the fused scan's
 # grouped modes (span / hashed open addressing) carry the aggregation
 # instead of the direct G<=64 grid; {groups} substituted in main()
 Q1G = """
@@ -157,11 +151,8 @@ GROUP BY l_orderkey
 ORDER BY revenue DESC LIMIT 10
 """
 
-# join-kernel eligible: the same Q3 probe chain (filtered orders build,
-# lineitem probe side) WITHOUT the order/limit tail, grouped on the
-# bucket key — BENCH_QUERY=q3k pins the pallas-vs-xla scan_kernel
-# comparison on it so the real-TPU re-measure covers the in-kernel join
-# probe (kernels/join.py) end to end
+# the same Q3 probe chain (filtered orders build, lineitem probe side)
+# WITHOUT the order/limit tail, grouped on the bucket key
 Q3K = """
 SELECT l_orderkey, sum(l_extendedprice * (1 - l_discount)) AS revenue,
        count(*) AS cnt
@@ -837,8 +828,7 @@ def _process_metrics():
     same registries the telemetry exporter scrapes
     (presto_tpu/telemetry/otlp.py), so each benchmark record carries the
     engine state it ran under: fabric byte movement, serving-cache hit
-    rates, storage-cache hit rate, and the scan-kernel counters."""
-    from presto_tpu.exec.kernels.scan_kernel import KERNEL_METRICS
+    rates and storage-cache hit rate."""
     from presto_tpu.parallel.fabric import FABRIC_METRICS
     from presto_tpu.serving import SERVING_METRICS
     from presto_tpu.storage import STORAGE_METRICS
@@ -850,16 +840,12 @@ def _process_metrics():
         if s["exchanges"]}
     sm = STORAGE_METRICS
     lookups = sm["cache_hits"] + sm["cache_misses"]
-    k = KERNEL_METRICS.snapshot()
     return {
         "device": _device_record(),
         "fabric": fabrics,
         "serving": SERVING_METRICS.compact_snapshot(),
         "storage_cache_hit_rate": round(sm["cache_hits"] / lookups, 4)
         if lookups else 0.0,
-        "kernel": {"scan_programs": k["scan_programs"],
-                   "declined": k["declined"],
-                   "dma_overlap_fraction": k["dma_overlap_fraction"]},
     }
 
 
@@ -1032,45 +1018,6 @@ def main():
         "chunks_total": sm["chunks_total"],
         "chunks_skipped": sm["chunks_skipped"],
     }
-    # Pallas-vs-XLA scan kernel side-by-side: same plan, same resident
-    # data, only the scan hot-path implementation differs.  Each mode gets
-    # its own warmup + best-of-N so the comparison is compile-free on both
-    # sides; kernel_programs counts fused scan programs that actually took
-    # the Pallas path (0 under xla or when every scan declined), and
-    # declined carries the per-reason counters for ineligible scans.
-    if qname in ("q1", "q6", "q6z", "q1g", "q3k"):
-        import dataclasses
-        kcmp = {}
-        for mode in ("pallas", "xla"):
-            kr = LocalQueryRunner(schema=schema, config=dataclasses.replace(
-                runner.config, scan_kernel=mode))
-            kr.execute(sql)           # warmup: compiles this variant
-            kbest = float("inf")
-            for _ in range(runs):
-                t0 = time.perf_counter()
-                kres = kr.execute(sql)
-                kbest = min(kbest, time.perf_counter() - t0)
-            rs = kres.runtime_stats or {}
-            kcmp[mode] = {
-                "wall_s": round(kbest, 4),
-                "rows_per_sec": round(n_rows / kbest, 1),
-                "effective_scan_gbps": round(
-                    n_rows / kbest * col_bytes / 1e9, 2),
-                "kernel_programs": int(
-                    rs.get("kernelScanPrograms", {}).get("sum", 0)),
-                "declined": {
-                    k[len("kernelDeclined"):]: int(v.get("sum", 0))
-                    for k, v in sorted(rs.items())
-                    if k.startswith("kernelDeclined")},
-            }
-        out["scan_kernel"] = {
-            **kcmp,
-            # > 1.0 means the Pallas fused pass beat the XLA chain
-            "pallas_vs_xla": round(
-                kcmp["xla"]["wall_s"] / kcmp["pallas"]["wall_s"], 3)
-            if kcmp["pallas"]["wall_s"] else 0.0,
-        }
-
     # operator-level breakdown from the stats spine: one EXPLAIN ANALYZE
     # pass (same plan, fused path) and the top-5 operators by wall — where
     # the headline wall actually went

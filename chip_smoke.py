@@ -19,8 +19,8 @@ results -- and checks what comes back:
            from the device -- independent of the engine (Q3 is cut above
            sf1: see Q3_MAX_SF);
   device   the chip did the work: peak device bytes cover the resident
-           columns, no Pallas kernel ran interpreted, and the compile cache
-           directory gained entries (or, warm, served hits).
+           columns, and the compile cache directory gained entries (or,
+           warm, served hits).
 
 Every earlier stdout line is one JSON object; walls are smoke output, not
 benchmark numbers.  Any failed phase raises: the last line is then
@@ -99,15 +99,6 @@ def emit(**record):
 
 def schema_of(sf: float) -> str:
     return f"sf{sf:g}"
-
-
-def kernel_counters() -> dict:
-    from presto_tpu.exec.kernels.scan_kernel import KERNEL_METRICS
-    k = KERNEL_METRICS.snapshot()
-    out = {f"kernelDeclined{r}": n for r, n in sorted(k["declined"].items())}
-    out["kernelScanPrograms"] = k["scan_programs"]
-    out["kernelWindowPrograms"] = k["window_programs"]
-    return out
 
 
 def cache_entries(path) -> int:
@@ -237,18 +228,14 @@ def phase_scale(cluster, sf: float):
     client = cluster.client(sf)
     results = {}
     for name, sql in queries_at(sf):
-        before = kernel_counters()
         cold, cold_wall = timed(client, sql)
         warm, warm_wall = timed(client, sql)
         assert warm.rows == cold.rows, f"{name}: warm rows differ from cold"
         assert cold.rows, f"{name}: no rows"
-        after = kernel_counters()
         results[name] = warm
         emit(phase="scale", query=name, schema=schema_of(sf),
              rows=len(warm.rows), cold_wall_s_including_compile=cold_wall,
-             warm_wall_s=warm_wall,
-             kernel_counters={k: v - before.get(k, 0)
-                              for k, v in after.items()})
+             warm_wall_s=warm_wall)
     revenue, count_order, n_rows = independent_answers(sf)
     got_revenue = results["q6"].rows[0][0]
     assert got_revenue == revenue, \
@@ -264,16 +251,13 @@ def phase_scale(cluster, sf: float):
 
 def phase_device(device, cache_dir, entries_before, on_chip: bool):
     """The device did the work, natively, and the compile cache filled."""
-    from presto_tpu.exec.kernels import shim
     from presto_tpu.storage import STORAGE_METRICS
-    counters = kernel_counters()
     resident = int(STORAGE_METRICS["resident_bytes"])
     stats = device.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
     entries_after = cache_entries(cache_dir)
     emit(phase="device", resident_column_bytes=resident,
          peak_bytes_in_use=peak, bytes_in_use=stats.get("bytes_in_use"),
-         kernel_interpret=shim.kernel_interpret(), kernel_counters=counters,
          compile_cache_dir=cache_dir, cache_entries_before=entries_before,
          cache_entries_after=entries_after, **CACHE_EVENTS)
     # resident_column_bytes is what the store's pool has reserved, which
@@ -281,10 +265,6 @@ def phase_device(device, cache_dir, entries_before, on_chip: bool):
     # (twice here, two tasks per scan stage: ROADMAP queue 1 item 5), so
     # the comparison with the peak asks more than it has to
     assert resident > 0, "no column became resident in device memory"
-    kernel_programs = (counters["kernelScanPrograms"]
-                       + counters["kernelWindowPrograms"])
-    assert not (shim.kernel_interpret() and kernel_programs), \
-        f"a Pallas kernel ran interpreted: {counters}"
     if on_chip:
         assert peak is not None and peak >= resident, \
             f"peak device bytes {peak} < resident column bytes {resident}"

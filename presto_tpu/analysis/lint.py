@@ -36,11 +36,9 @@ and flags the hazard shapes:
            and hides where walls are ACTUALLY recorded.  Sanctioned
            metering sites carry `# lint: allow-wall-clock`.
   KERNEL001  an `interpret=True` literal (keyword or kwargs-dict store)
-           anywhere outside `exec/kernels/shim.py`.  Interpret mode is
-           the CPU test fallback; a stray literal in kernel or call-site
-           code would make a TPU build silently run Pallas kernels in
-           the Python interpreter.  There is NO pragma escape — the shim
-           is the one sanctioned site.
+           anywhere: it would make a TPU build run a Pallas kernel in
+           the Python interpreter.  The tree holds no Pallas code, so no
+           file is allow-listed and there is NO pragma escape.
   TELEM001 an unbounded queue (`queue.Queue()` with no / zero maxsize,
            or `queue.SimpleQueue()`) in `presto_tpu/telemetry/`.  The
            telemetry export pipeline sits BESIDE the query path: if its
@@ -116,10 +114,6 @@ ALL_LINT_CODES = (SYNC_EXPLICIT, SYNC_CAST, SYNC_ASARRAY, SYNC_BRANCH,
                   SYNC_NETWORK, SYNC_WALLCLOCK, KERNEL_INTERPRET,
                   TELEM_UNBOUNDED_QUEUE, MEM_UNCHARGED_STAGING,
                   NET_NO_TIMEOUT)
-
-# KERNEL001 scope: everywhere.  The shim is the ONE file that may select
-# Pallas interpret mode (it gates on the backend); no pragma overrides.
-_INTERPRET_ALLOWLIST = ("presto_tpu/exec/kernels/shim.py",)
 
 # SYNC005 scope: pipeline compute packages where a blocking HTTP round
 # trip would serialise operator execution.  Matching is on path markers,
@@ -274,8 +268,6 @@ class _Linter(ast.NodeVisitor):
             m in norm for m in _NET_TIMEOUT_PATH_MARKERS)
         self._telem_scoped = _TELEM_PATH_MARKER in norm
         self._mem_scoped = any(m in norm for m in _MEM_PATH_MARKERS)
-        self._interpret_exempt = any(
-            norm.endswith(a) for a in _INTERPRET_ALLOWLIST)
 
     # -- reporting --------------------------------------------------------
     def _flag(self, node: ast.AST, code: str, message: str,
@@ -287,6 +279,12 @@ class _Linter(ast.NodeVisitor):
             return
         self.findings.append(LintFinding(
             self.path, first, getattr(node, "col_offset", 0), code, message))
+
+    def _flag_interpret(self, node: ast.AST) -> None:
+        self._flag(node, KERNEL_INTERPRET,
+                   "interpret=True would make TPU builds run Pallas "
+                   "kernels in the Python interpreter (no pragma escape)",
+                   allowed=set())
 
     # -- device-value dataflow --------------------------------------------
     def _scope(self) -> Set[str]:
@@ -351,21 +349,15 @@ class _Linter(ast.NodeVisitor):
 
     # -- bindings ----------------------------------------------------------
     def visit_Assign(self, node: ast.Assign) -> None:
-        if not self._interpret_exempt:
-            # the kwargs-dict store form of the same hazard:
-            # kwargs["interpret"] = True
-            for tgt in node.targets:
-                if (isinstance(tgt, ast.Subscript)
-                        and isinstance(tgt.slice, ast.Constant)
-                        and tgt.slice.value == "interpret"
-                        and isinstance(node.value, ast.Constant)
-                        and node.value.value is True):
-                    self._flag(node, KERNEL_INTERPRET,
-                               "interpret=True outside exec/kernels/shim.py "
-                               "would make TPU builds run Pallas kernels in "
-                               "the Python interpreter; route the call "
-                               "through the shim (no pragma escape)",
-                               allowed=set())
+        # the kwargs-dict store form of the KERNEL001 hazard:
+        # kwargs["interpret"] = True
+        for tgt in node.targets:
+            if (isinstance(tgt, ast.Subscript)
+                    and isinstance(tgt.slice, ast.Constant)
+                    and tgt.slice.value == "interpret"
+                    and isinstance(node.value, ast.Constant)
+                    and node.value.value is True):
+                self._flag_interpret(node)
         self.visit(node.value)
         if (isinstance(node.value, ast.Tuple)
                 and len(node.targets) == 1
@@ -514,17 +506,11 @@ class _Linter(ast.NodeVisitor):
                        allowed=self.wall_allowed)
         if self._telem_scoped:
             self._check_telemetry_queue(node, name)
-        if not self._interpret_exempt:
-            for kw in node.keywords:
-                if kw.arg == "interpret" \
-                        and isinstance(kw.value, ast.Constant) \
-                        and kw.value.value is True:
-                    self._flag(kw.value, KERNEL_INTERPRET,
-                               "interpret=True outside exec/kernels/shim.py "
-                               "would make TPU builds run Pallas kernels in "
-                               "the Python interpreter; route the call "
-                               "through the shim (no pragma escape)",
-                               allowed=set())
+        for kw in node.keywords:
+            if kw.arg == "interpret" \
+                    and isinstance(kw.value, ast.Constant) \
+                    and kw.value.value is True:
+                self._flag_interpret(kw.value)
         self.generic_visit(node)
 
     def _check_telemetry_queue(self, node: ast.Call, name: str) -> None:

@@ -202,7 +202,7 @@ def block_to_column(typ: Type, block, capacity: int) -> Column:
     from ..common.block import Int128Block
     if isinstance(block, Int128Block):
         # device holds long decimals narrowed to int64 (batch_to_page widens
-        # on the way back out); values beyond int64 would need Pallas i128
+        # on the way back out); values beyond int64 have no device form
         ints = block.to_pylist()
         vals = np.zeros(capacity, dtype=np.int64)
         nm = np.zeros(capacity, dtype=bool)

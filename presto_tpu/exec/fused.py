@@ -30,15 +30,6 @@ numpy oracle exec/reference.py:438-449 is the fixture for this).
 Semi joins (IN/EXISTS markers) fuse the same way; duplicate build keys are
 harmless there (the marker is existence), so semi steps never force a
 fallback.
-
-Under scan.kernel = pallas (or auto on TPU), eligible fanout-1
-INNER/LEFT and semi probe steps lower further: kernels/join.py rebuilds
-the probe math inside the Pallas scan kernel body, with the DirectTable
-/ BuildTable operands resident across the launch, so the chain runs
-decode -> filter -> probe -> compact -> agg without the XLA chain's
-per-step probe pages.  This module stays the planner, the build-side
-materializer, and the fallback executor for everything the kernel
-declines.
 """
 from __future__ import annotations
 

@@ -220,9 +220,8 @@ def _corr_finalize(name, m2x, m2y, cxy, n):
     return cxy / jnp.maximum(nf, 1.0), n < 1
 
 
-# numpy (not jnp) scalar: it embeds as a jaxpr literal, so kernel code
-# tracing under pallas_call (exec/kernels/grouped.py) can reference it
-# without capturing a device-array constant
+# numpy (not jnp) scalar: it embeds as a jaxpr literal, not as a captured
+# device-array constant
 EMPTY_SLOT = np.uint64(0xFFFFFFFFFFFFFFFF)
 PROBE_ROUNDS = 16
 
@@ -595,10 +594,9 @@ def agg_direct_init(G: int, specs: Tuple[AggSpec, ...]) -> dict:
 def agg_direct_update(state: dict, batch: Batch, codes,
                       agg_inputs: Dict[str, Optional[Column]],
                       specs: Tuple[AggSpec, ...], G: int) -> dict:
-    """codes: combined group code per row (int, < G).  A Pallas MXU
-    grouped-sum kernel was tried here and DELETED (no Pallas family
-    compiles for the v5e, kernels.KERNEL_FAMILY_COMPILES): the one-hot
-    grid below fuses into the surrounding program: of Q1's 0.114 s pass
+    """codes: combined group code per row (int, < G).  The one-hot grid
+    below fuses into the surrounding program, which is why no
+    hand-written grouped-sum kernel stands here: of Q1's 0.114 s pass
     over SF10's 60M rows on the v5e, each grouped-sum fusion is 2.6 ms
     (PERF.md section 5, PR 29)."""
     grid = (codes[None, :] == jnp.arange(G, dtype=codes.dtype)[:, None]) \

@@ -315,29 +315,6 @@ class ExecutionConfig:
     # the observed compute/collective overlap_fraction in FabricMetrics
     # (parallel/fabric.py IciChunkTuner, multiplicative feedback)
     ici_chunk_rows: int = 0
-    # -- Pallas scan kernel (exec/kernels) --------------------------------
-    # the fused scan->filter->project->partial-agg hot path: "pallas"
-    # requests the hand-written Pallas kernel (decode + prefix-sum
-    # compaction + subtile aggregation in one VMEM-resident grid pass),
-    # "xla" keeps the jnp fused chain, "auto" picks Pallas exactly when
-    # the chip's compiler accepts the kernel family the chain needs
-    # (kernels.KERNEL_FAMILY_COMPILES — none today, so "auto" declines
-    # with CompilerRefused on every backend), the backend is a real TPU
-    # (off-TPU the kernel only runs in interpret-mode emulation, never a
-    # win: Backend) AND the chain is eligible.  "pallas" pins the kernel:
-    # tests use it to run the kernel body interpreted, and on a TPU a
-    # refused lowering fails the query with the compiler's message.
-    # Ineligibility is metered per scan as kernelDeclined{reason}
-    # runtime-stats counters.  Config key scan.kernel / session scan_kernel
-    scan_kernel: str = "auto"
-    # DMA staging discipline for the kernel's encoded input slabs:
-    # "single" streams each grid block through the BlockSpec pipeline
-    # as before; "double" stages per-row slabs through a manually
-    # double-buffered VMEM scratch (pltpu.make_async_copy) so block
-    # k+1's HBM copy overlaps block k's decode/aggregate compute.  The
-    # achieved prefetch coverage is metered as kernelDmaOverlapFraction.
-    # Config key scan.kernel-dma / session scan_kernel_dma
-    scan_kernel_dma: str = "single"
     # -- per-query device profiler capture (telemetry/profiler.py) --------
     # session property `profile = true` wraps THIS query's execution in
     # jax.profiler.trace() writing a TensorBoard-loadable trace dir under
@@ -387,13 +364,6 @@ class ExecutionConfig:
     # flipping it re-keys the canonical plan cache
     fragment_share: bool = True
 
-
-# legal scan.kernel / scan_kernel values (worker/properties.py and the
-# session-property validation both check against this)
-SCAN_KERNEL_MODES = ("xla", "pallas", "auto")
-
-# legal scan.kernel-dma / scan_kernel_dma values
-SCAN_KERNEL_DMA_MODES = ("single", "double")
 
 # legal retry-policy / retry_policy values (worker/properties.py and the
 # session-property validation both check against this)
@@ -1608,32 +1578,6 @@ class PlanCompiler:
                     encode.append(k)
             if encode:
                 merged = _encode_lazy_keys(merged, encode)
-            cfg = self.ctx.config
-
-            def _declined(reason: str) -> None:
-                from .kernels.scan_kernel import KERNEL_METRICS
-                KERNEL_METRICS.record_declined(reason)
-                rs = self.ctx.runtime_stats
-                if rs is not None:
-                    rs.add(f"kernelDeclined{reason}", 1)
-
-            from .kernels import kernel_gate
-            gate = kernel_gate(cfg.scan_kernel, "window")
-            if gate is not None:
-                _declined(gate)
-            else:
-                # Pallas prefix-scan window kernel (exec/kernels/window):
-                # segments + running aggregates in one VMEM-resident
-                # launch over the sorted run.  None -> metered decline,
-                # fall through to the XLA segmented scans.
-                from .kernels import try_window_kernel
-                kres = try_window_kernel(
-                    merged, part_names, orderings, specs,
-                    declined=_declined,
-                    runtime_stats=self.ctx.runtime_stats)
-                if kres is not None:
-                    yield kres
-                    return
             yield _jits()[2](merged, part_names, orderings, specs)
         return BatchSource(gen, out_names, out_types)
 
@@ -1857,16 +1801,6 @@ class PlanCompiler:
             rs = self.ctx.runtime_stats
             if rs is not None:
                 rs.add(f"fusionDeclined{reason}", 1)
-
-        def _kernel_declined(reason: str) -> None:
-            """Pallas scan-kernel refusals (exec/kernels), metered like
-            the fusion ones: kernelDeclined{Reason} counters tell EXPLAIN
-            ANALYZE why a fused scan ran the XLA chain instead."""
-            from .kernels.scan_kernel import KERNEL_METRICS
-            KERNEL_METRICS.record_declined(reason)
-            rs = self.ctx.runtime_stats
-            if rs is not None:
-                rs.add(f"kernelDeclined{reason}", 1)
 
         def get_fused():
             """Whole-pipeline fusion: when the source is a
@@ -2095,54 +2029,14 @@ class PlanCompiler:
                     codes = jnp.zeros(b.capacity, dtype=jnp.int64)
                 return codes
 
-            from .kernels import (KERNEL_SPAN_MAX_GROUPS, chain_families,
-                                  kernel_gate, try_direct_scan_kernel,
-                                  try_grouped_scan_kernel)
             basic = basic_specs
             sort_only = sort_only_specs
+            # direct: closed key domains of at most 64 groups -- the
+            # one-hot grid
             info = (_direct_mode_info(key_names, key_cols)
                     if basic else None)
-            span_info = (_direct_mode_info(key_names, key_cols,
-                                           gmax=KERNEL_SPAN_MAX_GROUPS)
-                         if basic and info is None else None)
-            # the one scan.kernel decision of this chain: its aggregation
-            # family (one-hot grid for G <= 64, else span slot addressing
-            # when the closed key domains fit the VMEM accumulator gate,
-            # hashed open addressing otherwise) plus the in-kernel probe
-            gate = kernel_gate(cfg.scan_kernel, *chain_families(
-                "direct" if info is not None
-                else "hash" if span_info is None else "span", chain.steps))
-            if gate is None and not basic:
-                # non-basic aggregate functions (stddev/variance, corr,
-                # percentiles, distinct forms) have no in-kernel
-                # accumulator shape — the XLA chain keeps those
-                gate = "AggFunctionShape"
-            if gate is not None:
-                _kernel_declined(gate)
             if info is not None:
                 doms, G, strides, kdts, kdicts = info
-                if gate is None:
-                    # Pallas fused scan kernel (exec/kernels): decode +
-                    # filter + prefix-sum compaction + subtile partial
-                    # agg in one grid pass over the surviving chunks.
-                    # Its accumulator state and row counters are
-                    # agg_direct-shaped, so finalize and the operator
-                    # stats spine are shared with the XLA path below.
-                    kres = try_direct_scan_kernel(
-                        chain, aux, specs=specs,
-                        key_names=key_names, strides=strides, G=G,
-                        agg_exprs=_agg_exprs, lowering=low,
-                        cache=fused_cache, declined=_kernel_declined,
-                        runtime_stats=self.ctx.runtime_stats,
-                        dma=cfg.scan_kernel_dma,
-                        expands=expands, pool=pool)
-                    if kres is not None:
-                        state, kcounts, n_blocks = kres
-                        counts_out["counts"] = kcounts
-                        counts_out["n_chunks"] = n_blocks
-                        return ops.agg_direct_finalize(
-                            state, specs, key_names, doms, kdts, kdicts,
-                            force_row=not key_names)
 
                 def update(st, b):
                     return ops.agg_direct_update(
@@ -2153,26 +2047,6 @@ class PlanCompiler:
                 return ops.agg_direct_finalize(
                     state, specs, key_names, doms, kdts, kdicts,
                     force_row=not key_names)
-            if gate is None:
-                # grouped (G > 64) shapes run in-kernel too
-                # (exec/kernels/grouped.py).  A None return has already
-                # metered its kernelDeclined{reason}; the XLA span /
-                # sort / hash paths below take over.
-                kres = try_grouped_scan_kernel(
-                    chain, aux, specs=specs, key_names=key_names,
-                    key_dtypes=key_dtypes, key_dicts=key_dicts,
-                    key_lazy=key_lazy, span_info=span_info,
-                    est_slots=initial_slots, agg_exprs=_agg_exprs,
-                    lowering=low, cache=fused_cache,
-                    declined=_kernel_declined, pool=pool,
-                    state_bytes=_agg_state_bytes,
-                    runtime_stats=self.ctx.runtime_stats,
-                    dma=cfg.scan_kernel_dma, expands=expands)
-                if kres is not None:
-                    out, kcounts, n_blocks = kres
-                    counts_out["counts"] = kcounts
-                    counts_out["n_chunks"] = n_blocks
-                    return _maybe_compact(out)
 
             # static span: closed dictionary/bool domains beyond the grid
             # limit — combined stride code indexes accumulators directly

@@ -15,10 +15,10 @@ direct-mode aggregation path (one-hot grid, BASIC_AGGS, closed small key
 domains) — the batched program replays exactly the per-lane computation
 the sequential fused path would run, chunk loop and all, which is what
 makes the bit-identical-results guarantee of the batching layer hold.
-Anything outside that envelope (hash-table aggs, sort paths, Pallas
-kernel engagements, parameterized build sides or pushdown pruning whose
-CHUNK LIST depends on the bound constants) declines batching and the
-queries run sequentially as before.
+Anything outside that envelope (hash-table aggs, sort paths,
+parameterized build sides or pushdown pruning whose CHUNK LIST depends
+on the bound constants) declines batching and the queries run
+sequentially as before.
 
 Batch widths are padded to powers of two (padding lanes replicate lane
 0's parameters and are discarded at demux) so the per-width retrace
@@ -224,12 +224,6 @@ def _eligible(compiler, output) -> Optional[BatchedTemplateRunner]:
         return None
     info = _direct_mode_info(key_names, key_cols)
     if info is None:
-        return None
-    from ..exec.kernels import chain_families, kernel_gate
-    if kernel_gate(cfg.scan_kernel,
-                   *chain_families("direct", chain.steps)) is None:
-        # sequential runs engage the Pallas scan kernel here; batching
-        # through the XLA vmap would change the computation
         return None
     return BatchedTemplateRunner(compiler, output, chain, aux[:-1],
                                  expands, leaf_cap, specs, input_exprs,

@@ -166,27 +166,6 @@ def format_analyze_footer(runtime_stats, profile_dir: str = None) -> str:
     if declined:
         body = ", ".join(f"{k}: {v}" for k, v in sorted(declined.items()))
         lines.append(f"Fusion declined: {{{body}}}")
-    # the Pallas scan-kernel twin of the fusion counters: how many fused
-    # scans ran the hand-written kernel, and why the rest stayed on the
-    # XLA chain (exec/kernels KERNEL_DECLINE_REASONS)
-    kdeclined = {k[len("kernelDeclined"):]: int(v["sum"])
-                 for k, v in rs.items() if k.startswith("kernelDeclined")}
-    if kdeclined:
-        body = ", ".join(f"{k}: {v}" for k, v in sorted(kdeclined.items()))
-        lines.append(f"Scan kernel declined: {{{body}}}")
-    kp = rs.get("kernelScanPrograms")
-    if kp:
-        lines.append(f"Pallas scan kernels: {int(kp['sum'])}")
-    kw = rs.get("kernelWindowPrograms")
-    if kw:
-        lines.append(f"Pallas window kernels: {int(kw['sum'])}")
-    ov = rs.get("kernelDmaOverlapFraction")
-    if ov and ov.get("count"):
-        # scan.kernel-dma = double: fraction of staged block slabs whose
-        # HBM->VMEM copy was issued while the previous block computed
-        lines.append(f"Kernel DMA overlap: "
-                     f"{ov['sum'] / ov['count']:.2f} "
-                     f"(double-buffered, {ov['count']} kernel(s))")
     fw = rs.get("fusedProgramWallNanos")
     if fw:
         lines.append(f"Fused program wall: {fw['sum'] / 1e6:,.1f}ms "

@@ -124,8 +124,7 @@ def metrics_to_resource_metrics(points: Iterable[Tuple[str, float, dict]],
 
 def scrape_metric_points() -> List[Tuple[str, float, dict]]:
     """Flatten the process metric registries (exchange, fabric, serving,
-    storage, kernel decline/DMA counters, memory arbitration/spill) into
-    OTLP gauge points.  Import
+    storage, memory arbitration/spill) into OTLP gauge points.  Import
     inside the function: the registries live in packages this one must
     not import at module load (telemetry is imported by worker startup)."""
     points: List[Tuple[str, float, dict]] = []
@@ -153,15 +152,6 @@ def scrape_metric_points() -> List[Tuple[str, float, dict]]:
     from ..storage.store import STORAGE_METRICS
     for k, v in STORAGE_METRICS.items():
         points.append((f"presto_tpu.storage.{k}", float(v), {}))
-
-    from ..exec.kernels.scan_kernel import KERNEL_METRICS
-    for k, v in KERNEL_METRICS.snapshot().items():
-        if isinstance(v, dict):
-            for reason, n in v.items():
-                points.append((f"presto_tpu.kernel.{k}", float(n),
-                               {"reason": reason}))
-        else:
-            points.append((f"presto_tpu.kernel.{k}", float(v), {}))
 
     from ..exec.memory import MEMORY_METRICS
     for k, v in MEMORY_METRICS.snapshot().items():

@@ -134,22 +134,6 @@ def execution_config_from_properties(props: Dict[str, str],
             raise ValueError(
                 f"exchange.ici-chunk-rows must be >= 1, got {n}")
         kw["ici_chunk_rows"] = n
-    if "scan.kernel" in props:
-        from ..exec.pipeline import SCAN_KERNEL_MODES
-        mode = props["scan.kernel"].strip().lower()
-        if mode not in SCAN_KERNEL_MODES:
-            raise ValueError(
-                f"scan.kernel must be one of {SCAN_KERNEL_MODES}, "
-                f"got {mode!r}")
-        kw["scan_kernel"] = mode
-    if "scan.kernel-dma" in props:
-        from ..exec.pipeline import SCAN_KERNEL_DMA_MODES
-        mode = props["scan.kernel-dma"].strip().lower()
-        if mode not in SCAN_KERNEL_DMA_MODES:
-            raise ValueError(
-                f"scan.kernel-dma must be one of {SCAN_KERNEL_DMA_MODES}, "
-                f"got {mode!r}")
-        kw["scan_kernel_dma"] = mode
     if "exchange.max-response-size" in props:
         kw["exchange_max_response_bytes"] = parse_data_size(
             props["exchange.max-response-size"])
@@ -277,13 +261,6 @@ class SystemConfig:
         # 0 = auto-tune from the observed compute/collective overlap
         # (parallel/fabric.py IciChunkTuner); explicit values pin it
         ("exchange.ici-chunk-rows", int, 0),
-        # Pallas fused scan kernel selection (exec/kernels): also
-        # gates the in-kernel join probe (kernels/join.py) and the
-        # prefix-scan window kernel (kernels/window.py)
-        ("scan.kernel", str, "auto"),
-        # kernel block staging: single (BlockSpec streaming) or double
-        # (manually double-buffered make_async_copy prefetch)
-        ("scan.kernel-dma", str, "single"),
         ("announcement-interval-ms", int, 1000),
         ("heartbeat-interval-ms", int, 1000),
         ("async-data-cache-enabled", bool, False),

@@ -386,14 +386,6 @@ def apply_session_properties(config, session: Dict[str, str]):
                 "lock_validation must be one of on/off/true/false, "
                 f"got {mode!r}")
         kw["lock_validation"] = mode in ("on", "true")
-    if "scan_kernel" in session:
-        mode = str(session["scan_kernel"]).strip().lower()
-        from ..exec.pipeline import SCAN_KERNEL_MODES
-        if mode not in SCAN_KERNEL_MODES:
-            raise ValueError(
-                f"scan_kernel must be one of {SCAN_KERNEL_MODES}, "
-                f"got {mode!r}")
-        kw["scan_kernel"] = mode
     if "profile" in session:
         # per-query device profiler capture (telemetry/profiler.py):
         # wraps execution in jax.profiler.trace() under profile_dir

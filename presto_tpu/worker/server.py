@@ -751,7 +751,6 @@ class _Handler(BaseHTTPRequestHandler):
         the /v1/metrics exposition sections — included in QueryInfo so a
         single snapshot carries both query- and process-scoped state."""
         from ..exec.adaptive import ADAPTIVE_METRICS
-        from ..exec.kernels.scan_kernel import KERNEL_METRICS
         from ..exec.memory import MEMORY_METRICS
         from ..parallel.fabric import FABRIC_METRICS
         from ..serving import SERVING_METRICS
@@ -765,7 +764,6 @@ class _Handler(BaseHTTPRequestHandler):
                 "fabric": FABRIC_METRICS.snapshot(),
                 "serving": SERVING_METRICS.snapshot(),
                 "storage": dict(STORAGE_METRICS),
-                "kernel": KERNEL_METRICS.snapshot(),
                 "memory": MEMORY_METRICS.snapshot(),
                 "adaptive": ADAPTIVE_METRICS.snapshot()}
 

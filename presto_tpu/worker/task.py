@@ -158,12 +158,6 @@ class TpuTask:
                 "exchangeFabric": "http",
                 "exchangeFabricRequested": getattr(
                     self.config, "exchange_fabric", "auto"),
-                # which fused-scan implementation this task's config
-                # requested (exec/kernels Pallas vs XLA chain); actual
-                # engagement is per-scan via the kernelScanPrograms /
-                # kernelDeclined{reason} runtime-stats counters
-                "scanKernel": getattr(
-                    self.config, "scan_kernel", "auto"),
                 "runtimeStats": self.stats.to_dict(),
             },
             "pipelines": [{

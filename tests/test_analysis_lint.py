@@ -312,14 +312,14 @@ def test_lint_routes_through_error_taxonomy(tmp_path):
     lint_or_raise([os.path.join(REPO, "presto_tpu")])  # clean: no raise
 
 
-def test_interpret_literal_flagged_outside_shim():
-    """KERNEL001: an interpret=True literal outside the CPU-fallback shim
-    would make a TPU build silently run Pallas kernels interpreted."""
+def test_interpret_literal_flagged():
+    """KERNEL001: an interpret=True literal would make a TPU build
+    silently run a Pallas kernel interpreted."""
     src = ("from jax.experimental import pallas as pl\n"
            "def f(kernel, spec, shapes):\n"
            "    return pl.pallas_call(kernel, grid_spec=spec,\n"
            "                          out_shape=shapes, interpret=True)\n")
-    findings = lint_source(src, "presto_tpu/exec/kernels/scan_kernel.py")
+    findings = lint_source(src, "presto_tpu/exec/pipeline.py")
     assert KERNEL_INTERPRET in _codes(findings)
     # ...and there is no pragma escape
     src2 = ("from jax.experimental import pallas as pl\n"
@@ -328,7 +328,7 @@ def test_interpret_literal_flagged_outside_shim():
             "        kernel, grid_spec=spec,  # lint: allow-host-sync\n"
             "        out_shape=shapes,\n"
             "        interpret=True)  # lint: allow-wall-clock\n")
-    findings = lint_source(src2, "presto_tpu/exec/kernels/scan_kernel.py")
+    findings = lint_source(src2, "presto_tpu/exec/pipeline.py")
     assert KERNEL_INTERPRET in _codes(findings)
 
 
@@ -340,36 +340,38 @@ def test_interpret_kwargs_store_flagged():
     assert KERNEL_INTERPRET in _codes(findings)
 
 
-def test_interpret_allowed_in_shim_only():
+def test_interpret_has_no_exempt_file():
+    """The tree holds no Pallas code, so no file may select interpret
+    mode: not a shim-shaped wrapper, and not outside exec/ either."""
     src = ("def pallas_call(kernel, **kwargs):\n"
            "    kwargs['interpret'] = True\n"
            "    return kernel(**kwargs)\n")
-    assert lint_source(src, "presto_tpu/exec/kernels/shim.py") == []
-    assert lint_source(src, "presto_tpu/exec/kernels/other.py") != []
+    for path in ("presto_tpu/exec/kernels/shim.py",
+                 "presto_tpu/serving/batched.py", "bench.py"):
+        assert KERNEL_INTERPRET in _codes(lint_source(src, path)), path
 
 
-def test_kernels_package_is_sync_and_wall_scoped():
-    """exec/kernels/ files fall under the SYNC + wall-clock rules (the
-    path markers cover presto_tpu/exec/ recursively)."""
+def test_exec_package_is_sync_and_wall_scoped():
+    """exec/ files fall under the SYNC + wall-clock rules (the path
+    markers cover presto_tpu/exec/ recursively)."""
     findings = lint_source(
         "import time\n"
         "import jax.numpy as jnp\n"
         "def f(x):\n"
         "    t0 = time.perf_counter()\n"
         "    return jnp.sum(x).item(), t0\n",
-        "presto_tpu/exec/kernels/scan_kernel.py")
+        "presto_tpu/exec/fused.py")
     assert {SYNC_EXPLICIT, SYNC_WALLCLOCK} <= _codes(findings)
 
 
 @pytest.mark.parametrize("path", [
-    "presto_tpu/exec/kernels/join.py",
-    "presto_tpu/exec/kernels/window.py",
+    "presto_tpu/exec/operators.py",
+    "presto_tpu/exec/sub/package.py",
 ])
-def test_new_kernel_files_fall_under_kernel_rules(path):
-    """The PR 16 kernel files (in-kernel join probe, prefix-sum window
-    aggregation) sit under the same KERNEL001 + SYNC + wall-clock scope
-    as scan_kernel.py — an interpret literal or a host sync added there
-    must fail tier-1 exactly like in the original kernel."""
+def test_exec_files_fall_under_kernel_rules(path):
+    """A hand-written kernel that enters exec/ later sits under the
+    KERNEL001 + SYNC + wall-clock scope from its first line: an
+    interpret literal or a host sync added there must fail tier-1."""
     src = ("from jax.experimental import pallas as pl\n"
            "def f(kernel, shapes):\n"
            "    return pl.pallas_call(kernel, out_shape=shapes,\n"

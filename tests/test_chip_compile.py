@@ -9,44 +9,27 @@ parametrize argument -- because only one process may load the TPU library
 at a time and every xdist worker imports every test file.
 
 Each program is CAPTURED from the engine, not rebuilt here: a query runs at
-sf0.01 with batch_rows = 1 << 20 up to the first call of the jitted launcher
-(the Pallas launcher a kernels.build_* function returns, or the fused
-fori_loop program of exec/pipeline.py), the call is aborted, and the
-launcher is lowered with the captured argument shapes placed on the
-described device.  Code that asks jax.default_backend() still sees the CPU
-here, so shim.kernel_interpret is steered by the test (never by an option
-of the program).
+sf0.01 with batch_rows = 1 << 20 up to the first call of the named program
+(a fused fori_loop program of exec/pipeline.py, or window_batch), the call
+is aborted, and the program is lowered with the captured argument shapes
+placed on the described device.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from presto_tpu.exec.kernels import (KERNEL_FAMILY_COMPILES, grouped, shim,
-                                     window)
-from presto_tpu.exec.kernels import scan_kernel as sk
+from presto_tpu.exec import pipeline
 from presto_tpu.exec.pipeline import ExecutionConfig
 from presto_tpu.exec.runner import LocalQueryRunner
 from presto_tpu.serving.cache import PlanCache
 
-from test_join_kernel import Q3_SHAPE
+from test_fused import MODULUS_KEY, ORDERKEY_COUNT, Q3_SHAPE, SPAN_4KEYS
 from test_queries import TPCH_Q1, TPCH_Q6
-from test_window_kernel import RUNNING_SUM
+from test_window import RUNNING_SUM
 
 BATCH_ROWS = 1 << 20
 HBM_BYTES = 16 * 10**9          # one v5e chip
-
-# one query per kernel family: the shape that makes the engine build that
-# family's launcher under scan_kernel="pallas"
-FAMILY_SQL = {
-    "direct": TPCH_Q6,
-    "span": "select l_returnflag, l_linestatus, l_shipmode, l_shipinstruct, "
-            "sum(l_quantity), avg(l_discount), count(*) from lineitem "
-            "group by 1, 2, 3, 4",
-    "hash": "select l_orderkey, count(*) from lineitem group by l_orderkey",
-    "join": Q3_SHAPE,
-    "window": RUNNING_SUM,
-}
 
 
 @pytest.fixture(scope="module")
@@ -113,93 +96,73 @@ def _on(sharding, args):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel families vs the static table beside KERNEL_DECLINE_REASONS
+# the scan path: the fused XLA chain programs, and window_batch
 # ---------------------------------------------------------------------------
 
-def _capture_kernel(monkeypatch, family):
-    """The jitted Pallas launcher the engine builds for `family`, with the
-    arguments of its first call at BATCH_ROWS."""
-    real_direct, real_hash = sk.build_direct_runner, grouped.build_hash_runner
-    real_window = window._build_runner
-
-    def build_direct(*a, **k):
-        r = real_direct(*a, **k)
-        return r._replace(fn=_capturing(r.fn))
-
-    def build_hash(*a, **k):
-        run, names = real_hash(*a, **k)
-        return _capturing(run), names
-
-    # both the defining module and the importing module hold the name
-    monkeypatch.setattr(sk, "build_direct_runner", build_direct)
-    monkeypatch.setattr(grouped, "build_direct_runner", build_direct)
-    monkeypatch.setattr(grouped, "build_hash_runner", build_hash)
-    monkeypatch.setattr(window, "_build_runner",
-                        lambda *a, **k: _capturing(real_window(*a, **k)))
-    monkeypatch.setattr(window, "_RUNNER_CACHE", {})
-    return _capture(FAMILY_SQL[family], scan_kernel="pallas")
-
-
-@pytest.mark.parametrize("family", sorted(KERNEL_FAMILY_COMPILES))
-def test_kernel_family_matches_static_table(monkeypatch, one_chip, family):
-    """Every family the table accepts compiles for the v5e; every family it
-    refuses still raises -- so the PR that makes one compile must flip the
-    table (and `auto` starts selecting it), and a JAX upgrade that breaks
-    an accepted family fails here, not on the chip."""
-    assert set(FAMILY_SQL) == set(KERNEL_FAMILY_COMPILES)
-    fn, args = _capture_kernel(monkeypatch, family)
-    monkeypatch.setattr(shim, "kernel_interpret", lambda: False)
-    lower = lambda: fn.lower(*_on(one_chip, args)).compile()  # noqa: E731
-    if KERNEL_FAMILY_COMPILES[family]:
-        assert "tpu_custom_call" in lower().as_text()
-    else:
-        with pytest.raises(Exception) as refused:
-            lower()
-        # the compiler's own refusal, not a capture or placement slip
-        assert "mosaic" in str(refused.traceback[-1].path), refused.value
-
-
-# ---------------------------------------------------------------------------
-# what `auto` runs today: the fused XLA chain programs
-# ---------------------------------------------------------------------------
-
-def _capture_fused(monkeypatch, sql, **capture):
-    """The jitted fused loop of `sql` (exec/pipeline.py `scan_agg_*`) and
-    the arguments of its first call."""
+def _capture_program(monkeypatch, sql, program, **capture):
+    """The jitted program `program` (the name named_jit gave it) that
+    `sql` builds and the arguments of its first call; whatever the query
+    launches before it (build tables, the span probe) runs for real."""
     real_jit = jax.jit
 
     def recording_jit(fun, *a, **k):
         jitted = real_jit(fun, *a, **k)
-        # named_jit names the fused loop by its mode: scan_agg_direct, ...
-        if getattr(fun, "__name__", "").startswith("scan_agg_"):
+        if getattr(fun, "__name__", "") == program:
             return _capturing(jitted)
         return jitted
 
     monkeypatch.setattr(jax, "jit", recording_jit)
+    # sort_batch / build_table / window_batch are jitted once a process
+    # (pipeline._jits): have them jitted again, under the recorder
+    for once in ("_jit_sort", "_jit_build", "_jit_window"):
+        monkeypatch.setattr(pipeline, once, None)
     fn, args = _capture(sql, **capture)
     monkeypatch.undo()
     return fn, args
 
 
-@pytest.mark.parametrize("sql", [TPCH_Q6, TPCH_Q1], ids=["q6", "q1"])
-def test_fused_xla_step_compiles_and_fits(monkeypatch, one_chip, sql):
-    """The fused scan -> filter -> project -> agg fori_loop program the
-    default config runs for Q6 / Q1 compiles for the v5e at BATCH_ROWS and
-    fits its 16 GB.  And at the encodings the served store holds (sf0.1 at
-    the served 64K-row chunk is the least size that takes them: under a
-    1M-row pad every sf0.01 column is plain) no column is decoded by a
-    per-row gather, 7 ns a row on the chip (storage/encodings.py)."""
-    from presto_tpu.storage import ResidentColumn
-    fn, args = _capture_fused(monkeypatch, sql)
-    compiled = fn.lower(*_on(one_chip, args)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()   # no Pallas in auto
+@pytest.mark.parametrize("sql,program,n_static,sort_budget", [
+    (TPCH_Q6, "scan_agg_direct", 0, None),
+    (TPCH_Q1, "scan_agg_direct", 0, None),
+    (SPAN_4KEYS, "scan_agg_static_span", 0, None),
+    (ORDERKEY_COUNT, "scan_agg_runtime_span", 0, None),
+    (Q3_SHAPE, "scan_agg_runtime_span", 0, None),
+    # over the sort budget: the scatter hash table.  (scan_agg_sort itself
+    # compiles too, in 89 s on this host: too long for this file)
+    (MODULUS_KEY, "scan_agg_hash", 0, 0),
+    # window_batch(batch, part_names, orderings, specs): three static
+    (RUNNING_SUM, "window_batch", 3, None),
+], ids=["q6", "q1", "static_span", "anchored_span", "join", "hash",
+        "window"])
+def test_fused_xla_step_compiles_and_fits(monkeypatch, one_chip, sql,
+                                          program, n_static, sort_budget):
+    """The program the default config runs for each shape -- the fused
+    scan -> filter -> project [-> probe] -> agg fori_loop by aggregation
+    strategy at BATCH_ROWS, and the window's segmented scans at the
+    capacity the query materializes -- compiles for the v5e and fits its
+    16 GB, with no hand-written kernel in it."""
+    if sort_budget is not None:
+        monkeypatch.setattr(pipeline, "SORT_AGG_MAX_BYTES", sort_budget)
+    fn, args = _capture_program(monkeypatch, sql, program)
+    n = len(args) - n_static
+    compiled = fn.lower(*_on(one_chip, args[:n]), *args[n:]).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < need < HBM_BYTES, mem
 
-    fn, args = _capture_fused(monkeypatch, sql, schema="sf0.1",
-                              batch_rows=1 << 16)
+
+@pytest.mark.parametrize("sql", [TPCH_Q6, TPCH_Q1], ids=["q6", "q1"])
+def test_served_encodings_decode_without_a_gather(monkeypatch, one_chip,
+                                                  sql):
+    """At the encodings the served store holds (sf0.1 at the served
+    64K-row chunk is the least size that takes them: under a 1M-row pad
+    every sf0.01 column is plain) no column of Q6 / Q1 is decoded by a
+    per-row gather, 7 ns a row on the chip (storage/encodings.py)."""
+    from presto_tpu.storage import ResidentColumn
+    fn, args = _capture_program(monkeypatch, sql, "scan_agg_direct",
+                                schema="sf0.1", batch_rows=1 << 16)
     kinds = [(c.kind, c.dtype) for c in jax.tree_util.tree_leaves(
         args, is_leaf=lambda x: isinstance(x, ResidentColumn))
         if isinstance(c, ResidentColumn)]

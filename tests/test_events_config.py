@@ -10,9 +10,11 @@ import os
 
 import pytest
 
+from presto_tpu.exec.pipeline import ExecutionConfig
 from presto_tpu.worker.events import (EventListener, EventListenerManager,
                                       FileEventListener)
-from presto_tpu.worker.properties import (execution_config_from_properties,
+from presto_tpu.worker.properties import (SystemConfig,
+                                          execution_config_from_properties,
                                           load_properties,
                                           register_catalogs_from_etc,
                                           server_kwargs_from_etc)
@@ -78,6 +80,47 @@ def test_execution_config_mapping():
     with pytest.raises(ValueError, match="LZO"):
         execution_config_from_properties(
             {"exchange.compression-codec": "LZO"})
+
+
+@pytest.mark.parametrize("key,value", [
+    ("scan.kernel", "pallas"), ("scan.kernel-dma", "double"),
+    ("scan_kernel", "pallas"), ("scan_kernel_dma", "double"),
+])
+def test_removed_scan_kernel_knobs_are_ignored(key, value):
+    """A coordinator that still sends a knob of the deleted Pallas path is
+    served as for any unknown key: a config key (dotted) or a session
+    property (underscored) parses to the default ExecutionConfig."""
+    from presto_tpu.worker.protocol import apply_session_properties
+    parse = (execution_config_from_properties if "." in key
+             else lambda kv: apply_session_properties(ExecutionConfig(), kv))
+    assert parse({key: value}) == ExecutionConfig()
+
+
+def test_no_configuration_name_mentions_a_kernel():
+    import dataclasses
+    fields = [f.name for f in dataclasses.fields(ExecutionConfig)]
+    keys = [k for k, _t, _d in SystemConfig.KEYS]
+    assert not [n for n in fields + keys if "kernel" in n]
+
+
+def test_no_module_imports_pallas():
+    """There is one scan path, the fused XLA chain: no module of the
+    package imports Pallas, and analysis/lint.py alone names it (KERNEL001
+    refuses an interpret=True literal wherever a kernel enters later)."""
+    import presto_tpu
+    root = os.path.dirname(presto_tpu.__file__)
+    naming = []
+    for dirpath, _dirs, files in os.walk(root):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(dirpath, f)
+                with open(path, encoding="utf-8") as fh:
+                    src = fh.read()
+                assert "jax.experimental.pallas" not in src \
+                    and "jax.experimental import pallas" not in src, path
+                if "pallas" in src.lower():
+                    naming.append(os.path.relpath(path, root))
+    assert naming == [os.path.join("analysis", "lint.py")]
 
 
 def _write_etc(tmp_path, extra_catalogs=()):

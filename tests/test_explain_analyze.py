@@ -28,12 +28,15 @@ from test_queries import TPCH_Q1, TPCH_Q6
 # fused vs unfused ANALYZE parity
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("sql", [TPCH_Q1, TPCH_Q6], ids=["q1", "q6"])
-def test_fused_vs_unfused_analyze_row_parity(sql):
+@pytest.mark.parametrize("sql,cfg", [
+    (TPCH_Q1, dict(batch_rows=1 << 13)),
+    (TPCH_Q6, dict(batch_rows=1 << 13)),
+    (TPCH_Q6, {}),      # one 64K-row chunk: what a served query runs under
+], ids=["q1", "q6", "q6-default-config"])
+def test_fused_vs_unfused_analyze_row_parity(sql, cfg):
     """ANALYZE over the fused path reports the same per-node row counts as
     the old interpreted instrumentation — the device-side counters riding
     the jitted program are exact, not estimates."""
-    cfg = dict(batch_rows=1 << 13)
     fused = LocalQueryRunner("sf0.01", config=ExecutionConfig(**cfg))
     unfused = LocalQueryRunner("sf0.01", config=ExecutionConfig(
         analyze_unfused=True, **cfg))
@@ -177,8 +180,8 @@ def test_query_info_schema_golden(cluster):
 
     # process metrics ride along for a single-snapshot health read
     assert set(info["processMetrics"]) == {"exchange", "fabric", "serving",
-                                           "storage", "kernel", "memory",
-                                           "adaptive", "programs"}
+                                           "storage", "memory", "adaptive",
+                                           "programs"}
     # the program table names what JAX traced and loaded, by program
     programs = info["processMetrics"]["programs"]
     assert programs and all(
@@ -187,6 +190,36 @@ def test_query_info_schema_golden(cluster):
     assert "resident_bytes" in info["processMetrics"]["storage"]
     assert "spilled_bytes" in info["processMetrics"]["memory"]
     assert "filters_applied" in info["processMetrics"]["adaptive"]
+
+
+def test_query_info_has_no_kernel_surface(cluster):
+    """There is one scan path: a fused Q6 served over the cluster reports
+    no kernelDeclined* counter, no `kernel` process section, no scanKernel
+    on its tasks and no kernel family among the /v1/metrics lines."""
+    from presto_tpu.client import StatementClient
+    coordinator, _ = cluster
+    r = StatementClient(coordinator.uri, schema="sf0.01").execute(TPCH_Q6)
+    info = _get_json(f"{coordinator.uri}/v1/query/{r.query_id}")
+    assert info["runtimeStats"]
+    assert not [k for k in info["runtimeStats"] if "kernel" in k.lower()]
+    assert "kernel" not in info["processMetrics"]
+    tasks = [t for stage in info["stages"] for t in stage["tasks"]]
+    assert tasks and not any("scanKernel" in t.get("stats", {})
+                             for t in tasks)
+    with urllib.request.urlopen(f"{coordinator.uri}/v1/metrics",
+                                timeout=10) as resp:
+        assert "kernel" not in resp.read().decode()
+
+
+def test_explain_analyze_prints_no_kernel_line(cluster):
+    """...and EXPLAIN ANALYZE of it names the fused program and nothing of
+    a kernel: the footer's decline line is the fusion's alone."""
+    from presto_tpu.client import StatementClient
+    coordinator, _ = cluster
+    text = StatementClient(coordinator.uri, schema="sf0.01").execute(
+        "EXPLAIN ANALYZE " + TPCH_Q6).rows[0][0]
+    assert "[fused]" in text
+    assert not re.search(r"kernel|pallas", text, re.IGNORECASE)
 
 
 def test_metrics_namespace_consistency(cluster):

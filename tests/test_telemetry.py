@@ -87,8 +87,8 @@ def test_spans_to_resource_spans_golden_shape():
 def test_metrics_payload_golden_shape():
     payload = metrics_to_resource_metrics(
         [("presto_tpu.exchange.bytes", 42.0, {}),
-         ("presto_tpu.kernel.declined", 2.0,
-          {"reason": "CompilerRefused"})],
+         ("presto_tpu.serving.servingBatchOccupancy", 2.0,
+          {"occupancy": "4"})],
         time_unix_nano=123, resource={"service.name": "p"})
     (rm,) = payload["resourceMetrics"]
     (sm,) = rm["scopeMetrics"]
@@ -98,7 +98,7 @@ def test_metrics_payload_golden_shape():
         {"timeUnixNano": "123", "asDouble": 42.0}]
     (dp,) = m1["gauge"]["dataPoints"]
     assert dp["attributes"] == [
-        {"key": "reason", "value": {"stringValue": "CompilerRefused"}}]
+        {"key": "occupancy", "value": {"stringValue": "4"}}]
     json.dumps(payload)
 
 
@@ -106,9 +106,9 @@ def test_scrape_covers_every_registry():
     names = {n for n, _v, _a in scrape_metric_points()}
     for prefix in ("presto_tpu.exchange.", "presto_tpu.exchange_fabric.",
                    "presto_tpu.serving.", "presto_tpu.storage.",
-                   "presto_tpu.kernel.", "presto_tpu.memory."):
+                   "presto_tpu.memory."):
         assert any(n.startswith(prefix) for n in names), prefix
-    assert "presto_tpu.kernel.scan_programs" in names
+    assert not any(n.startswith("presto_tpu.kernel.") for n in names)
     assert "presto_tpu.memory.spilled_bytes" in names
 
 

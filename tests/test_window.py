@@ -8,6 +8,7 @@ differential strategy (SURVEY.md §4.3).
 Reference semantics fixture: presto-main-base/.../operator/window/
 (frames), WindowOperator.java:69.
 """
+import numpy as np
 import pytest
 
 from presto_tpu.exec.pipeline import ExecutionConfig
@@ -195,3 +196,106 @@ def test_ntile_hand_checked(runner):
     for b, sz in enumerate(sizes, 1):
         want += [b] * sz
     assert [b for _, b in rows] == want
+
+
+# ---------------------------------------------------------------------------
+# window_batch under the DEFAULT config (what a served query runs under).
+# Everything but float_sum is integer/decimal arithmetic, so the comparison
+# with the oracle is exact equality.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def default_runner():
+    return LocalQueryRunner("sf0.01")
+
+
+RUNNING_SUM = """
+    select custkey, orderkey,
+           sum(totalprice) over (partition by custkey
+                                 order by orderkey) as running
+    from orders where orderkey < 4000
+"""
+
+DEFAULT_CONFIG_SHAPES = {
+    "running_sum": RUNNING_SUM,
+    # row_number / rank / dense_rank share one (partition, order) spec
+    "ranking": "select custkey, orderkey, "
+               "row_number() over (partition by custkey order by orderdate, "
+               "orderkey) as rn, "
+               "rank() over (partition by custkey order by orderdate, "
+               "orderkey) as rk, "
+               "dense_rank() over (partition by custkey order by orderdate, "
+               "orderkey) as dr "
+               "from orders where orderkey < 4000",
+    "count_avg": "select custkey, orderkey, "
+                 "count(*) over (partition by custkey order by orderkey) "
+                 "as c, avg(totalprice) over (partition by custkey "
+                 "order by orderkey) as a "
+                 "from orders where orderkey < 4000",
+    # every partition has exactly one row: the frame is the row itself
+    "single_row_partitions":
+        "select orderkey, sum(totalprice) over (partition by orderkey "
+        "order by orderkey) as s, count(*) over (partition by orderkey "
+        "order by orderkey) as c from orders where orderkey < 3000",
+    # no PARTITION BY at all: one segment spans the whole live range
+    "global_partition":
+        "select orderkey, sum(totalprice) over (order by orderkey) as s, "
+        "rank() over (order by orderkey) as r "
+        "from orders where orderkey < 3000",
+    # NULL inputs: count skips them, sum carries them as non-contributing
+    # rows, empty frames are NULL
+    "null_args": "select k, orderkey, sum(v) over (partition by k "
+                 "order by orderkey) as s, count(v) over (partition by k "
+                 "order by orderkey) as c from "
+                 "(select custkey % 7 as k, orderkey, "
+                 "case when orderkey % 3 = 0 then null else totalprice "
+                 "end as v from orders where orderkey < 6000)",
+    # a shifted gather, not a prefix scan
+    "lag": "select orderkey, lag(totalprice) over (partition by custkey "
+           "order by orderkey) as prev from orders where orderkey < 3000",
+    "float_sum": "select orderkey, sum(cast(totalprice as double)) over "
+                 "(partition by custkey order by orderkey) as s "
+                 "from orders where orderkey < 3000",
+    "explicit_frame":
+        "select orderkey, sum(totalprice) over (partition by custkey "
+        "order by orderkey rows between 1 preceding and current row) "
+        "as s from orders where orderkey < 3000",
+    # a late-materialized partition key whose row ids are not value
+    # ordered: the host encodes it before window_batch compares keys
+    "lazy_key": "select orderkey, sum(totalprice) over (partition by clerk "
+                "order by orderkey) as s from orders where orderkey < 3000",
+}
+
+
+@pytest.mark.parametrize("name", sorted(DEFAULT_CONFIG_SHAPES))
+def test_window_shape_default_config(default_runner, name):
+    default_runner.assert_same_as_reference(DEFAULT_CONFIG_SHAPES[name])
+
+
+# seeded fuzz: partition-key cardinality x functions x order keys.
+# orderkey is unique, so every function is deterministic under the sort.
+_FUNCS = ["row_number()", "rank()", "dense_rank()", "count(*)",
+          "count(totalprice)", "sum(totalprice)", "avg(totalprice)"]
+# multi-row partitions, single-row partitions (the unique order key), one
+# global partition, and a dictionary-encoded partition key
+_PARTS = ["partition by custkey", "partition by orderkey", "",
+          "partition by orderpriority"]
+
+
+def _window_fuzz_sql(seed: int) -> str:
+    rng = np.random.default_rng(seed)
+    part = _PARTS[int(rng.integers(len(_PARTS)))]
+    order = ["order by orderkey",
+             "order by orderdate, orderkey"][int(rng.integers(2))]
+    over = f"over ({part}{' ' if part else ''}{order})"
+    n = int(rng.integers(2, 5))
+    funcs = [_FUNCS[i] for i in rng.choice(len(_FUNCS), n, replace=False)]
+    sel = ", ".join(f"{f} {over} as w{i}" for i, f in enumerate(funcs))
+    hi = int(rng.integers(2000, 12_000))
+    return (f"select custkey, orderkey, {sel} "
+            f"from orders where orderkey < {hi}")
+
+
+@pytest.mark.parametrize("seed", range(31, 40))
+def test_window_fuzz_vs_oracle(default_runner, seed):
+    default_runner.assert_same_as_reference(_window_fuzz_sql(seed))
