@@ -44,7 +44,7 @@ import numpy as np
 from ..spi import plan as P
 from .batch import Batch, Column
 from . import operators as ops
-from ..utils.runtime_stats import host_get, jit_as
+from ..utils.runtime_stats import current_stats, host_get, jit_as
 
 # absolute cap on a direct-address table (entries), and the max ratio of
 # key span to build rows before falling back to the hash table
@@ -328,19 +328,63 @@ class FusedChain:
         return build_lookup(self.compiler, build_node, keys, for_join)
 
     def shape_probe(self, aux, expands: Tuple[int, ...], leaf_cap: int):
-        """Abstract output of `make` for one chunk (jax.eval_shape): what
-        callers read key dtypes, dictionaries and laziness from.  A trace,
-        not a launch -- under its own name (`chain_shape_probe`) in JAX's
-        trace events, since every execution that asks pays it again."""
-        def chain_shape_probe(p, v):
-            return self.make(p, v, aux, expands, leaf_cap)
-        return jax.eval_shape(chain_shape_probe, jnp.int64(0), jnp.int64(1))
+        """Abstract output of `make` for one chunk: what callers read key
+        dtypes, dictionaries and laziness from.  A trace, not a launch,
+        and a cached entry like the chain's programs: the `ShapeProbe`
+        comes from the process-wide program cache under their key, so
+        only the first execution of a structure runs the Python body.
+        `shapeProbeMisses` counts the executions on which it ran,
+        `shapeProbeHits` those on which it did not."""
+        prog = self.program
+        return self.compiler.shared_entry(
+            self.root, "chain_shape_probe",
+            lambda: ShapeProbe(prog, expands, leaf_cap),
+            extra=prog.signature(expands, leaf_cap))(aux)
 
     def make(self, pos, valid, aux, expands: Tuple[int, ...],
              leaf_cap: int, with_counts: bool = False):
         """`ChainProgram.make`: one scan chunk through the chain."""
         return self.program.make(pos, valid, aux, expands, leaf_cap,
                                  with_counts)
+
+
+class ShapeProbe:
+    """One chain structure's abstract outputs, by what `aux` looks like.
+
+    The entry `FusedChain.shape_probe` keeps in the program cache: it
+    closes over the `ChainProgram` and the host constants of the chain's
+    signature and over nothing of a task, and takes `aux` as an ARGUMENT,
+    so resident columns, build tables and bound parameters are avals, not
+    constants.  A result is looked up by aux's treedef (column names,
+    nullability, dictionaries, the store's plain / dict / rle choice) and
+    its leaves' avals (dtypes, lengths): what the trace can depend on.
+    Every asker gets the same Batch of shapes, to read and not to write.
+    A body that raises stores nothing, so it raises on every execution;
+    tasks that miss together each trace once and store the same thing."""
+
+    MAX_RESULTS = 16
+
+    def __init__(self, program: "ChainProgram", expands: Tuple[int, ...],
+                 leaf_cap: int):
+        def chain_shape_probe(p, v, aux):
+            return program.make(p, v, aux, expands, leaf_cap)
+        self._fn = chain_shape_probe
+        self._results: Dict[tuple, Batch] = {}
+
+    def __call__(self, aux) -> Batch:
+        leaves, treedef = jax.tree_util.tree_flatten(aux)
+        key = (treedef,) + tuple(jax.typeof(x) for x in leaves)
+        out = self._results.get(key)
+        stats = current_stats()
+        if stats is not None:
+            stats.add("shapeProbeMisses" if out is None else "shapeProbeHits",
+                      1)
+        if out is None:
+            out = jax.eval_shape(self._fn, jnp.int64(0), jnp.int64(1), aux)
+            if len(self._results) >= self.MAX_RESULTS:
+                self._results.clear()   # an aux that never repeats
+            self._results[key] = out
+        return out
 
 
 class ChainProgram:

@@ -120,12 +120,13 @@ _jit_window = None
 def _jits():
     global _jit_sort, _jit_build, _jit_window
     if _jit_sort is None:
-        _jit_sort = named_jit("sort_batch", ops.sort_batch,
-                              static_argnums=1)
         _jit_build = named_jit("build_table", ops.build_table,
                                static_argnums=(1,))
         _jit_window = named_jit("window_batch", ops.window_batch,
                                 static_argnums=(1, 2, 3))
+        # the guard LAST: a task thread that finds it set finds all three
+        _jit_sort = named_jit("sort_batch", ops.sort_batch,
+                              static_argnums=1)
     return _jit_sort, _jit_build, _jit_window
 
 
@@ -623,39 +624,46 @@ class PlanCompiler:
 
     def shared_jit(self, node, purpose: str, fn, extra=(), **kw):
         """`named_jit(purpose, fn)` for a program compiled from `node`,
-        shared with everyone who compiles the same thing.
+        shared with everyone who compiles the same thing: the
+        `shared_entry` whose build is the jit.  `fn` must reach nothing
+        of this task: no `self`, no `self.ctx` (the cache outlives the
+        task and must neither pin it nor write into it)."""
+        return self.shared_entry(
+            node, purpose, lambda: named_jit(purpose, fn, **kw), extra)
+
+    def shared_entry(self, node, purpose: str, build, extra=()):
+        """What `build()` makes for `node` -- a jitted program, a fused
+        chain's shape probe -- shared with everyone who compiles the
+        same thing.
 
         In the in-process batch scheduler the tasks of one stage share
-        ONE traced program per (node id, purpose, extra) through the
-        stage's `TaskContext.shared_jits`.  Everywhere else -- a worker
-        task (a new PlanCompiler every task), the single-node runner's
-        pooled compilers -- the program comes from the process-wide
-        cache (serving/fragments.py) under the STRUCTURAL key
-        `(purpose, subtree with node ids blanked, its real variable
-        names, extra, config fingerprint)`: a second task of the stage,
-        or the next query with the same text, gets the same jax.jit
-        object and traces, lowers and loads nothing.  The
-        `fragment_share` knob off means a fresh jit per compiler.
+        ONE entry per (node id, purpose, extra) through the stage's
+        `TaskContext.shared_jits`.  Everywhere else -- a worker task (a
+        new PlanCompiler every task), the single-node runner's pooled
+        compilers -- the entry comes from the process-wide cache
+        (serving/fragments.py) under the STRUCTURAL key `(purpose,
+        subtree with node ids blanked, its real variable names, extra,
+        config fingerprint)`: a second task of the stage, or the next
+        query with the same text, gets the same object and traces,
+        lowers and loads nothing.  The `fragment_share` knob off means a
+        fresh build per call.
 
-        `extra` must carry every host constant the traced closure bakes
-        in beyond (subtree, names, config) -- chunk capacity, first-batch
+        `extra` must carry every host constant the entry bakes in
+        beyond (subtree, names, config) -- chunk capacity, first-batch
         laziness/dictionary signature, join fanouts, the direct mode's
         G and strides, `ctx.task_index` where the program reads it, the
         operator-stats variant -- since a false share would execute the
-        wrong program, while a missed share only costs one retrace.  And
-        `fn` must reach nothing of this task: no `self`, no `self.ctx`
-        (the cache outlives the task and must neither pin it nor write
-        into it)."""
+        wrong program, while a missed share only costs one retrace."""
         cache = self.ctx.shared_jits
         if cache is not None:
             key = (node.id, purpose) + tuple(extra)
             ent = cache.get(key)
             if ent is None:
-                ent = cache.setdefault(key, named_jit(purpose, fn, **kw))
+                ent = cache.setdefault(key, build())
             return ent
         cfg = self.ctx.config
         if not cfg.fragment_share:
-            return named_jit(purpose, fn, **kw)
+            return build()
         from ..serving.fragments import FRAGMENT_JIT_CACHE
         if self._config_fp is None:
             from ..sql.canonical import config_fingerprint
@@ -666,8 +674,7 @@ class PlanCompiler:
             structure = self._structures[id(node)] = \
                 (node, P.named_structural_key(node))
         key = (purpose,) + structure[1] + (tuple(extra), self._config_fp)
-        return FRAGMENT_JIT_CACHE.get_or_build(
-            key, lambda: named_jit(purpose, fn, **kw))
+        return FRAGMENT_JIT_CACHE.get_or_build(key, build)
 
     def _new_spill_store(self, salt: Optional[int] = None
                          ) -> PartitionedSpillStore:

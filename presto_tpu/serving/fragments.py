@@ -12,6 +12,15 @@ no executable load.  A worker task builds a new PlanCompiler every time,
 so on coordinator -> worker this cache is what keeps a warm query from
 re-tracing its programs a task.
 
+A fused chain's shape probe (`FusedChain.shape_probe`, exec/fused.py:
+the abstract output its callers read before they pick a program) is an
+entry like the programs, through `PlanCompiler.shared_entry`: a
+`ShapeProbe` under the purpose `chain_shape_probe` and the chain's own
+key, with one eviction and one invalidation.  It keeps its results by
+the aux pytree's treedef and avals, so a warm execution traces nothing
+at all (`shapeProbeHits` / `shapeProbeMisses`, counted where the probe
+is asked).
+
 The key is `(purpose, structural key of the subtree, its real variable
 names, extras, config fingerprint)` (`spi.plan.named_structural_key`:
 node ids blanked, literals kept; `sql.canonical.config_fingerprint`).

@@ -135,6 +135,10 @@ def test_warm_mesh_query_builds_loads_and_compiles_nothing(served, name):
     assert warm["programCacheHits"]["sum"] >= DEVICES
     assert "jaxBackendCompiles" not in warm
     assert "jaxLowerWallNanos" not in warm
+    # and nothing is traced: each pinned task's shape probe is a hit
+    assert "jaxTraces" not in warm
+    assert warm["shapeProbeHits"]["sum"] >= DEVICES
+    assert "shapeProbeMisses" not in warm
 
 
 def test_prepared_statements_run_through_the_mesh(served):

@@ -2,10 +2,17 @@
 PlanCompiler.shared_jit): a second task of the same fragment builds no
 program again; nothing that differs in what a program bakes in is ever
 shared; a cached program keeps no task alive; eviction and invalidation
-leave a running task its programs."""
+leave a running task its programs.  The fused chain's shape probe is an
+entry of the same cache: a second execution runs no trace, and nothing
+the trace can depend on is ever shared."""
 import gc
+import sys
+import threading
 import weakref
+from collections import Counter
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -14,17 +21,19 @@ from presto_tpu.common.page import Page
 from presto_tpu.common.serde import deserialize_pages
 from presto_tpu.common.types import BIGINT, VarcharType
 from presto_tpu.connectors import catalog
+from presto_tpu.exec.fused import ChainProgram, assemble_chain
 from presto_tpu.exec.pipeline import (ExecutionConfig, PlanCompiler,
-                                      TaskContext)
+                                      TaskContext, _fragment_batch_sig)
 from presto_tpu.exec.reference import execute_reference
 from presto_tpu.exec.runner import (LocalQueryRunner, _assert_rows_equal,
                                     pages_to_result)
-from presto_tpu.serving import FRAGMENT_JIT_CACHE
+from presto_tpu.serving import FRAGMENT_JIT_CACHE, PlanCache
 from presto_tpu.spi import plan as P
 from presto_tpu.spi.expr import VariableReferenceExpression
 from presto_tpu.sql import parser as A
 from presto_tpu.sql.fragmenter import FragmenterConfig, plan_distributed
 from presto_tpu.sql.planner import Planner
+from presto_tpu.storage.encodings import ResidentColumn
 from presto_tpu.telemetry import jax_events
 from presto_tpu.utils.runtime_stats import RuntimeStats
 from presto_tpu.worker.protocol import (OutputBuffersSpec, TaskSource,
@@ -129,8 +138,8 @@ def test_second_task_of_a_fragment_builds_no_program(sql):
     assert _count(first, "programCacheMisses") >= 1
     assert _count(second, "programCacheHits") >= 1
     assert _count(second, "programCacheMisses") == 0
-    # nothing is lowered or loaded again, and the fused program is not
-    # traced again (what still traces is the chain's eval_shape probe)
+    # nothing is traced, lowered or loaded again: the chain's shape probe
+    # is served from the cache like the fused program it keys
     assert "jaxLowerWallNanos" not in second, second
     assert "jaxBackendCompiles" not in second, second
     fused = [n for n in after if n.startswith("scan_agg_")]
@@ -139,7 +148,10 @@ def test_second_task_of_a_fragment_builds_no_program(sql):
         assert after[n] == before[n], (n, before[n], after[n])
     assert _count(second, "pipelineLaunches") \
         == _count(first, "pipelineLaunches")
-    assert _count(second, "jaxTraces") < _count(first, "jaxTraces")
+    assert _count(first, "jaxTraces") >= 1 and "jaxTraces" not in second
+    assert _count(first, "shapeProbeMisses") >= 1
+    assert _count(second, "shapeProbeHits") >= 1
+    assert _count(second, "shapeProbeMisses") == 0
 
 
 def test_tasks_racing_a_cold_fragment_build_each_program_once():
@@ -300,6 +312,7 @@ def test_cached_programs_do_not_retain_the_first_task(monkeypatch):
     task = tm.get("keep.0.0.0.0")
     task._thread.join(timeout=30)
     assert FRAGMENT_JIT_CACHE.info()["entries"] >= 2   # programs stay
+    assert _cached_purposes()["chain_shape_probe"] == 1   # and the probe
     owner = weakref.ref(task.stats)
     del tm.tasks["keep.0.0.0.0"], task
     gc.collect()
@@ -312,6 +325,8 @@ def test_cached_programs_do_not_retain_the_first_task(monkeypatch):
                               {"collect_operator_stats": "true"})
     assert _rows == rows
     assert _count(second, "programCacheMisses") == 0
+    assert _count(second, "shapeProbeHits") >= 1
+    assert _count(second, "shapeProbeMisses") == 0
 
 
 # ---------------------------------------------------------------------------
@@ -344,3 +359,227 @@ def test_eviction_and_invalidation_do_not_fail_a_running_task(how,
     oracle = LocalQueryRunner("sf0.01")
     res, _stats = _run(_plan(Q1))
     _assert_rows_equal(res, oracle.execute_reference(Q1), False)
+
+
+# ---------------------------------------------------------------------------
+# (e) the fused chain's shape probe: an entry of the same cache
+# ---------------------------------------------------------------------------
+
+JOIN = ("select n_name, sum(s_acctbal) as bal from supplier "
+        "join nation on s_nationkey = n_nationkey group by n_name")
+
+
+def _cached_purposes():
+    """{purpose: entries} of the process-wide cache."""
+    return Counter(key[0] for key in list(FRAGMENT_JIT_CACHE._entries))
+
+
+def _probe_traces():
+    return jax_events.PROGRAMS.snapshot().get(
+        "chain_shape_probe", {"traces": 0})["traces"]
+
+
+def _chain(sql, owner=None):
+    """The fused chain under `sql`'s aggregation, assembled by a NEW
+    PlanCompiler as a task's would."""
+    agg = next(n for n in P.walk_plan(_plan(sql))
+               if isinstance(n, P.AggregationNode))
+    return assemble_chain(
+        PlanCompiler(TaskContext(config=CONFIG, runtime_stats=owner)),
+        agg.source)
+
+
+def _probe(sql, edit=None):
+    """One task's shape probe of `sql`'s chain: (abstract batch, "hit" |
+    "miss", the aux it was asked with).  `edit(aux)` changes what the
+    task holds before it asks.  A miss IS the body running: JAX's own
+    trace count of `chain_shape_probe` must agree."""
+    jax_events.install()
+    owner = RuntimeStats()
+    with owner.activate():
+        chain = _chain(sql, owner)
+        aux, expands, _deferred = chain.prep()
+        if edit is not None:
+            aux = edit(aux)
+        before = _probe_traces()
+        out = chain.shape_probe(aux, expands, chain.leaf_cap(expands))
+        traced = _probe_traces() > before
+    stats = owner.to_dict()
+    hits, misses = (_count(stats, "shapeProbe" + k)
+                    for k in ("Hits", "Misses"))
+    assert hits + misses == 1, stats
+    assert traced == bool(misses)
+    return out, "hit" if hits else "miss", aux
+
+
+def _with_dictionary(dictionary):
+    """aux whose build table carries n_name under another dictionary."""
+    def edit(aux):
+        scan, table = aux
+        cols = dict(table.columns)
+        name = next(n for n, c in cols.items() if c.dictionary is not None)
+        cols[name] = type(cols[name])(cols[name].values, None, dictionary)
+        return scan, type(table)(table.slots, table.base, cols)
+    return edit
+
+
+def _other_residency(column, shorten=0):
+    """aux whose resident `column` is held the other way round (plain
+    for dict, dict for plain; what the store chose depends on who built
+    the column first in this process), or, with `shorten`, as a dict
+    with that many values fewer."""
+    def edit(aux):
+        scan = dict(aux[0])
+        rc = scan[column]
+        if rc.kind == "plain" or shorten:
+            values, codes = np.unique(np.asarray(rc.decode_full()),
+                                      return_inverse=True)
+            arrays = (jnp.asarray(codes.astype(np.int8)),
+                      jnp.asarray(values[:len(values) - shorten]))
+            scan[column] = ResidentColumn("dict", arrays, rc.n_rows)
+        else:
+            scan[column] = ResidentColumn("plain", (rc.decode_full(),),
+                                          rc.n_rows)
+        return (scan,) + tuple(aux[1:])
+    return edit
+
+
+def _shape(batch):
+    """What the probe's callers read of it."""
+    return (_fragment_batch_sig(batch),
+            {n: (c.values.dtype, c.values.shape, c.dictionary, c.lazy)
+             for n, c in batch.columns.items()}, batch.mask.shape)
+
+
+def test_second_probe_of_a_chain_runs_no_trace():
+    _out, first, _aux = _probe(Q1)
+    _out, second, _aux = _probe(Q1)
+    assert (first, second) == ("miss", "hit")
+    assert _cached_purposes()["chain_shape_probe"] == 1
+
+
+def test_tasks_probing_a_cold_chain_together_agree():
+    """Pinned tasks of a stage ask at once while nobody has traced: every
+    one of them is answered alike, each counts one hit or one miss, and
+    the entry ends with the one result (a lost update would leave none,
+    or two)."""
+    chains = [_chain(Q1) for _ in range(12)]
+    prepared = [(c,) + c.prep()[:2] for c in chains]
+    owners = [RuntimeStats() for _ in chains]
+    shapes, errors = [None] * len(chains), []
+    start = threading.Barrier(len(chains))
+
+    def ask(i):
+        chain, aux, expands = prepared[i]
+        try:
+            with owners[i].activate():
+                start.wait(timeout=60)
+                shapes[i] = _shape(chain.shape_probe(
+                    aux, expands, chain.leaf_cap(expands)))
+        except Exception as e:   # noqa: BLE001 -- reported below
+            errors.append(e)
+    threads = [threading.Thread(target=ask, args=(i,))
+               for i in range(len(chains))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads), errors
+    assert all(sh == shapes[0] for sh in shapes) and shapes[0]
+    counts = [(_count(o.to_dict(), "shapeProbeHits"),
+               _count(o.to_dict(), "shapeProbeMisses")) for o in owners]
+    assert all(h + m == 1 for h, m in counts), counts
+    assert sum(m for _h, m in counts) >= 1
+    entries = [e for key, e in list(FRAGMENT_JIT_CACHE._entries.items())
+               if key[0] == "chain_shape_probe"]
+    assert len(entries) == 1 and len(entries[0]._results) == 1
+    out, how, _aux = _probe(Q1)
+    assert how == "hit" and _shape(out) == shapes[0]
+
+
+def test_no_false_share_of_the_shape_probe_between_literals():
+    a, b = Q6.format(q=24), Q6.format(q=11)
+    assert [_probe(sql)[1] for sql in (a, b, a, b)] \
+        == ["miss", "miss", "hit", "hit"]
+    assert _cached_purposes()["chain_shape_probe"] == 2
+
+
+@pytest.mark.parametrize("other", [("X", "Y"),
+                                   tuple("ABCDEFGHIJKLMNOPQRSTUVWXY")],
+                         ids=["other_domain_size", "same_size_other_values"])
+def test_no_false_share_of_the_shape_probe_between_key_dictionaries(other):
+    """A build table's dictionary is part of aux's treedef: the probe of
+    a task that holds another one is its own, and says so."""
+    mine, how, _aux = _probe(JOIN)
+    assert how == "miss"
+    key = next(n for n, c in mine.columns.items() if c.dictionary)
+    assert len(mine.columns[key].dictionary) == 25
+    theirs, how, _aux = _probe(JOIN, _with_dictionary(other))
+    assert how == "miss"
+    assert theirs.columns[key].dictionary == other
+    for edit, expected in ((None, mine), (_with_dictionary(other), theirs)):
+        again, how, _aux = _probe(JOIN, edit)
+        assert how == "hit" and _shape(again) == _shape(expected)
+    assert _cached_purposes()["chain_shape_probe"] == 1     # one key
+
+
+def test_no_false_share_of_the_shape_probe_between_plain_and_dict_residency():
+    """The resident store's choice for a column is part of aux's treedef
+    (`ResidentColumn.kind`), and a dictionary's length of its avals."""
+    out, how, aux = _probe(Q1)
+    assert how == "miss"
+    other, how, flipped = _probe(Q1, _other_residency("quantity"))
+    assert how == "miss" and _shape(other) == _shape(out)
+    assert {aux[0]["quantity"].kind, flipped[0]["quantity"].kind} \
+        == {"plain", "dict"}
+    assert _probe(Q1, _other_residency("quantity", shorten=1))[1] == "miss"
+    assert _probe(Q1, _other_residency("quantity"))[1] == "hit"
+    assert _probe(Q1)[1] == "hit"
+
+
+def test_shape_probe_after_invalidation_runs_its_body_again():
+    assert _probe(Q1)[1] == "miss"
+    assert _probe(Q1)[1] == "hit"
+    assert FRAGMENT_JIT_CACHE.invalidate_all() >= 1     # what DDL does
+    assert _probe(Q1)[1] == "miss"
+
+
+@pytest.mark.parametrize("sql", [Q1, Q6.format(q=24), JOIN],
+                         ids=["q1", "q6", "join"])
+def test_a_probe_hit_hands_back_what_a_miss_did(sql):
+    """... and both what an uncached `jax.eval_shape` over the chain
+    says: `_fragment_batch_sig`, key dtypes, dictionaries, laziness."""
+    miss, how, aux = _probe(sql)
+    assert how == "miss"
+    hit, how, _aux = _probe(sql)
+    assert how == "hit" and _shape(hit) == _shape(miss)
+    chain = _chain(sql)
+    _aux, expands, _deferred = chain.prep()
+    plain = jax.eval_shape(
+        lambda p, v: chain.make(p, v, aux, expands, chain.leaf_cap(expands)),
+        jnp.int64(0), jnp.int64(1))
+    assert _shape(plain) == _shape(hit)
+
+
+def test_a_probe_that_raises_raises_on_every_execution(monkeypatch):
+    """A chain the probe cannot trace is declined on the second execution
+    as on the first (nothing is cached of a failure), and the query
+    answers through the streaming path both times."""
+    def unsupported(self, *args, **kwargs):
+        raise NotImplementedError("not in this test")
+    monkeypatch.setattr(ChainProgram, "make", unsupported)
+    sql = Q6.format(q=24)
+    r = LocalQueryRunner("sf0.01", plan_cache=PlanCache(), config=CONFIG)
+    expected = r.execute_reference(sql)
+    for _execution in range(3):
+        res = r.execute(sql)
+        stats = res.runtime_stats
+        _assert_rows_equal(res, expected, False)
+        assert _count(stats, "fusionDeclinedProbeUnsupported") == 1, stats
+        assert _count(stats, "shapeProbeMisses") == 1
+        assert "shapeProbeHits" not in stats

@@ -10,7 +10,6 @@ TPU serving problem where the expensive artifact is the compiled XLA
 executable, so the cache key must be the canonical (value-free) plan
 structure plus the execution-config fingerprint."""
 import threading
-import time
 import urllib.request
 
 import pytest
@@ -426,8 +425,14 @@ def test_http_fair_share_across_groups():
         done = []
         lock = threading.Lock()
 
+        # both groups offer work from the same instant: a 50 ms head
+        # start let group a drain four warm queries before b had asked
+        # once, and said nothing of fairness either way
+        start = threading.Barrier(2)
+
         def run(source, n):
             c = StatementClient(s.uri, source=source)
+            start.wait(timeout=30)
             for _ in range(n):
                 c.execute("select count(*) from region")
                 with lock:
@@ -435,10 +440,8 @@ def test_http_fair_share_across_groups():
 
         threads = [threading.Thread(target=run, args=("src-a", 4)),
                    threading.Thread(target=run, args=("src-b", 4))]
-        # stagger starts so group a enqueues a backlog first
-        threads[0].start()
-        time.sleep(0.05)
-        threads[1].start()
+        for t in threads:
+            t.start()
         for t in threads:
             t.join()
         # fair share: group b finishes work before group a fully drains
