@@ -118,6 +118,16 @@ def generate_values_at(table: str, column: str, sf: float, ids,
     return m.generate_values_at(table, column, sf, ids)
 
 
+def generate_dictionary_at(table: str, column: str, sf: float, ids,
+                           connector_id: Optional[str] = None):
+    """(codes, values) where the connector can give a string column so
+    (the generated catalogs' enumerated columns), else None: the caller
+    falls back to `generate_values_at`."""
+    m = _CONNECTORS[connector_id] if connector_id else _module_for_table(table)
+    fn = getattr(m, "generate_dictionary_at", None)
+    return None if fn is None else fn(table, column, sf, ids)
+
+
 # ---------------------------------------------------------------------------
 # splits (reference ConnectorSplitManager / TpchSplitManager)
 # ---------------------------------------------------------------------------

@@ -635,9 +635,10 @@ _DICTS: Dict[Tuple[str, str, str], tuple] = {
 # stricter compression bar) or known degenerate ("rle" constants).  The
 # store falls back to empirical selection for unhinted columns.
 _ENCODING_HINTS: Dict[Tuple[str, str, str], str] = {
-    # lineitem rows are grouped by order: orderkey is monotone (~4-row
-    # runs); orders/part/etc. keys are 1-row runs and stay unhinted
-    ("tpch", "lineitem", "orderkey"): "rle",
+    # (lineitem.orderkey, monotone in ~4-row runs, is NOT hinted: 15 M
+    # runs at SF10 halve its bytes, and their decode, a search and a
+    # 64-bit running sum a chunk, took 4.6 ms a 64K-row chunk on the chip
+    # where a plain column is a slice: 4.2 s of a Q12, PERF.md PR 32)
     ("tpch", "orders", "shippriority"): "rle",     # constant 0
     # tpcds co-bucket layouts: sales/returns rows grouped by order
     ("tpcds", "web_sales", "ws_order_number"): "rle",

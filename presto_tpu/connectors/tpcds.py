@@ -1431,6 +1431,13 @@ def generate_values_at(table: str, column: str, sf: float,
     return out
 
 
+def generate_dictionary_at(table: str, column: str, sf: float,
+                           ids: np.ndarray):
+    """As tpch.generate_dictionary_at."""
+    out = _GENERATORS[table](column, np.asarray(ids, dtype=np.int64), sf)
+    return out if isinstance(out, tuple) else None
+
+
 def _connector_stats(handle) -> float:
     sf = dict(handle.extra).get("scaleFactor", 0.01)
     return float(table_row_count(handle.table_name, sf))

@@ -667,6 +667,16 @@ def generate_values_at(table: str, column: str, sf: float,
     return raw.tolist()
 
 
+def generate_dictionary_at(table: str, column: str, sf: float,
+                           idx: np.ndarray):
+    """(codes, values) of a dictionary-shaped string column at arbitrary
+    row indices, or None where the generator makes strings row by row:
+    an output boundary ships the codes and the few values, not a Python
+    string a row."""
+    raw = _GENERATORS[table](column, np.asarray(idx, dtype=np.int64), sf)
+    return raw if isinstance(raw, tuple) else None
+
+
 def generate_block(table: str, column: str, sf: float, start: int, count: int):
     """Column data for rows [start, start+count) as a Block."""
     raw = generate_column(table, column, sf, start, count)

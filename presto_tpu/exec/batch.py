@@ -342,6 +342,20 @@ def batch_to_page(batch: Batch, names, types) -> Page:
         if col.lazy is not None:
             from ..connectors import catalog as _catalog
             cid, table, column, sf = col.lazy
+            from ..common.block import DictionaryBlock as HB, VariableWidthBlock as VB
+            coded = _catalog.generate_dictionary_at(table, column, sf,
+                                                    values, cid)
+            if coded is not None:
+                # an enumerated column: int32 codes and its few values,
+                # not a Python string a row
+                ids, entries = np.asarray(coded[0], dtype=np.int32), \
+                    list(coded[1])
+                if nulls is not None and nulls.any():
+                    ids = ids.copy()
+                    ids[nulls] = len(entries)
+                    entries.append(None)
+                blocks.append(HB(ids, VB.from_strings(entries)))
+                continue
             strings = _catalog.generate_values_at(table, column, sf, values,
                                                   cid)
             if nulls is not None:
@@ -402,70 +416,142 @@ def batch_to_page(batch: Batch, names, types) -> Page:
     return Page(blocks, len(keep))
 
 
+def _host_column(typ: Type, block):
+    """Host block -> (values, nulls or None, dictionary or None) as numpy
+    arrays of the block's own length, or None for a block kind that only
+    `block_to_column` knows (arrays)."""
+    from ..common.block import Int128Block
+    if isinstance(block, DictionaryBlock):
+        flat = decode_to_flat(block.dictionary)
+        if isinstance(flat, VariableWidthBlock):
+            nulls = None
+            if flat.nulls is not None:
+                nulls = flat.null_mask()[block.ids]
+            return block.ids, nulls, tuple(flat.to_pylist())
+    block = decode_to_flat(block)
+    if isinstance(block, VariableWidthBlock):
+        strings = block.to_pylist()
+        uniq = sorted({s for s in strings if s is not None})
+        index = {s: i for i, s in enumerate(uniq)}
+        codes = np.fromiter((index.get(s, 0) for s in strings),
+                            dtype=np.int32, count=len(strings))
+        nulls = block.null_mask() if block.nulls is not None else None
+        return codes, nulls, tuple(uniq)
+    if isinstance(block, Int128Block):
+        # device holds long decimals narrowed to int64 (batch_to_page widens
+        # on the way back out); values beyond int64 have no device form
+        ints = block.to_pylist()
+        nulls = np.fromiter((v is None for v in ints), dtype=bool,
+                            count=len(ints))
+        values = np.fromiter((0 if v is None else v for v in ints),
+                             dtype=np.int64, count=len(ints))
+        return values, nulls if nulls.any() else None, None
+    if not isinstance(block, FixedWidthBlock):
+        return None
+    return _logical_np(typ, block.values), block.nulls, None
+
+
+def _padded(values: np.ndarray, capacity: int) -> np.ndarray:
+    if len(values) == capacity:
+        return values
+    out = np.zeros((capacity,) + values.shape[1:], dtype=values.dtype)
+    out[:len(values)] = values
+    return out
+
+
 def pages_to_batches(pages, names, types, capacity):
-    """Host pages (exchange input) -> device batches with STABLE dictionaries.
+    """Host pages (exchange input) -> DENSE device batches with STABLE
+    dictionaries.
+
+    A producer that filters hands over many small pages (a 64K-row scan
+    batch that keeps 1 % is a 700-row page): they are concatenated on the
+    host, in arrival order, into batches of up to `capacity` live rows,
+    so a consumer pays its per-batch work (a launch, a probe step at full
+    capacity) once per `capacity` LIVE rows; a page larger than
+    `capacity` is chunked.
 
     Pages arriving from different producer tasks carry independent
     dictionaries; jitted consumers (agg tables, concat for joins) need one
-    dictionary per column across all batches, so string columns are remapped
-    to a union dictionary first.  Pages larger than `capacity` are chunked.
+    dictionary per column across all batches, so string columns are
+    remapped (one table lookup a page) to a union dictionary, which needs
+    every page before the first batch; numeric-only schemas stream.
     """
-    from ..common.block import block_to_values
-
     string_cols = [i for i, t in enumerate(types)
                    if isinstance(t, (VarcharType, CharType))]
-    if not string_cols:
-        # numeric-only schema: stream page by page
+    if string_cols:
+        pages = [p for p in pages if p.position_count]
+
+    def host_pages():
         for page in pages:
-            for lo in range(0, page.position_count, capacity):
-                n = min(capacity, page.position_count - lo)
-                cols = {}
-                for name, typ, block in zip(names, types, page.blocks):
-                    chunk = block if (lo == 0 and n == page.position_count) \
-                        else block.take(np.arange(lo, lo + n))
-                    cols[name] = block_to_column(typ, chunk, capacity)
-                mask = np.zeros(capacity, dtype=bool)
-                mask[:n] = True
-                yield Batch(cols, jnp.asarray(mask))
-        return
+            if page.position_count:
+                yield page, [_host_column(t, b)
+                             for t, b in zip(types, page.blocks)]
 
-    pages = [p for p in pages if p.position_count]
-    if not pages:
-        return
-    # union dictionary per string column; cache the decoded strings for reuse
+    staged = host_pages()
     unions = {}
-    decoded = {}  # (page index, col index) -> list of strings
-    for i in string_cols:
-        seen = set()
-        for pi, page in enumerate(pages):
-            strings = block_to_values(types[i], page.blocks[i])
-            decoded[(pi, i)] = strings
-            seen.update(s for s in strings if s is not None)
-        uniq = tuple(sorted(seen))
-        unions[i] = (uniq, {s: j for j, s in enumerate(uniq)})
+    if string_cols:
+        staged = list(staged)
+        for i in string_cols:
+            seen = set()
+            for _page, cols in staged:
+                seen.update(s for s in cols[i][2] if s is not None)
+            uniq = tuple(sorted(seen))
+            unions[i] = (uniq, {s: j for j, s in enumerate(uniq)})
 
-    for pi, page in enumerate(pages):
-        for lo in range(0, page.position_count, capacity):
-            n = min(capacity, page.position_count - lo)
-            cols = {}
-            for i, (name, typ) in enumerate(zip(names, types)):
-                block = page.blocks[i]
+    def emit(group, rows):
+        """One batch of the `rows` rows of `group`: [(page, cols, lo, hi)]."""
+        out = {}
+        for i, name in enumerate(names):
+            if group[0][1][i] is None:
+                # a block only block_to_column knows: its page is a
+                # group of its own
+                (page, _cols, lo, hi), = group
+                out[name] = block_to_column(
+                    types[i], page.blocks[i].take(np.arange(lo, hi)),
+                    capacity)
+                continue
+            parts, null_parts, any_null = [], [], False
+            for _page, cols, lo, hi in group:
+                values, nulls, dictionary = cols[i]
+                values = values[lo:hi]
                 if i in unions:
-                    uniq, index = unions[i]
-                    strings = decoded[(pi, i)][lo:lo + n]
-                    codes = np.zeros(capacity, dtype=np.int32)
-                    nm = np.zeros(capacity, dtype=bool)
-                    for j, s in enumerate(strings):
-                        if s is None:
-                            nm[j] = True
-                        else:
-                            codes[j] = index[s]
-                    nulls = jnp.asarray(nm) if nm.any() else None
-                    cols[name] = Column(jnp.asarray(codes), nulls, uniq)
-                else:
-                    chunk = block if (lo == 0 and n == page.position_count) \
-                        else block.take(np.arange(lo, lo + n))
-                    cols[name] = block_to_column(typ, chunk, capacity)
-            mask = np.zeros(capacity, dtype=bool)
-            mask[:n] = True
-            yield Batch(cols, jnp.asarray(mask))
+                    index = unions[i][1]
+                    lut = np.fromiter((index.get(s, 0) for s in dictionary),
+                                      dtype=np.int32, count=len(dictionary))
+                    values = lut[values] if len(lut) else \
+                        np.zeros(hi - lo, dtype=np.int32)
+                parts.append(values)
+                null_parts.append(None if nulls is None else nulls[lo:hi])
+                # (a string column carries nulls only where one is set,
+                # a numeric one wherever its block does: as page_to_batch)
+                any_null = any_null or (nulls is not None and (
+                    i not in unions or bool(nulls[lo:hi].any())))
+            values = parts[0] if len(parts) == 1 else np.concatenate(parts)
+            nulls = None
+            if any_null:
+                nulls = jnp.asarray(_padded(np.concatenate(
+                    [np.zeros(len(v), dtype=bool) if n is None else n
+                     for v, n in zip(parts, null_parts)]), capacity))
+            out[name] = Column(jnp.asarray(_padded(values, capacity)), nulls,
+                               unions[i][0] if i in unions else None)
+        mask = np.zeros(capacity, dtype=bool)
+        mask[:rows] = True
+        return Batch(out, jnp.asarray(mask))
+
+    group, rows = [], 0
+    for page, cols in staged:
+        alone = any(c is None for c in cols)
+        lo = 0
+        while lo < page.position_count:
+            if rows == capacity or (rows and alone):
+                yield emit(group, rows)
+                group, rows = [], 0
+            hi = min(page.position_count, lo + capacity - rows)
+            group.append((page, cols, lo, hi))
+            rows += hi - lo
+            lo = hi
+            if alone:
+                yield emit(group, rows)
+                group, rows = [], 0
+    if group:
+        yield emit(group, rows)

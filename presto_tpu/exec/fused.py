@@ -848,7 +848,14 @@ def record_chain_stats(stats, chain: "FusedChain", counts, n_chunks: int,
     _instrument wrapper (fused_stream yields through it)."""
     if stats is None or counts is None:
         return
-    vals = [int(v) for v in host_get(counts, "chain_counts")]
+    fold_chain_counts(stats, chain, host_get(counts, "chain_counts"),
+                      n_chunks, wall_s, skip_root)
+
+
+def fold_chain_counts(stats, chain: "FusedChain", counts, n_chunks: int,
+                      wall_s: float = 0.0, skip_root: bool = False) -> None:
+    """`record_chain_stats` for counters that are on the host already."""
+    vals = [int(v) for v in counts]
     root = chain.node_ids[-1] if chain.node_ids else None
     for nid, rows in zip(chain.node_ids, vals):
         if nid is None:
@@ -976,6 +983,114 @@ def fused_stream(compiler, node: P.PlanNode):
                 record_chain_stats(compiler.ctx.stats, chain, acc,
                                    len(chunks), skip_root=True)
     return gen()
+
+
+# a sparse chain's dense output is written by ONE program into a buffer
+# of this many bytes at most; a larger one streams chunk by chunk
+DENSE_STREAM_MAX_BYTES = 256 << 20
+# under this many scan chunks the chunk-by-chunk stream is taken as it is:
+# two more programs to compile for a handful of batches saved
+DENSE_STREAM_MIN_CHUNKS = 8
+
+
+def fused_dense_stream(compiler, node: P.PlanNode):
+    """A selective Filter/Project chain over a resident scan as DENSE
+    batches: what the chunk-by-chunk stream hands up as one nearly empty
+    batch a scan chunk (a launch an operator a chunk, each paying for its
+    whole-column arguments, and per-batch work in everything downstream)
+    comes out of two programs a task.  The first runs the chain over
+    every chunk and counts its live rows, a chunk (one host sync brings
+    them back, with the per-step totals EXPLAIN ANALYZE reads); the
+    second runs it again, moves each chunk's live rows to its front
+    (`ops.compact_front`) and writes the chunk where the rows of the
+    chunks before it end.  Chosen by what the counts show: where a
+    quarter of the scanned rows or more stay live the stream is dense
+    already and this returns None (as it does for a chain it cannot
+    assemble), and the caller streams as before."""
+    cfg = compiler.ctx.config
+    analyzing = compiler.ctx.stats is not None
+    if compiler.ctx.memory.limited or not cfg.fuse_pipelines \
+            or (analyzing and cfg.analyze_unfused):
+        return None
+    key = ("fdense", node.id)
+    if compiler._jit_cache.get(key, False) is None:     # negative-cached
+        return None
+    chain = assemble_chain(compiler, node)
+    if chain is None or not chain.chunks or any(
+            s[0] not in ("filter", "project", "rename") for s in chain.steps):
+        compiler._jit_cache[key] = None
+        return None
+    aux, expands, _deferred = chain.prep()
+    leaf_cap = chain.leaf_cap(expands)
+    chunks = chain.chunks_for(expands, meter=True)
+    if len(chunks) < DENSE_STREAM_MIN_CHUNKS:
+        return None
+    prog = chain.program
+    signature = prog.signature(expands, leaf_cap)
+    pos_arr = jnp.asarray([c[0] for c in chunks], dtype=jnp.int64)
+    cnt_arr = jnp.asarray([c[1] for c in chunks], dtype=jnp.int64)
+
+    def count(pos_arr, cnt_arr, aux):
+        def step(pc):
+            b, c = prog.make(pc[0], pc[1], aux, expands, leaf_cap,
+                             with_counts=True)
+            return jnp.sum(b.mask, dtype=jnp.int32), c
+        live, counts = jax.lax.map(step, (pos_arr, cnt_arr))
+        return live, jnp.sum(counts, axis=0)
+
+    try:
+        counted = compiler.shared_jit(
+            node, "chain_dense_counts", count, extra=signature)(
+            pos_arr, cnt_arr, aux)
+    except NotImplementedError:     # an expression the chain cannot lower
+        compiler._jit_cache[key] = None
+        return None
+    live, totals = host_get(counted, "chain_dense_counts")
+    total = int(live.sum())
+    # (8 value bytes + 1 null byte a column: _instrument's estimate)
+    row_bytes = 9 * max(1, len(node.output_variables))
+    # the buffer: whole batches of the chain's capacity, a power of two
+    # of them (one compiled program a size class)
+    n_out = -(-total // chain.cap)
+    rows = chain.cap * (1 << max(0, n_out - 1).bit_length())
+    if total * 4 >= sum(c[1] for c in chunks) \
+            or rows * row_bytes > DENSE_STREAM_MAX_BYTES:
+        return None
+    if analyzing:
+        fold_chain_counts(compiler.ctx.stats, chain, totals, len(chunks),
+                          skip_root=True)
+    rs = compiler.ctx.runtime_stats
+    if rs is not None:
+        rs.add("denseStreamChunks", len(chunks))
+        rs.add("denseStreamBatches", n_out)
+    if total == 0:
+        return iter(())
+
+    def write(pos_arr, cnt_arr, offsets, aux):
+        def body(i, out):
+            # the chunk's live rows moved to its front (no scatter: 50-80
+            # ns an index on the chip), the whole chunk written where the
+            # rows before it end; the next one overwrites its dead tail
+            b = ops.compact_front(
+                prog.make(pos_arr[i], cnt_arr[i], aux, expands, leaf_cap))
+            return jax.tree_util.tree_map(
+                lambda dst, src: jax.lax.dynamic_update_slice_in_dim(
+                    dst, src, offsets[i], axis=0), out, b)
+        # (a chunk traced for its shapes alone: its values are dead code;
+        # one chunk of room behind the rows for the last chunk's tail)
+        empty = jax.tree_util.tree_map(
+            lambda a: jnp.zeros((rows + leaf_cap,) + a.shape[1:], a.dtype),
+            prog.make(pos_arr[0], cnt_arr[0], aux, expands, leaf_cap))
+        return jax.lax.fori_loop(0, pos_arr.shape[0], body, empty)
+
+    offsets = np.zeros(len(chunks), dtype=np.int32)
+    np.cumsum(live[:-1], out=offsets[1:])
+    dense = compiler.shared_jit(
+        node, "chain_dense_write", write, extra=signature + (rows,))(
+        pos_arr, cnt_arr, jnp.asarray(offsets), aux)
+    from .pipeline import _jit_rows_at
+    return (_jit_rows_at(dense, jnp.int32(i * chain.cap), chain.cap)
+            for i in range(n_out))
 
 
 def _empty_build_batch(build_node: P.PlanNode) -> Batch:

@@ -711,12 +711,33 @@ class Lowering:
                 out = av * bv  # scale sa+sb
                 return Column(_rescale(out, sa + sb, rs), nulls)
             if name == "divide":
-                # numerator scaled to rs + sb, then round-half-up divide
-                num = _rescale(av, sa, rs + sb)
                 safe_b = jnp.where(bv == 0, 1, bv)
-                q = jnp.sign(num) * jnp.sign(safe_b) * (
-                    (jnp.abs(num) + jnp.abs(safe_b) // 2) // jnp.abs(safe_b))
                 nulls = _or_null(nulls, bv == 0)
+                up = rs + sb - sa
+                pa = ta.precision if _is_decimal(ta) else 19
+                if up <= 0 or pa + up <= 18:
+                    # numerator scaled to rs + sb (it fits: a short
+                    # decimal's digits plus the shift stay inside int64),
+                    # then round-half-up divide
+                    num = _rescale(av, sa, rs + sb)
+                    q = jnp.sign(num) * jnp.sign(safe_b) * (
+                        (jnp.abs(num) + jnp.abs(safe_b) // 2)
+                        // jnp.abs(safe_b))
+                    return Column(q.astype(av.dtype), nulls)
+                # a long decimal (a sum): scaling the numerator up first
+                # leaves int64 long before the quotient does (TPC-H Q14's
+                # 100.00 * sum / sum at sf1), so divide digit by digit:
+                # the remainder stays below the divisor, and times ten
+                # inside int64 for any divisor under 9.2e17
+                na, nb = jnp.abs(av), jnp.abs(safe_b)
+                q = na // nb
+                r = na - q * nb
+                for _ in range(up):
+                    r = r * 10
+                    d = r // nb
+                    q, r = q * 10 + d, r - d * nb
+                q = q + (r >= nb - r)               # half away from zero
+                q = jnp.sign(av) * jnp.sign(safe_b) * q
                 return Column(q.astype(av.dtype), nulls)
             av = _rescale(av, sa, rs)
             bv = _rescale(bv, sb, rs)

@@ -18,6 +18,7 @@ The TPU analog of the reference LocalExecutionPlanner
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import json
@@ -56,6 +57,28 @@ SORT_AGG_MAX_BYTES = 6 << 30
 _jit_concat = named_jit("concat_batches",
                         lambda batches: _concat_batches(batches))
 _jit_compact = named_jit("compact", ops.compact, static_argnums=1)
+_jit_prefix = named_jit(
+    "batch_prefix",
+    lambda batch, n: jax.tree_util.tree_map(lambda a: a[:n], batch),
+    static_argnums=1)
+_jit_rows_at = named_jit(
+    "batch_rows_at",
+    lambda batch, at, n: jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, at, n), batch),
+    static_argnums=2)
+# live rows of a mask, on top of a running total where one is carried on
+# the device (operator statistics: summed a batch, fetched once a stream)
+_jit_count_live = named_jit(
+    "count_live",
+    lambda mask, total=0: total + jnp.sum(mask, dtype=jnp.int64))
+_jit_count_dropped = named_jit(
+    "count_dropped", lambda before, after, total=0: total
+    + jnp.sum(before, dtype=jnp.int64) - jnp.sum(after, dtype=jnp.int64))
+
+
+def _span(rs, name: str):
+    """`rs.span(name)`, or nothing to enter where no RuntimeStats is."""
+    return rs.span(name) if rs is not None else contextlib.nullcontext()
 
 
 def _compact_concat(batches: List[Batch]) -> Batch:
@@ -110,6 +133,123 @@ def _maybe_compact(batch: Batch) -> Batch:
     if bucket is None or bucket >= batch.capacity:
         return batch
     return _jit_compact(batch, bucket)
+
+
+def _coalesce_start(batch: Batch) -> Batch:
+    """A carry of twice `batch`'s capacity that holds its live rows as a
+    prefix: room for `capacity` live rows and, behind them, for the whole
+    of any batch written there (its dead tail is overwritten by the next
+    one or cut off)."""
+    front = ops.compact_front(batch)
+    return jax.tree_util.tree_map(
+        lambda a: jnp.concatenate([a, jnp.zeros_like(a)]), front)
+
+
+def _coalesce_append(carry: Batch, batch: Batch, fill) -> Batch:
+    """`carry`, its first `fill` rows live, with the live rows of `batch`
+    written behind them in order (the caller knows they fit into the
+    carry's first half)."""
+    front = ops.compact_front(batch)
+    return jax.tree_util.tree_map(
+        lambda dst, src: jax.lax.dynamic_update_slice_in_dim(
+            dst, src, fill, axis=0), carry, front)
+
+
+_jit_coalesce_start = named_jit("coalesce_start", _coalesce_start)
+_jit_coalesce = named_jit("coalesce_append", _coalesce_append)
+# how many batches of a stream are pulled before their live counts come
+# back in ONE host sync: short first, so that a short stream waits for
+# little, then doubling, so that a long one syncs O(log n) + n/128 times
+_DENSE_WINDOWS = (16, 32, 64, 128)
+
+
+def dense_batches(batches, rs=None, key: str = "probeCoalesce"):
+    """The same rows in the same order, in fewer batches where the stream
+    is sparse: a selective filter leaves 64K-row batches with a few
+    hundred live rows each, and everything downstream of it (a probe step
+    at full capacity, a page fetch, a launch an operator) is paid per
+    BATCH.  Chosen by what the stream shows, a window at a time: a batch
+    at least half live passes through untouched (a dense stream takes no
+    coalescing step and pays one count launch a batch and one sync a
+    window), an empty one is dropped, the others' live rows are gathered
+    on the device behind one another (`ops.compact_front` and a slice
+    update: no scatter) into batches of the stream's capacity.  A stream
+    of one batch is handed on as it is, unseen.
+    `<key>WallNanos` is the time spent here (not in the pulls),
+    `<key>dBatches` the batches folded away."""
+    it = iter(batches)
+    first = next(it, None)
+    if first is None:
+        return
+    second = next(it, None)
+    if second is None:
+        yield first
+        return
+    pending = [first, second]
+    # batches taken into carries or dropped empty, and carries handed on
+    carry, fill, yielded, absorbed, carries = None, 0, False, 0, 0
+    windows = iter(_DENSE_WINDOWS)
+    width = next(windows)
+    exhausted = False
+    while not exhausted:
+        while len(pending) < width:
+            b = next(it, None)
+            if b is None:
+                exhausted = True
+                break
+            pending.append(b)
+        width = next(windows, width)
+        out = []
+        with _span(rs, key):
+            lives = host_get([_jit_count_live(b.mask) for b in pending],
+                             "dense_batches_live")
+            for b, k in zip(pending, lives):
+                k = int(k)
+                fits = carry is not None and _same_layout(carry, b) \
+                    and max(fill + k, b.capacity) <= carry.capacity // 2
+                if k == 0:
+                    absorbed += 1
+                elif fits and k * 2 < b.capacity:
+                    carry = _jit_coalesce(carry, b, jnp.int32(fill))
+                    fill += k
+                    absorbed += 1
+                else:
+                    if carry is not None:
+                        out.append(_jit_prefix(carry, carry.capacity // 2))
+                        carry, fill, carries = None, 0, carries + 1
+                    if k * 2 >= b.capacity:
+                        out.append(b)
+                    else:
+                        carry, fill = _jit_coalesce_start(b), k
+                        absorbed += 1
+            pending = []
+        for b in out:
+            yielded = True
+            yield b
+    if carry is not None:
+        carries += 1
+        yield _jit_prefix(carry, carry.capacity // 2)
+    elif not yielded:
+        absorbed -= 1
+        yield first             # every row dead: one all-dead batch
+    if rs is not None and absorbed > carries:
+        rs.add(key + "dBatches", absorbed - carries)
+
+
+def _same_layout(a: Batch, b: Batch) -> bool:
+    """Whether `b`'s live rows can be written into `a`: the same columns
+    with the same dictionaries, null masks and dtypes."""
+    if a.columns.keys() != b.columns.keys():
+        return False
+    for name, x in a.columns.items():
+        y = b.columns[name]
+        if (x.dictionary != y.dictionary or x.lazy != y.lazy
+                or (x.nulls is None) != (y.nulls is None)
+                or (x.lengths is None) != (y.lengths is None)
+                or x.values.dtype != y.values.dtype
+                or x.values.shape[1:] != y.values.shape[1:]):
+            return False
+    return True
 
 
 _jit_sort = None
@@ -676,6 +816,14 @@ class PlanCompiler:
         key = (purpose,) + structure[1] + (tuple(extra), self._config_fp)
         return FRAGMENT_JIT_CACHE.get_or_build(key, build)
 
+    def _dense(self, batches, key: str):
+        """`dense_batches` into this task's RuntimeStats; under a memory
+        budget the stream as it is (the window of batches it looks at is
+        not reserved)."""
+        if self.ctx.memory.limited:
+            return batches
+        return dense_batches(batches, self.ctx.runtime_stats, key)
+
     def _new_spill_store(self, salt: Optional[int] = None
                          ) -> PartitionedSpillStore:
         """One place wires the two-tier + async-staging spill config into
@@ -786,20 +934,29 @@ class PlanCompiler:
             now = time.time()  # lint: allow-wall-clock
             span = times.setdefault(node.id, [now, now])
             it = src.batches()
-            while True:
-                t0 = time.perf_counter()  # lint: allow-wall-clock
-                try:
-                    b = next(it)
-                except StopIteration:
+            # rows are summed on the device, one launch a batch, and
+            # fetched once when the stream ends (or its consumer stops
+            # pulling): no host sync a batch
+            live = None
+            try:
+                while True:
+                    t0 = time.perf_counter()  # lint: allow-wall-clock
+                    try:
+                        b = next(it)
+                    except StopIteration:
+                        ent["wall_s"] += time.perf_counter() - t0  # lint: allow-wall-clock
+                        return
                     ent["wall_s"] += time.perf_counter() - t0  # lint: allow-wall-clock
-                    return
-                ent["wall_s"] += time.perf_counter() - t0  # lint: allow-wall-clock
-                span[1] = time.time()  # lint: allow-wall-clock
-                rows = int(host_get(b.mask.sum(), "operator_stats_rows"))
-                ent["rows"] += rows
-                ent["bytes"] += rows * row_bytes
-                ent["batches"] += 1
-                yield b
+                    span[1] = time.time()  # lint: allow-wall-clock
+                    live = _jit_count_live(b.mask) if live is None \
+                        else _jit_count_live(b.mask, live)
+                    ent["batches"] += 1
+                    yield b
+            finally:
+                if live is not None:
+                    rows = int(host_get(live, "operator_stats_rows"))
+                    ent["rows"] += rows
+                    ent["bytes"] += rows * row_bytes
         out = BatchSource(gen, src.names, src.types)
         # the fused-chain assembler reads scan metadata off the compiled
         # source (assemble_chain); the wrapper must not hide it, or
@@ -1258,6 +1415,11 @@ class PlanCompiler:
         cache: dict = {}  # resolution is laziness-dependent only: jit once
 
         def gen():
+            from .fused import fused_dense_stream
+            dense = fused_dense_stream(self, node)
+            if dense is not None:
+                yield from dense
+                return
             it = iter(src.batches())
             first = next(it, None)
             if first is None:
@@ -3053,20 +3215,42 @@ class PlanCompiler:
                 cfg.join_out_capacity,
                 join_type="LEFT" if full else node.join_type,
                 filter_fn=filter_fn, matched=matched)
-            return joined, overflow, total, matched
+            return (joined, overflow, total, matched,
+                    jnp.sum(batch.mask, dtype=jnp.int32))
 
         step = self.shared_jit(node, "join_step", _jstep)
+        # an INNER probe without an ON filter writes its pairs as a dense
+        # prefix of the padded output (probe_join: out_mask = j < total)
+        prefix_dense = node.join_type == P.INNER and filter_expr is None
 
-        def shrink(joined, live):
-            """Compact a joined batch whose out_capacity padding dominates:
-            downstream per-batch work (hash-agg scatter rounds, further
-            probes) scales with CAPACITY, so selective joins would
-            otherwise pay 2M-row costs for a few thousand live rows."""
-            live = int(live)
-            bucket = _bucket_for(live)
+        def trimmed(joined, live):
+            """A joined batch whose out_capacity padding dominates, cut to
+            the bucket that holds its rows: downstream per-batch work
+            (hash-agg scatter rounds, further probes) scales with
+            CAPACITY.  A slice, not a compaction: the probe input is dense
+            (dense_batches), so only an output that is a dense prefix
+            already is worth cutting."""
+            bucket = _bucket_for(int(live)) if prefix_dense else None
             if bucket is None or bucket * 4 > joined.capacity:
                 return joined
-            return _jit_compact(joined, bucket)
+            return _jit_prefix(joined, bucket)
+
+        rs = self.ctx.runtime_stats
+
+        def count(name, value):
+            if rs is not None:
+                rs.add(name, value)
+
+        def timed(name, batches):
+            """`batches`, the time inside each pull under the span `name`
+            (the consumer's time between pulls is not the stream's)."""
+            it = iter(batches)
+            while True:
+                with _span(rs, name):
+                    b = next(it, None)
+                if b is None:
+                    return
+                yield b
 
         probe_names = [n for n in out_names if n not in build_out]
 
@@ -3153,41 +3337,58 @@ class PlanCompiler:
                     yield b.select(out_names)
                 return
 
-            def probe_stream(table, batches, build_batch=None,
-                             dyn_filter=None):
+            def probe_input(batches, dyn_filter):
+                """The probe side as the join steps take it: narrowed by
+                the build side's key bounds, then dense."""
                 stats_ent = None
                 if dyn_filter is not None and self.ctx.stats is not None:
                     stats_ent = self.ctx.stats.setdefault(
                         node.id, {"rows": 0, "wall_s": 0.0, "batches": 0})
                     stats_ent.setdefault("dynamicFilterRowsDropped", 0)
-                batches = iter(batches)
-                batches = _apply_dyn_filter(batches, dyn_filter, stats_ent)
-                yield from _probe_stream_inner(table, batches, build_batch)
+                return self._dense(
+                    _apply_dyn_filter(iter(batches), dyn_filter, stats_ent),
+                    "probeCoalesce")
 
-            def _jdirect(batch, dt, matched):
-                return ops.probe_join_direct(
+            def probe_stream(table, batches, build_batch=None,
+                             dyn_filter=None):
+                yield from timed("joinProbe", _probe_stream_inner(
+                    table, probe_input(batches, dyn_filter), build_batch))
+
+            def _jdirect(batch, dt, matched, rows):
+                out, matched = ops.probe_join_direct(
                     batch, dt, probe_keys[0], build_out,
                     join_type="LEFT" if full else node.join_type,
                     filter_fn=filter_fn, matched=matched)
+                # rows in and out, summed on the device: fetched once
+                return out, matched, rows + jnp.stack(
+                    [jnp.sum(batch.mask, dtype=jnp.int64),
+                     jnp.sum(out.mask, dtype=jnp.int64)])
 
             step_direct = self.shared_jit(node, "join_direct", _jdirect)
 
             def probe_stream_direct(dt, batches, build_batch,
                                     dyn_filter=None):
-                stats_ent = None
-                if dyn_filter is not None and self.ctx.stats is not None:
-                    stats_ent = self.ctx.stats.setdefault(
-                        node.id, {"rows": 0, "wall_s": 0.0, "batches": 0})
-                    stats_ent.setdefault("dynamicFilterRowsDropped", 0)
-                batches = _apply_dyn_filter(iter(batches), dyn_filter,
-                                            stats_ent)
+                yield from timed("joinProbe", _probe_stream_direct(
+                    dt, probe_input(batches, dyn_filter), build_batch))
+
+            def _probe_stream_direct(dt, batches, build_batch):
                 matched = (jnp.zeros(build_batch.capacity, dtype=bool)
                            if full else None)
-                for b in batches:
-                    out, matched = step_direct(b, dt, matched)
-                    yield out.select(out_names)
-                if full:
-                    yield unmatched_build(build_batch, matched)
+                rows, steps = jnp.zeros(2, dtype=jnp.int64), 0
+                try:
+                    for b in batches:
+                        out, matched, rows = step_direct(b, dt, matched,
+                                                         rows)
+                        steps += 1
+                        yield out.select(out_names)
+                    if full:
+                        yield unmatched_build(build_batch, matched)
+                finally:
+                    if steps and rs is not None:
+                        rows_in, rows_out = host_get(rows, "join_stats")
+                        count("joinProbeBatches", steps)
+                        count("joinProbeRowsIn", int(rows_in))
+                        count("joinOutputRows", int(rows_out))
 
             def _probe_stream_inner(table, batches, build_batch=None):
                 # matched is threaded through for FULL joins; the build
@@ -3207,9 +3408,11 @@ class PlanCompiler:
 
                 def submit(piece):
                     nonlocal matched
-                    joined, overflow, total, matched = step(piece, table,
-                                                            matched)
-                    inflight.append((piece, joined, overflow, total))
+                    joined, overflow, total, matched, rows_in = step(
+                        piece, table, matched)
+                    count("joinProbeBatches", 1)
+                    inflight.append((piece, joined, overflow, total,
+                                     rows_in))
 
                 batches = iter(batches)
                 exhausted = False
@@ -3230,12 +3433,12 @@ class PlanCompiler:
                     if not inflight:
                         break
                     metas = host_get(
-                        [(ov, tot) for _p, _j, ov, tot in inflight],
+                        [(ov, tot, n) for _p, _j, ov, tot, n in inflight],
                         "join_overflow")
                     window = list(inflight)
                     inflight.clear()
-                    for (piece, joined, _o, _t), (ovv, livev) in zip(
-                            window, metas):
+                    for (piece, joined, _o, _t, _n), (ovv, livev, rows_in) \
+                            in zip(window, metas):
                         if bool(ovv):
                             # recursive halving on output overflow: high-
                             # fanout probes (worst case a constant-key
@@ -3246,7 +3449,9 @@ class PlanCompiler:
                                     "probe row: raise join_out_capacity")
                             work.extendleft(reversed(_split_batch(piece)))
                             continue
-                        yield shrink(joined, livev).select(out_names)
+                        count("joinProbeRowsIn", int(rows_in))
+                        count("joinOutputRows", int(livev))
+                        yield trimmed(joined, livev).select(out_names)
                 if full:
                     yield unmatched_build(build_batch, matched)
 
@@ -3258,34 +3463,41 @@ class PlanCompiler:
             # partitioned spilling)
             buf = _RevocableBuildBuffer(self, build_keys, cfg.spill_enabled)
             try:
-                from .fused import fused_materialize
-                fb = fused_materialize(self, build_src_node, cache=True)
-                if fb is not None:
-                    # fused single-program build materialization (only when
-                    # memory is unbudgeted, so no reservation bookkeeping)
-                    buf.seed([fb])
-                else:
-                    for b in self._compile(build_src_node).batches():
-                        buf.add(b)
-                collected, spill = buf.finish()
+                with _span(rs, "joinBuild"):
+                    from .fused import fused_materialize
+                    fb = fused_materialize(self, build_src_node, cache=True)
+                    if fb is not None:
+                        # fused single-program build materialization (only
+                        # when memory is unbudgeted, so no reservation
+                        # bookkeeping)
+                        buf.seed([fb])
+                    else:
+                        for b in self._dense(
+                                self._compile(build_src_node).batches(),
+                                "buildCoalesce"):
+                            buf.add(b)
+                    collected, spill = buf.finish()
                 if spill is None:
-                    build_batch = (
-                        None if not collected else collected[0]
-                        if len(collected) == 1
-                        else _compact_concat(collected))
-                    if build_batch is not None \
-                            and self.ctx.shared_jits is not None:
-                        # stage-shared tracing: sibling tasks' build sides
-                        # differ by a few rows, which would retrace every
-                        # shared join program per task — normalize to a
-                        # power-of-two bucket so the stage converges on
-                        # one build shape (costs one live-count sync)
-                        live = int(host_get(build_batch.mask.sum(),
-                                            "join_build_live"))
-                        bucket = _bucket_for(live) \
-                            or 1 << max(0, live - 1).bit_length()
-                        if bucket != build_batch.capacity:
-                            build_batch = _jit_compact(build_batch, bucket)
+                    with _span(rs, "joinBuild"):
+                        build_batch = (
+                            None if not collected else collected[0]
+                            if len(collected) == 1
+                            else _compact_concat(collected))
+                        if build_batch is not None \
+                                and self.ctx.shared_jits is not None:
+                            # stage-shared tracing: sibling tasks' build
+                            # sides differ by a few rows, which would
+                            # retrace every shared join program per task —
+                            # normalize to a power-of-two bucket so the
+                            # stage converges on one build shape (costs one
+                            # live-count sync)
+                            live = int(host_get(build_batch.mask.sum(),
+                                                "join_build_live"))
+                            bucket = _bucket_for(live) \
+                                or 1 << max(0, live - 1).bit_length()
+                            if bucket != build_batch.capacity:
+                                build_batch = _jit_compact(build_batch,
+                                                           bucket)
                     probe = self._compile(probe_src_node)
                     if build_batch is None:
                         if node.join_type == P.INNER:
@@ -3294,11 +3506,18 @@ class PlanCompiler:
                             yield null_extended(batch)
                         return
                     from .fused import _drop_null_keys, try_direct_table
-                    dropped = _drop_null_keys(build_batch,
-                                              tuple(build_keys))
-                    dt = (try_direct_table(dropped, build_keys[0],
-                                           allow_dup=False)
-                          if len(build_keys) == 1 else None)
+                    with _span(rs, "joinBuild"):
+                        dropped = _drop_null_keys(build_batch,
+                                                  tuple(build_keys))
+                        dt = (try_direct_table(dropped, build_keys[0],
+                                               allow_dup=False)
+                              if len(build_keys) == 1 else None)
+                        table = None if dt is not None else \
+                            _jits()[1](dropped, tuple(build_keys))
+                        if rs is not None:
+                            count("joinBuildRows", int(host_get(
+                                _jit_count_live(dropped.mask),
+                                "join_build_rows")))
                     if dt is not None:
                         # dense unique integer key: fanout-1 direct probe,
                         # zero per-batch host syncs (no overflow/live
@@ -3307,7 +3526,6 @@ class PlanCompiler:
                             dt, probe.batches(), build_batch,
                             dyn_filter=make_dynamic_filter(build_batch))
                         return
-                    table = _jits()[1](dropped, tuple(build_keys))
                     yield from probe_stream(
                         table, probe.batches(), build_batch,
                         dyn_filter=make_dynamic_filter(build_batch))
@@ -3935,16 +4153,23 @@ def _concat_batches(batches: List[Batch]) -> Batch:
 def _apply_dyn_filter(batches, dyn_filter, stats_ent):
     """Apply a dynamic filter to a probe stream, tracking dropped rows
     when EXPLAIN ANALYZE stats are enabled."""
-    for b in batches:
-        if dyn_filter is None:
-            yield b
-            continue
-        nb = dyn_filter(b)
-        if stats_ent is not None:
-            before, after = host_get((b.mask.sum(), nb.mask.sum()),
-                                     "dynamic_filter_rows")
-            stats_ent["dynamicFilterRowsDropped"] += int(before) - int(after)
-        yield nb
+    if dyn_filter is None:
+        yield from batches
+        return
+    dropped = None
+    try:
+        for b in batches:
+            nb = dyn_filter(b)
+            if stats_ent is not None:
+                # summed on the device, fetched once when the stream ends
+                dropped = _jit_count_dropped(b.mask, nb.mask) \
+                    if dropped is None \
+                    else _jit_count_dropped(b.mask, nb.mask, dropped)
+            yield nb
+    finally:
+        if dropped is not None:
+            stats_ent["dynamicFilterRowsDropped"] += int(
+                host_get(dropped, "dynamic_filter_rows"))
 
 
 def _split_batch(batch: Batch) -> List[Batch]:

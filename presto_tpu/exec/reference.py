@@ -145,29 +145,35 @@ def _exec_TableScanNode(node: P.TableScanNode) -> Table:
     th = node.table
     sf = dict(th.extra).get("scaleFactor", 0.01)
     n = catalog.table_row_count(th.table_name, sf, th.connector_id)
-    cols = {}
-    for v in node.outputs:
-        cname = node.assignments[v].name
-        raw = catalog.generate_column(th.table_name, cname, sf, 0, n,
-                                      th.connector_id)
-        nulls = None
-        if isinstance(raw, catalog.HostColumn):
-            nulls = raw.nulls
-            raw = raw.values
-        if isinstance(raw, tuple):
-            codes, values = raw
-            arr = np.array(values, dtype=object)[codes]
-        elif isinstance(raw, list):
-            arr = np.array(raw, dtype=object)
-        else:
-            arr = raw
-        if nulls is not None and arr.dtype == object:
-            # null strings surface as None VALUES too: grouping compares
-            # values, so a masked row must not alias its code-0 entry
-            arr = arr.copy()
-            arr[nulls] = None
-        cols[v.name] = (arr, nulls)
+    cols = {v.name: scan_column(th.table_name, node.assignments[v].name,
+                                sf, 0, n, th.connector_id)
+            for v in node.outputs}
     return Table(cols, n)
+
+
+def scan_column(table: str, cname: str, sf: float, start: int, count: int,
+                connector_id=None) -> Col:
+    """Rows [start, start + count) of one column as the evaluator reads
+    them: (values, null mask or None)."""
+    raw = catalog.generate_column(table, cname, sf, start, count,
+                                  connector_id)
+    nulls = None
+    if isinstance(raw, catalog.HostColumn):
+        nulls = raw.nulls
+        raw = raw.values
+    if isinstance(raw, tuple):
+        codes, values = raw
+        arr = np.array(values, dtype=object)[codes]
+    elif isinstance(raw, list):
+        arr = np.array(raw, dtype=object)
+    else:
+        arr = raw
+    if nulls is not None and arr.dtype == object:
+        # null strings surface as None VALUES too: grouping compares
+        # values, so a masked row must not alias its code-0 entry
+        arr = arr.copy()
+        arr[nulls] = None
+    return arr, nulls
 
 
 def _exec_ValuesNode(node: P.ValuesNode) -> Table:

@@ -633,7 +633,9 @@ class LocalQueryRunner:
             if footer:
                 text += "\n\n" + footer
         return QueryResult(["Query Plan"], [VarcharType(max(1, len(text)))],
-                           [[text]])
+                           [[text]],
+                           runtime_stats=None if rstats is None
+                           else rstats.to_dict())
 
     def _fragmenter_config(self):
         from ..sql.fragmenter import FragmenterConfig

@@ -303,26 +303,44 @@ class ExchangeInserter:
 
         lest = estimate_rows(node.left, self._calc)
         rest = estimate_rows(node.right, self._calc)
-        # INNER joins may swap sides so the smaller relation is built
+        # INNER joins may swap sides so the smaller relation is built --
+        # unless exactly one side is the scan of a table on its own dense
+        # primary key: that side builds a direct-address table (one
+        # scatter to build, one gather a probe row, no per-batch sync:
+        # exec/fused.py try_direct_table) where the other would be sorted
+        # by hash and searched a probe row, so it builds whatever its size
+        # (the optimizer's SwapJoinSides chose so already; this keeps it)
+        from .stats import primary_key_sides
+        pk_left, pk_right = primary_key_sides(self._calc, node)
+        threshold = self.config.broadcast_threshold
         if node.join_type == P.INNER and lest is not None and rest is not None \
-                and lest < rest:
+                and (pk_left if pk_left != pk_right else lest < rest):
             node.left, node.right = node.right, node.left
             node.criteria = [(r, l) for l, r in node.criteria]
             left, right = right, left
             lest, rest = rest, lest
+            pk_left, pk_right = pk_right, pk_left
 
         # record the planner's build-side assumption so the scheduler can
         # compare it against observed rows at the stage boundary and flip
         # the exchange strategy (exec/adaptive.decide_exchange)
         node.planned_build_rows = int(rest) if rest is not None else None
-        broadcast = (rest is not None
-                     and rest <= self.config.broadcast_threshold
+        broadcast = (rest is not None and rest <= threshold
                      and node.join_type in (P.INNER, P.LEFT))
         if broadcast:
             node.distribution = P.REPLICATED
             if right.dist != SINGLE or left.dist != SINGLE:
                 node.right = self._broadcast(node.right)
             return _Placed(node, left.dist, left.hash_keys)
+        if pk_right and node.join_type == P.INNER and right.dist == SOURCE \
+                and lest is not None and lest <= threshold:
+            # the small side is the PROBE: it is broadcast, every task
+            # builds over its own splits of the key's table and emits
+            # the matches that fall there (INNER only: a probe row that
+            # finds no match in one task may find it in another)
+            node.distribution = P.REPLICATED
+            node.left = self._broadcast(node.left)
+            return _Placed(node, right.dist, right.hash_keys)
 
         node.distribution = P.PARTITIONED
         lkeys = [l for l, _ in node.criteria]

@@ -318,6 +318,14 @@ def plan_runtime_filter_pushdown(root: P.PlanNode) -> P.PlanNode:
         if not getattr(node, "dynamic_filters", None):
             continue
         for recv, _src, fid, subtree in _runtime_filter_pairs(node):
+            source = (node.filtering_source if isinstance(node, P.SemiJoinNode)
+                      else node.left if subtree is node.right
+                      else node.right)
+            if isinstance(source, P.TableScanNode) and not source.pushdown:
+                # a whole table's key domain prunes nothing of the other
+                # side, and a scan that expects a summary waits for it
+                # (dynamic_filtering_wait_timeout_s a task)
+                continue
             scans = []
             trace(subtree, recv, scans)
             for scan, col in scans:

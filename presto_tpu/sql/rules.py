@@ -311,7 +311,11 @@ class SwapJoinSides(Rule):
     """Put the smaller estimated side on the build (right) side of an
     inner equi join (DetermineJoinDistributionType.java /
     ReorderJoins.java side choice; hysteresis avoids flip-flopping on
-    close estimates)."""
+    close estimates) -- unless exactly one side is the scan of a table
+    on its own dense primary key: that side builds whatever its size,
+    as a direct-address table (one scatter to build, one gather a probe
+    row, no sync a batch), where the other side would be sorted by hash
+    and searched once a probe row."""
     name = "SwapJoinSides"
     node_class = (P.JoinNode,)
     RATIO = 1.25
@@ -321,7 +325,12 @@ class SwapJoinSides(Rule):
             return None
         left = ctx.stats.rows(node.left)
         right = ctx.stats.rows(node.right)
-        if left is None or right is None or right <= left * self.RATIO:
+        if left is None or right is None:
+            return None
+        from .stats import primary_key_sides
+        pk_left, pk_right = primary_key_sides(ctx.stats, node)
+        if not (pk_left if pk_left != pk_right
+                else right > left * self.RATIO):
             return None
         return P.JoinNode(node.id, node.join_type, node.right, node.left,
                           [(r, l) for l, r in node.criteria],

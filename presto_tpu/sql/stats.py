@@ -80,6 +80,7 @@ class StatsCalculator:
 
     def __init__(self):
         self._memo: Dict[str, PlanStats] = {}
+        self._unknown_terms = 0
 
     def stats(self, node: P.PlanNode) -> PlanStats:
         got = self._memo.get(node.id)
@@ -125,8 +126,20 @@ class StatsCalculator:
         src = self.stats(node.source)
         if src.rows is None:
             return src
+        self._unknown_terms = 0
         sel, cols = self._selectivity(node.predicate, src)
+        if self._unknown_terms:
+            sampled = _sampled_selectivity(node)
+            if sampled is not None:
+                sel = sampled
         return PlanStats(max(0.0, src.rows * sel), cols)
+
+    def _unknown(self) -> float:
+        """The coefficient of a term the column stats say nothing about
+        (a comparison of two columns, a function of one); the filter's
+        estimate then comes from a sample where one is at hand."""
+        self._unknown_terms += 1
+        return UNKNOWN_FILTER_COEFFICIENT
 
     def _stats_ProjectNode(self, node: P.ProjectNode) -> PlanStats:
         src = self.stats(node.source)
@@ -235,7 +248,7 @@ class StatsCalculator:
                     n = len(e.arguments) - 1
                     if ndv:
                         return min(1.0, n / ndv), dict(src.columns)
-                return UNKNOWN_FILTER_COEFFICIENT, dict(src.columns)
+                return self._unknown(), dict(src.columns)
         if isinstance(e, CallExpression):
             name = _canon(e.display_name)
             args = e.arguments
@@ -265,7 +278,7 @@ class StatsCalculator:
                 if isinstance(a, VariableReferenceExpression) and \
                         isinstance(b, ConstantExpression):
                     return self._cmp_sel(src, a.name, op, b)
-        return UNKNOWN_FILTER_COEFFICIENT, dict(src.columns)
+        return self._unknown(), dict(src.columns)
 
     def _cmp_sel(self, src: PlanStats, var: str, op: str,
                  const: ConstantExpression):
@@ -278,14 +291,14 @@ class StatsCalculator:
                                     low=c if c is not None else cs.low,
                                     high=c if c is not None else cs.high)
                 return min(1.0, 1.0 / cs.ndv), cols
-            return UNKNOWN_FILTER_COEFFICIENT, cols
+            return self._unknown(), cols
         if op == "neq":
             if cs.ndv:
                 return 1.0 - min(1.0, 1.0 / cs.ndv), cols
-            return UNKNOWN_FILTER_COEFFICIENT, cols
+            return self._unknown(), cols
         if c is None or cs.low is None or cs.high is None \
                 or cs.high <= cs.low:
-            return UNKNOWN_FILTER_COEFFICIENT, cols
+            return self._unknown(), cols
         frac = (c - cs.low) / (cs.high - cs.low)
         frac = min(1.0, max(0.0, frac))
         if op in ("lt", "lte"):
@@ -300,13 +313,88 @@ class StatsCalculator:
         cols = dict(src.columns)
         if lo is None or hi is None or cs.low is None or cs.high is None \
                 or cs.high <= cs.low:
-            return UNKNOWN_FILTER_COEFFICIENT, cols
+            return self._unknown(), cols
         inter_lo = max(lo, cs.low)
         inter_hi = min(hi, cs.high)
         if inter_hi < inter_lo:
             return 0.0, cols
         cols[var] = replace(cs, low=inter_lo, high=inter_hi)
         return (inter_hi - inter_lo) / (cs.high - cs.low), cols
+
+
+# rows of a generated table a filter is tried on where its column stats
+# leave a term unknown, and the columns generated for it so far
+SAMPLE_ROWS = 1 << 14
+_SAMPLES: Dict[tuple, tuple] = {}
+
+
+def _sampled_selectivity(node: P.FilterNode) -> Optional[float]:
+    """The share of a generated table's first SAMPLE_ROWS rows that pass
+    the filter directly above its scan, or None (another connector, a
+    table no larger than the sample, an expression the host evaluator
+    does not know, a bound parameter).
+    The generated catalogs make any row range on the host in
+    milliseconds, and their rows are drawn independently of position; a
+    filter that keeps nothing of the sample reads as half a row of it."""
+    scan = node.source
+    if not isinstance(scan, P.TableScanNode) \
+            or scan.table.connector_id not in ("tpch", "tpcds"):
+        return None
+    from ..connectors import catalog
+    from ..exec import reference
+    th = scan.table
+    sf = dict(th.extra).get("scaleFactor", 0.01)
+    try:
+        n = SAMPLE_ROWS
+        if int(catalog.table_row_count(th.table_name, sf,
+                                       th.connector_id)) <= n:
+            # a table the sample would hold whole: the coefficient's
+            # error is bounded by its few rows, and asking every row is
+            # running the query to plan it
+            return None
+        cols = {}
+        for v in scan.outputs:
+            key = (th.connector_id, th.table_name, sf,
+                   scan.assignments[v].name, n)
+            if key not in _SAMPLES:
+                if len(_SAMPLES) >= 256:
+                    _SAMPLES.clear()
+                _SAMPLES[key] = reference.scan_column(
+                    th.table_name, key[3], sf, 0, n, th.connector_id)
+            cols[v.name] = _SAMPLES[key]
+        values, nulls = reference._eval(node.predicate,
+                                        reference.Table(cols, n))
+        keep = np.asarray(values).astype(bool)
+        if nulls is not None:
+            keep = keep & ~nulls
+        return max(0.5, float(np.count_nonzero(keep))) / n
+    except Exception:       # noqa: BLE001 -- an estimate, never a failure
+        return None
+
+
+def primary_key_scan(calc: "StatsCalculator", side: P.PlanNode,
+                     keys) -> bool:
+    """Whether `side` is a bare scan joined on ONE column that its
+    statistics call unique, dense and never null: what the executor
+    turns into a direct-address table (exec/fused.py try_direct_table),
+    one scatter to build and one gather a probe row."""
+    if len(keys) != 1 or not isinstance(side, P.TableScanNode):
+        return False
+    st = calc.stats(side)
+    cs = st.col(keys[0].name)
+    if not st.rows or cs.ndv is None or cs.low is None \
+            or cs.high is None or cs.null_fraction:
+        return False
+    from ..exec.fused import DIRECT_TABLE_MAX, DIRECT_TABLE_SPAN_RATIO
+    span = cs.high - cs.low + 1
+    return (cs.ndv >= st.rows and span <= DIRECT_TABLE_MAX
+            and span <= DIRECT_TABLE_SPAN_RATIO * st.rows)
+
+
+def primary_key_sides(calc: "StatsCalculator", join: P.JoinNode):
+    """(left, right): which sides of `join` are `primary_key_scan`s."""
+    return tuple(primary_key_scan(calc, side, [c[i] for c in join.criteria])
+                 for i, side in enumerate((join.left, join.right)))
 
 
 def _maybe_const(e) -> Optional[float]:

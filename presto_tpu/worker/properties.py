@@ -336,7 +336,14 @@ def server_kwargs_from_etc(etc_dir: str) -> Tuple[dict, Dict[str, str]]:
         props.update(load_properties(config_path))
     if os.path.exists(node_path):
         props.update(load_properties(node_path))
+    return server_kwargs_from_properties(props), props
 
+
+def server_kwargs_from_properties(props: Dict[str, str]) -> dict:
+    """Keys of config.properties / node.properties -> WorkerServer kwargs
+    (`config` among them: the keys' ExecutionConfig over the server's
+    tuned defaults).  What `--etc-dir` reads from files and
+    `WorkerServer(properties=...)` takes as a dict."""
     kwargs: dict = {}
     if "http-server.http.port" in props:
         kwargs["port"] = int(props["http-server.http.port"])
@@ -435,7 +442,7 @@ def server_kwargs_from_etc(etc_dir: str) -> Tuple[dict, Dict[str, str]]:
     # bare ExecutionConfig — file keys override, absence must not detune
     kwargs["config"] = execution_config_from_properties(
         props, base=tuned_config())
-    return kwargs, props
+    return kwargs
 
 
 def register_catalogs_from_etc(etc_dir: str) -> Dict[str, str]:

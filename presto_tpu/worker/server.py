@@ -968,6 +968,43 @@ class _QuerySpanListener:
         self._server._export_query_spans(event)
 
 
+class _HttpServer(ThreadingHTTPServer):
+    """The stock server listens with a backlog of 5.  One join query
+    opens more connections than that in the same millisecond (two tasks
+    a stage, each pulling from every task of its two source stages, and
+    the coordinator's own pulls): the overflow's SYNs are dropped and
+    retransmitted a second later, which a 0.7 s query then takes 1.7 s
+    for (PERF.md, PR 32)."""
+    request_queue_size = 128
+
+
+def _with_properties(init):
+    """`WorkerServer(properties={...})`: the keys of Presto's
+    config.properties / node.properties as a dict, applied through the
+    mapping `--etc-dir` uses (worker/properties.py).  An argument given
+    explicitly wins over a property; `config` given explicitly replaces
+    the properties' ExecutionConfig whole."""
+    import functools
+    import inspect
+    signature = inspect.signature(init)
+
+    @functools.wraps(init)
+    def with_properties(self, *args, properties=None, **kwargs):
+        if properties:
+            from .properties import server_kwargs_from_properties
+            given = signature.bind_partial(self, *args, **kwargs).arguments
+            for k, v in server_kwargs_from_properties(
+                    {str(k): str(v) for k, v in properties.items()}).items():
+                if k not in given:
+                    kwargs[k] = v
+        return init(self, *args, **kwargs)
+    with_properties.__signature__ = signature.replace(parameters=[
+        *signature.parameters.values(),
+        inspect.Parameter("properties", inspect.Parameter.KEYWORD_ONLY,
+                          default=None)])
+    return with_properties
+
+
 class WorkerServer:
     """One worker (or coordinator) process node.  With coordinator=True the
     server also hosts the embedded discovery service, like the reference
@@ -977,6 +1014,7 @@ class WorkerServer:
     # must not be kept alive by the registry)
     _live: "weakref.WeakSet" = weakref.WeakSet()
 
+    @_with_properties
     def __init__(self, port: int = 0, node_id: Optional[str] = None,
                  coordinator: bool = False,
                  discovery_uri: Optional[str] = None,
@@ -1025,7 +1063,7 @@ class WorkerServer:
             set_validation(True)
 
         handler = type("Handler", (_Handler,), {"server_ref": self})
-        self.httpd = ThreadingHTTPServer(("127.0.0.1", port), handler)
+        self.httpd = _HttpServer(("127.0.0.1", port), handler)
         self.port = self.httpd.server_port
         scheme = "http"
         if https_cert_path:

@@ -668,7 +668,11 @@ class TpuTask:
         batch_to_page here (its device fetch is `hostSync.page_fetch*`
         inside it), then serialize_page and buffers.add in the page loop."""
         from ..exec.batch import batch_to_page
-        it = iter(src.batches())
+        from ..exec.pipeline import dense_batches
+        # a selective fragment hands up many nearly empty batches: made
+        # dense on the device first, so that a page (its fetch, its split
+        # and serialisation, the consumer's batch) is paid per live rows
+        it = dense_batches(src.batches(), self.stats, "outputCoalesce")
         while True:
             with self.stats.span("pipelineDrain"):
                 batch = next(it, None)

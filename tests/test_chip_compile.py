@@ -225,3 +225,92 @@ def test_ici_exchange_compiles_on_four_chip_mesh(topo):
     assert "all-to-all" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# the join path of tpch10-joins: the programs PR 32 brought
+# ---------------------------------------------------------------------------
+
+SPARSE_FILTER = ("select l_orderkey, l_partkey, l_extendedprice from lineitem "
+                 "where l_shipdate = date '1996-03-13'")
+FULL_JOIN = ("select l_orderkey, o_custkey from lineitem full join orders "
+             "on l_orderkey = o_orderkey")
+
+
+@pytest.mark.parametrize("sql,program,config", [
+    # a selective chain as dense batches: the count pass and the write
+    (SPARSE_FILTER, "chain_dense_counts", {}),
+    (SPARSE_FILTER, "chain_dense_write", {}),
+    # the unfused join against a direct-address table, its row counters
+    # carried on the device
+    (FULL_JOIN, "join_direct", {}),
+    # the sorted-hash probe step with its live count
+    ("select o_orderkey, l_linenumber from orders full join lineitem "
+     "on o_orderkey = l_orderkey", "join_step", {}),
+], ids=["dense_counts", "dense_write", "join_direct", "join_step"])
+def test_join_path_program_compiles_and_fits(monkeypatch, one_chip, sql,
+                                             program, config):
+    """At the served chunk (64K rows) over sf0.1's resident columns."""
+    fn, args = _capture_program(monkeypatch, sql, program, schema="sf0.1",
+                                batch_rows=1 << 16, **config)
+    compiled = fn.lower(*_on(one_chip, args)).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert 0 < need < HBM_BYTES, mem
+
+
+def test_stream_coalescer_programs_compile(one_chip):
+    """`dense_batches`' append step, the count behind it and the window
+    cut of a dense buffer, for a batch of an int64, a decimal, a date and
+    a dictionary column."""
+    from presto_tpu.exec.batch import Batch, Column
+    cap = 1 << 16
+
+    def batch(rows):
+        def col(dtype, nulls=False, dictionary=None):
+            return Column(jnp.zeros(rows, dtype),
+                          jnp.zeros(rows, bool) if nulls else None,
+                          dictionary)
+        return Batch({"k": col(jnp.int64), "price": col(jnp.int64, True),
+                      "day": col(jnp.int32),
+                      "mode": col(jnp.int32, False, ("AIR", "MAIL"))},
+                     jnp.zeros(rows, bool))
+
+    carry, piece = _on(one_chip, batch(2 * cap)), _on(one_chip, batch(cap))
+    fill = _on(one_chip, jnp.int32(0))
+    pipeline._jit_coalesce_start.lower(piece).compile()
+    text = pipeline._jit_coalesce.lower(carry, piece, fill).compile().as_text()
+    # rows move by shifts and selects: no scatter, no gather
+    assert " scatter(" not in text and " gather(" not in text
+    pipeline._jit_count_live.lower(piece.mask).compile()
+    pipeline._jit_count_live.lower(piece.mask,
+                                   _on(one_chip, jnp.int64(0))).compile()
+    pipeline._jit_rows_at.lower(_on(one_chip, batch(8 * cap)), fill,
+                                cap).compile()
+    pipeline._jit_prefix.lower(_on(one_chip, batch(4 * cap)), cap).compile()
+
+
+@pytest.mark.parametrize("values_dtype", [jnp.int64, jnp.int32],
+                         ids=["int64", "int32"])
+def test_run_length_chunk_decode_compiles_without_a_search_a_row(
+        one_chip, values_dtype):
+    """l_orderkey's 15M runs at SF10: a chunk's decode is one scalar
+    search, a slice of run starts, one scatter-add and a running sum --
+    no `while` (the per-row binary search) and no per-row gather of the
+    run table in the scan loop."""
+    from presto_tpu.storage.encodings import ResidentColumn
+    chunk, runs, rows = 1 << 16, 15_000_000, 60_000_000
+    col = ResidentColumn(
+        "rle", (jnp.zeros(runs + 1, values_dtype),
+                jnp.zeros(runs + 1, jnp.int64)), rows, base=jnp.int64(0))
+
+    def decode(col, pos):
+        return col.slice_decode(pos, chunk)
+
+    text = jax.jit(decode).lower(
+        _on(one_chip, col), _on(one_chip, jnp.int64(0))).compile().as_text()
+    # the one search is a loop of its own; nothing else may loop or gather
+    # `chunk` rows from the 15M-entry run table
+    assert text.count(" while(") <= 1
+    assert f"[{chunk}]" in text
