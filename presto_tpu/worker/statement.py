@@ -320,6 +320,11 @@ class ManagedQuery:
     started_at: Optional[float] = None
     finished_at: Optional[float] = None
     done: threading.Event = field(default_factory=threading.Event)
+    # notified at every transition a client can learn something from
+    # (QUEUED -> RUNNING, a streaming result handed over, finished): what
+    # a statement's poll sleeps on
+    _changed: threading.Condition = field(
+        default_factory=threading.Condition)
     _cancelled: bool = False
     _admitted: bool = False     # holds a resource-group running slot
     memory_estimate: Optional[int] = None   # admission claim, bytes
@@ -343,6 +348,10 @@ class ManagedQuery:
         if not own:
             return self._result_stats
         return {**(self._result_stats or {}), **own}
+
+    def notify_changed(self) -> None:
+        with self._changed:
+            self._changed.notify_all()
 
     def stats(self) -> dict:
         now = self.finished_at or time.time()
@@ -497,6 +506,7 @@ class DispatchManager:
             return
         q.state = RUNNING
         q.started_at = time.time()
+        q.notify_changed()
         # submit -> an executor thread runs it (admission, thread start)
         q.rstats.add("statementQueuedWallNanos",
                      time.perf_counter_ns() - q._created_ns, "NANO")
@@ -512,6 +522,7 @@ class DispatchManager:
                     q.columns = result.columns
                     q._stats_src = result.stats
                     q._row_iter = iter(result.row_iter)
+                    q.notify_changed()
                     return
                 q.columns = [{"name": n, "type": str(t)}
                              for n, t in zip(result.column_names,
@@ -556,6 +567,7 @@ class DispatchManager:
         q.error = error
         q.finished_at = time.time()
         q.done.set()
+        q.notify_changed()
         from .events import QueryCompletedEvent
         now = q.finished_at
         self.events.query_completed(QueryCompletedEvent(
@@ -599,11 +611,24 @@ class DispatchManager:
                 for q in qs]
 
     # -- protocol responses ----------------------------------------------
+    @staticmethod
+    def _poll(q: ManagedQuery, ready: Callable[[], bool],
+              wait_s: float) -> None:
+        """One long-poll on the client's behalf: the handler sleeps until
+        the query has something to tell (`ready`, re-checked at each
+        transition the query announces), at most `wait_s`."""
+        timed_out = False
+        if not ready():
+            with q.rstats.span("statementPollWait"), q._changed:
+                timed_out = not q._changed.wait_for(ready, wait_s)
+        q.rstats.add("statementPolls", 1)
+        q.rstats.add("statementPollTimeouts", int(timed_out))
+
     def queued_response(self, q: ManagedQuery, token: int,
                         base_uri: str, wait_s: float = 0.1) -> dict:
-        if q.state == QUEUED:
-            with q.rstats.span("statementPollWait"):
-                q.done.wait(wait_s)
+        # (`wait_s` 0: the POST's own answer, which is no poll)
+        if wait_s:
+            self._poll(q, lambda: q.state != QUEUED, wait_s)
         resp = {"id": q.query_id,
                 "infoUri": f"{base_uri}/v1/query/{q.query_id}",
                 "stats": q.stats()}
@@ -629,6 +654,7 @@ class DispatchManager:
     def _ensure_chunk(self, q: ManagedQuery, token: int) -> None:
         """Pull rows from the streaming iterator until chunk `token`
         exists or the stream is drained; forget acknowledged chunks."""
+        chunk_rows = self.RESULT_CHUNK_ROWS
         while not q._drained and q._max_token < token:
             # the rows' way to the client: on the single-node path the
             # pipeline itself runs inside this pull, on the handler's
@@ -636,17 +662,20 @@ class DispatchManager:
             # ... and from a roomy frame, like every thread that may
             # trace or lower a program (utils/stack.py)
             with q.rstats.activate(), q.rstats.span("statementDrain"):
-                rows = roomy(list, itertools.islice(
-                    q._row_iter, self.RESULT_CHUNK_ROWS))
-            if not rows:
+                rows = roomy(list, itertools.islice(q._row_iter,
+                                                    chunk_rows))
+            if rows:
+                q._max_token += 1
+                q._chunks[q._max_token] = rows
+                q.rows_served += len(rows)
+            if len(rows) < chunk_rows:
+                # a short pull: islice ran the iterator to its end (the
+                # runner has released its execution), so this chunk is
+                # the last and its response the final one
                 q._drained = True
                 if q._stats_src is not None \
                         and q._stats_src is not q.rstats:
                     q._result_stats = q._stats_src.to_dict()
-                break
-            q._max_token += 1
-            q._chunks[q._max_token] = rows
-            q.rows_served += len(rows)
         for t in [t for t in q._chunks if t < token - self._CHUNK_KEEP + 1]:
             del q._chunks[t]
 
@@ -685,15 +714,13 @@ class DispatchManager:
     def executing_response(self, q: ManagedQuery, token: int,
                            base_uri: str, wait_s: float = 0.5) -> dict:
         q.last_access = time.time()
+        # until the executor hands over a streaming result's iterator (the
+        # woken handler's thread then runs the pipeline, in this same
+        # request) or the query is done
+        self._poll(q, lambda: q._row_iter is not None or q.done.is_set(),
+                   wait_s)
         if q._row_iter is not None:
             return self._executing_streaming(q, token, base_uri)
-        if not q.done.is_set():
-            # a handler blocked on the client's behalf until the query is
-            # done or the poll times out.  (A poll that arrives before the
-            # executor has handed over a streaming result's iterator waits
-            # out the whole `wait_s` here: `done` is only set at drain.)
-            with q.rstats.span("statementPollWait"):
-                q.done.wait(wait_s)
         resp = {"id": q.query_id,
                 "infoUri": f"{base_uri}/v1/query/{q.query_id}",
                 "stats": q.stats()}

@@ -59,6 +59,95 @@ def test_multi_chunk_paging(coordinator, client):
     assert r.rows == sorted(r.rows)
 
 
+def _executing_gets(client):
+    """Make `client` log the tokens of its GET .../executing requests."""
+    tokens = []
+    real = client._request
+
+    def logged(url, method="GET", data=None, _hops=0):
+        if method == "GET" and "/v1/statement/executing/" in url:
+            tokens.append(int(url.rsplit("/", 1)[1]))
+        return real(url, method, data, _hops)
+    client._request = logged
+    return tokens
+
+
+@pytest.mark.parametrize("rows,gets", [(5, 1), (10, 2), (20, 3), (25, 3)])
+def test_paging_ends_with_the_short_chunk(coordinator, monkeypatch, rows,
+                                          gets):
+    """A pull that comes back short is the last: its response is final.  A
+    result of an exact multiple of the chunk keeps its one empty pull."""
+    c = StatementClient(coordinator.uri, schema="sf0.01")
+    tokens = _executing_gets(c)
+    monkeypatch.setattr(DispatchManager, "RESULT_CHUNK_ROWS", 10)
+    r = c.execute("SELECT orderkey FROM orders ORDER BY orderkey "
+                  f"LIMIT {rows}")
+    assert len(r.rows) == rows and r.rows == sorted(r.rows)
+    assert r.stats["state"] == "FINISHED"
+    assert tokens == list(range(gets))
+
+
+def _hold_executor(dispatch, monkeypatch, seconds):
+    """Every query's executor starts `seconds` late: a client's first GET
+    .../executing/0 then beats the hand-over of the result."""
+    real = dispatch._executor
+
+    def late(q):
+        time.sleep(seconds)
+        return real(q)
+    monkeypatch.setattr(dispatch, "_executor", late)
+
+
+def _query_info(coordinator, query_id):
+    import json
+    import urllib.request
+    with urllib.request.urlopen(
+            f"{coordinator.uri}/v1/query/{query_id}") as resp:
+        return json.loads(resp.read())
+
+
+def test_first_poll_is_woken_by_the_hand_over(coordinator, monkeypatch):
+    """A solo single-node SELECT streams: its first GET arrives before the
+    executor has handed the row iterator over, is woken by the hand-over
+    and runs the query in the same request."""
+    c = StatementClient(coordinator.uri, schema="sf0.01")
+    sql = ("SELECT returnflag, count(*) c FROM lineitem "
+           "GROUP BY returnflag ORDER BY returnflag")
+    c.execute(sql)                          # plan and programs are warm
+    _hold_executor(coordinator.dispatch, monkeypatch, 0.1)
+    tokens = _executing_gets(c)
+    r = c.execute(sql)
+    assert len(r.rows) == 3 and r.stats["state"] == "FINISHED"
+    assert tokens == [0]
+    stats = _query_info(coordinator, r.query_id)["runtimeStats"]
+    # POST's answer is no poll; at most one GET .../queued before the one
+    # GET .../executing, and none of them ran out its wait
+    assert 1 <= stats["statementPolls"]["sum"] <= 2
+    assert stats["statementPollTimeouts"]["sum"] == 0
+    assert stats["statementPollWaitWallNanos"]["sum"] < 0.5e9
+
+
+def test_sub_chunk_result_is_final_in_its_first_response(coordinator,
+                                                         monkeypatch):
+    d = coordinator.dispatch
+    _hold_executor(d, monkeypatch, 0.1)
+    q = d.submit("SELECT returnflag, count(*) c FROM lineitem "
+                 "GROUP BY returnflag ORDER BY returnflag")
+    t0 = time.perf_counter()
+    resp = d.executing_response(q, 0, coordinator.uri, wait_s=60.0)
+    # the poll ends with the hand-over and the query, not with its wait
+    assert time.perf_counter() - t0 < 30.0
+    assert "nextUri" not in resp and "error" not in resp
+    assert len(resp["data"]) == 3
+    assert resp["stats"]["state"] == FINISHED and q.done.is_set()
+    assert q.runtime_stats["queryExecuteWallNanos"]["count"] == 1
+    assert q.runtime_stats["statementPollTimeouts"]["sum"] == 0
+    # the client may ask for its current token again
+    again = d.executing_response(q, 0, coordinator.uri)
+    assert again["data"] == resp["data"] and "nextUri" not in again
+    assert again["stats"]["state"] == FINISHED
+
+
 def test_error_propagates(client):
     with pytest.raises(QueryError):
         client.execute("SELECT no_such_column FROM lineitem")
@@ -94,9 +183,7 @@ def test_query_info_endpoint(coordinator, client):
     r = client.execute("SELECT 1 x")
     import json
     import urllib.request
-    with urllib.request.urlopen(
-            f"{coordinator.uri}/v1/query/{r.query_id}") as resp:
-        info = json.loads(resp.read())
+    info = _query_info(coordinator, r.query_id)
     assert info["state"] == "FINISHED"
     assert "resourceGroups" in info
     with urllib.request.urlopen(f"{coordinator.uri}/v1/query") as resp:
@@ -224,6 +311,68 @@ def test_canceled_query_reports_error():
     assert resp["error"]["errorName"] == "USER_CANCELED"
     gate.set()
     q1.done.wait(5)
+
+
+def test_failure_before_the_hand_over_wakes_the_poll():
+    def fails(q):
+        time.sleep(0.1)
+        raise ValueError("no such column")
+    d = DispatchManager(fails)
+    q = d.submit("s1")
+    t0 = time.perf_counter()
+    resp = d.executing_response(q, 0, "http://x", wait_s=60.0)
+    assert time.perf_counter() - t0 < 30.0
+    assert "no such column" in resp["error"]["message"]
+    assert resp["error"]["errorName"] == "QUERY_FAILED"
+    assert resp["stats"]["state"] == FAILED and "nextUri" not in resp
+    assert q.runtime_stats["statementPolls"]["sum"] == 1
+    assert q.runtime_stats["statementPollTimeouts"]["sum"] == 0
+
+
+def test_queued_poll_is_woken_when_the_query_starts():
+    gate1, gate2 = threading.Event(), threading.Event()
+    gates = {"s1": gate1, "s2": gate2}
+
+    def run(q):
+        gates[q.sql].wait(60)
+        return _FakeResult()
+    rgm = ResourceGroupManager(
+        [ResourceGroupSpec("g", hard_concurrency_limit=1, max_queued=5)],
+        [Selector(group="g")])
+    d = DispatchManager(run, rgm)
+    q1 = d.submit("s1")
+    q2 = d.submit("s2")
+    assert q2.state == QUEUED
+    threading.Timer(0.1, gate1.set).start()
+    t0 = time.perf_counter()
+    resp = d.queued_response(q2, 1, "http://x", wait_s=60.0)
+    try:
+        # q2 is RUNNING, not done: its start ended the poll
+        assert time.perf_counter() - t0 < 30.0
+        assert resp["stats"]["state"] == RUNNING and not q2.done.is_set()
+        assert resp["nextUri"].endswith(f"/executing/{q2.query_id}/"
+                                        f"{q2.slug}/0")
+        assert q2.runtime_stats["statementPollTimeouts"]["sum"] == 0
+    finally:
+        gate2.set()
+    assert q1.done.wait(5) and q2.done.wait(5)
+
+
+def test_poll_that_runs_out_is_counted():
+    gate = threading.Event()
+    d = DispatchManager(_slow_executor(gate))
+    q = d.submit("s1")
+    # the POST's own answer is no poll
+    d.queued_response(q, 0, "http://x", wait_s=0.0)
+    assert q.runtime_stats is None \
+        or "statementPolls" not in q.runtime_stats
+    resp = d.executing_response(q, 0, "http://x", wait_s=0.05)
+    assert resp["nextUri"].endswith("/0")       # the same token again
+    gate.set()
+    resp = d.executing_response(q, 0, "http://x", wait_s=60.0)
+    assert resp["data"] == [[1]] and "nextUri" not in resp
+    assert q.runtime_stats["statementPolls"]["sum"] == 2
+    assert q.runtime_stats["statementPollTimeouts"]["sum"] == 1
 
 
 def test_selector_routing():
