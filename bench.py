@@ -513,7 +513,8 @@ def bench_aqe(runs):
     # estimate of ~0.9x orders, so the join plans partitioned — and the
     # runtime flip to broadcast (observed rows >= 10x below plan) is the
     # adaptive path's call to make
-    thresh = int(os.environ.get("BENCH_AQE_BROADCAST_THRESHOLD", "5000"))
+    # (bytes: join-max-broadcast-table-size; 64 KiB is ~5000 narrow rows)
+    thresh = int(os.environ.get("BENCH_AQE_BROADCAST_BYTES", str(64 << 10)))
     schema = f"sf{sf:g}"
     n_rows = tpch._table_rows("lineitem", sf)
     cutoff = max(2, int(tpch._table_rows("orders", sf) * frac))
@@ -527,7 +528,7 @@ def bench_aqe(runs):
     def timed(cfg):
         runner = DistributedQueryRunner(schema, config=cfg,
                                         n_tasks=n_tasks,
-                                        broadcast_threshold=thresh)
+                                        join_max_broadcast_table_size=thresh)
         runner.execute(sql)                  # warmup: compiles
         reset_adaptive_metrics()
         best, result = float("inf"), None

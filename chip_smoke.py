@@ -16,8 +16,9 @@ results -- and checks what comes back:
            columns resident in HBM through presto_tpu/storage) Q6 and Q1 run
            cold then warm, and Q6's scalar and Q1's per-group count_order
            equal a few lines of numpy over the generated columns fetched
-           from the device -- independent of the engine (Q3 is cut above
-           sf1: see Q3_MAX_SF);
+           from the device -- independent of the engine; Q3 runs cold then
+           warm there too since PR 34 (the four-chip phase still cuts it
+           above sf1: see MESH_Q3_MAX_SF);
   device   the chip did the work: peak device bytes cover the resident
            columns, and the compile cache directory gained entries (or,
            warm, served hits).
@@ -71,18 +72,19 @@ limit 10
 """
 
 QUERIES = (("q6", Q6), ("q1", Q1), ("q3", Q3))
-# The cut (PR 24, CHANGES.md): no phase runs Q3 above sf1.  On the chip two
-# SF10 runs were killed at 1500 s inside it, cause not observed there.  On
-# the CPU at SF10 it fails: the final aggregation over the join starts at
-# agg_slots = 4096 (the optimizer has no group estimate there) and may
-# double max_agg_retries = 6 times, so past ~131k groups per task it raises
-# "aggregation collision retries exhausted" after re-streaming the stage
-# for every retry (ROADMAP queue 1 item 3).
-Q3_MAX_SF = 1.0
+# The cut (PR 24, CHANGES.md) that is left: the FOUR-CHIP phase runs no Q3
+# above sf1.  Through coordinator -> worker Q3 at SF10 takes ~3 s since
+# PR 34 (the join's distribution by bytes, a dense stream above the join,
+# an aggregation that reads its input once; before it two SF10 runs were
+# killed at 1500 s and a third at 540 s).  The mesh node's scheduler
+# (exec/scheduler.py) has its own exchange and has not run Q3 at SF10 on
+# four chips: ROADMAP queue 2b item 2.
+MESH_Q3_MAX_SF = 1.0
 
 
-def queries_at(sf: float):
-    return tuple((n, q) for n, q in QUERIES if n != "q3" or sf <= Q3_MAX_SF)
+def mesh_queries_at(sf: float):
+    return tuple((n, q) for n, q in QUERIES
+                 if n != "q3" or sf <= MESH_Q3_MAX_SF)
 
 
 # this script's lines go to the real stdout; whatever the engine itself
@@ -223,11 +225,11 @@ def independent_answers(sf: float):
 
 
 def phase_scale(cluster, sf: float):
-    """The queries cold then warm at the real size (Q3: see Q3_MAX_SF),
-    Q6 and Q1 checked against the engine-independent numpy answers."""
+    """The queries cold then warm at the real size, Q6 and Q1 checked
+    against the engine-independent numpy answers."""
     client = cluster.client(sf)
     results = {}
-    for name, sql in queries_at(sf):
+    for name, sql in QUERIES:
         cold, cold_wall = timed(client, sql)
         warm, warm_wall = timed(client, sql)
         assert warm.rows == cold.rows, f"{name}: warm rows differ from cold"
@@ -295,7 +297,7 @@ def run_one_chip(args, device, on_chip: bool):
 # ---------------------------------------------------------------------------
 
 def run_four_chips(args, devices, on_chip: bool):
-    """Q1 (and Q3: see Q3_MAX_SF) through the in-process distributed
+    """Q1 (and Q3: see MESH_Q3_MAX_SF) through the in-process distributed
     scheduler on a four-device mesh, cold and then warm, rows equal to
     one device's LocalQueryRunner; the hashed stages must resolve to
     fabric ici, the all_to_all must engage, every device must hold data
@@ -328,7 +330,7 @@ def run_four_chips(args, devices, on_chip: bool):
 
     S.InProcessScheduler._ici_exchange = counting
     try:
-        for name, sql in queries_at(args.sf)[1:]:   # Q1, and Q3 at sf <= 1
+        for name, sql in mesh_queries_at(args.sf)[1:]:  # Q1; Q3 at sf <= 1
             engaged.clear()
             FABRIC_METRICS.reset()
             t0 = time.perf_counter()

@@ -261,13 +261,14 @@ class ExchangeDecision:
 
 
 def decide_exchange(planned_rows: Optional[int], observed_rows: int,
-                    broadcast_threshold: int,
+                    max_broadcast_rows: int,
                     ratio: float = ADAPTIVE_RATIO) -> bool:
     """True when a PARTITIONED build side should flip to broadcast: the
-    observed build fits under the broadcast threshold AND the planner's
+    observed build fits the broadcast limit (join-max-broadcast-table-size
+    over the build's row width, the caller's division) AND the planner's
     estimate was off by at least `ratio` (an estimate that was simply
     absent counts as wrong — the planner had nothing to stand on)."""
-    if observed_rows > broadcast_threshold:
+    if observed_rows > max_broadcast_rows:
         return False
     if planned_rows is None:
         return True

@@ -47,9 +47,15 @@ from . import operators as ops
 from ..utils.runtime_stats import current_stats, host_get, jit_as
 
 # absolute cap on a direct-address table (entries), and the max ratio of
-# key span to build rows before falling back to the hash table
+# key span to build rows before falling back to the hash table: 4 bytes
+# of table a key of the span, so a filtered scan of a dense key (one
+# order in ten of TPC-H Q3's `orders` side) is still addressed directly
+# and not sorted by hash and searched once a probe row (40 ms a 32K-row
+# step on the v5e, PERF.md PR 32)
 DIRECT_TABLE_MAX = 1 << 26
-DIRECT_TABLE_SPAN_RATIO = 8
+DIRECT_TABLE_SPAN_RATIO = 64
+# the hidden column a cut chain carries its build row indices in
+BUILD_ROW = "$build_row"
 # largest per-join fanout the in-loop expansion handles, and the largest
 # combined expansion across a chain (chunk capacity is divided by it)
 MAX_EXPAND = 64
@@ -257,6 +263,8 @@ class FusedChain:
                     rs.add("dynamicFilterRowsIn", detail["rows_in"])
                     rs.add("dynamicFilterRowsPruned",
                            detail["dyn_rows_pruned"])
+                    rs.add("dynamicFilterRowsOut", detail["rows_in"]
+                           - detail["dyn_rows_pruned"])
         return chunks
 
     def leaf_cap(self, expands: Tuple[int, ...]) -> int:
@@ -420,13 +428,38 @@ class ChainProgram:
         return (expands, leaf_cap,
                 self.task_index if self.assigns_ids else None)
 
+    def dense_cut(self, expands: Tuple[int, ...]) -> Optional[int]:
+        """The step a sparse chain is cut at when its rows are made dense
+        (`fused_dense_stream`): its last INNER join of fanout 1 without an
+        ON filter, whose build columns are then gathered for the rows
+        that survive it and not for every scanned row; None where there
+        is no such join."""
+        cut, ji = None, 0
+        if self.assigns_ids:    # ids are made from the scan position
+            return None
+        for i, step in enumerate(self.steps):
+            if step[0] == "join":
+                node = step[1]
+                if expands[ji] == 1 and node.join_type == P.INNER \
+                        and node.filter is None:
+                    cut = i
+            if step[0] in ("join", "semi"):
+                ji += 1
+        return cut
+
     def make(self, pos, valid, aux, expands: Tuple[int, ...],
-             leaf_cap: int, with_counts: bool = False):
+             leaf_cap: int, with_counts: bool = False,
+             cut: Optional[int] = None, found=None):
         """Apply the chain to one scan chunk.  With with_counts=True the
         return value is (Batch, int64[1+len(steps)]) where counts[0] is
         the scan's live rows and counts[i+1] the live rows after step i —
         the device-side OperatorStats row counters EXPLAIN ANALYZE reads
-        (they ride the jitted program's outputs; no host syncs in-loop)."""
+        (they ride the jitted program's outputs; no host syncs in-loop).
+        With `cut` the chain stops after the lookup of the join at that
+        step (`dense_cut`): the batch carries the matched build rows'
+        indices as BUILD_ROW (-1: no match) and `finish` does the rest;
+        `found`, the BUILD_ROW values an earlier pass over the same chunk
+        came to, stands in for the lookup."""
         mk = self._leaf_make.get(leaf_cap)
         if mk is None:
             mk = self._leaf_make[leaf_cap] = self._make_factory(leaf_cap)
@@ -438,6 +471,30 @@ class ChainProgram:
         batch = Batch({n: Column(v, None, dicts.get(n))
                        for n, v in outs.items()}, live)
         counts = [jnp.sum(live)] if with_counts else None
+        steps = self.steps if cut is None else self.steps[:cut + 1]
+        return self._run(batch, aux, expands, steps, 0, counts, cut, pos,
+                         found)
+
+    def finish(self, batch: Batch, aux, expands: Tuple[int, ...],
+               cut: int) -> Batch:
+        """What `make(..., cut=cut)` left undone, for a batch of its rows
+        (any rows: they carry their build row): the cut join's build
+        columns, then the steps above it."""
+        ji = sum(1 for s in self.steps[:cut] if s[0] in ("join", "semi"))
+        node = self.steps[cut][1]
+        cols = dict(batch.columns)
+        bidx = jnp.maximum(cols.pop(BUILD_ROW).values, 0)
+        batch = self._join_columns(Batch(cols, batch.mask), node,
+                                   aux[ji + 1], bidx)
+        return self._run(batch, aux, expands, self.steps[cut + 1:],
+                         ji + 1, None, None, None)
+
+    def _run(self, batch: Batch, aux, expands, steps, ji: int, counts,
+             cut: Optional[int], pos, found=None):
+        """`steps` over `batch`; `ji` is the join/semi ordinal of the
+        first of them (aux[0] is the scan cache), `pos` the scan position
+        of the batch's first row."""
+        with_counts = counts is not None
         low = self.lowering
         params = aux[-1] if self.has_params else None
 
@@ -446,8 +503,7 @@ class ChainProgram:
             # (Batch.params is not a pytree child, so every derived Batch
             # above dropped it)
             return b.with_params(params) if self.has_params else b
-        ji = 0                      # join/semi ordinal; aux[0] = scan cache
-        for step in self.steps:
+        for si, step in enumerate(steps):
             kind = step[0]
             with jax.named_scope(kind):
                 if kind == "filter":
@@ -461,7 +517,16 @@ class ChainProgram:
                     batch = Batch({o: batch.columns[i] for o, i in step[1]},
                                   batch.mask)
                 elif kind == "join":
-                    if expands[ji] == 1:
+                    if cut is not None and si == cut:
+                        if found is None:
+                            hit, bidx = self._lookup(batch, step[1],
+                                                     aux[ji + 1])
+                            found = jnp.where(batch.mask & hit,
+                                              bidx.astype(jnp.int32), -1)
+                        batch = Batch(
+                            {**batch.columns, BUILD_ROW: Column(found)},
+                            batch.mask & (found >= 0))
+                    elif expands[ji] == 1:
                         batch = self._apply_join(batch, step[1], aux[ji + 1],
                                                  low)
                     else:
@@ -516,12 +581,17 @@ class ChainProgram:
             return batch, jnp.stack(counts).astype(jnp.int64)
         return batch
 
-    def _apply_join(self, batch: Batch, node: P.JoinNode, tbl, low) -> Batch:
+    @staticmethod
+    def _lookup(batch: Batch, node: P.JoinNode, tbl):
+        """(hit, build row index) of a fanout-1 probe."""
         probe_keys = tuple(l.name for l, _r in node.criteria)
         if isinstance(tbl, DirectTable):
-            hit, bidx = probe_direct(batch, tbl, probe_keys[0])
-        else:
-            hit, bidx = probe_unique(batch, tbl, probe_keys)
+            return probe_direct(batch, tbl, probe_keys[0])
+        return probe_unique(batch, tbl, probe_keys)
+
+    @staticmethod
+    def _join_columns(batch: Batch, node: P.JoinNode, tbl, bidx) -> Batch:
+        """`batch` with the join's build columns, gathered at `bidx`."""
         build_names = {v.name for v in node.right.output_variables}
         out_names = [v.name for v in node.outputs]
         cols = dict(batch.columns)
@@ -530,7 +600,14 @@ class ChainProgram:
                                       bidx)
         for n in gcols:
             cols[n] = gathered[id(tbl.columns[n])]
-        pairs = Batch(cols, batch.mask)
+        return Batch(cols, batch.mask)
+
+    def _apply_join(self, batch: Batch, node: P.JoinNode, tbl, low) -> Batch:
+        hit, bidx = self._lookup(batch, node, tbl)
+        build_names = {v.name for v in node.right.output_variables}
+        out_names = [v.name for v in node.outputs]
+        pairs = self._join_columns(batch, node, tbl, bidx)
+        cols = dict(pairs.columns)
         matched = hit
         if node.filter is not None:
             pred = low.eval(node.filter, pairs)
@@ -636,27 +713,30 @@ def build_lookup(compiler, build_node: P.PlanNode, keys: Tuple[str, ...],
     fanout > MAX_EXPAND.  The null flag is computed only for semi builds
     (for_join=False); join builds report False unconditionally (they drop
     NULL keys either way)."""
-    batch = compiler._materialize_node(build_node, cache=True)
-    if batch is None:
-        batch = _empty_build_batch(build_node)
-    # only semi-join markers need the null-key flag (three-valued
-    # output); join builds skip the device round-trip it costs
-    had_null = False if for_join else _build_has_null_key(batch, keys)
-    batch = _drop_null_keys(batch, keys)
-    if len(keys) == 1:
-        dt = try_direct_table(batch, keys[0], allow_dup=not for_join)
-        if dt is not None:
-            return dt, 1, had_null
-    from .pipeline import _jits
-    table = _jits()[1](batch, keys)
-    if not for_join:
-        return table, 1, had_null
-    kmax = int(host_get(_max_run(table), "build_max_run"))
-    if kmax <= 1:
-        return table, 1, False
-    if kmax > MAX_EXPAND:
-        return None
-    return table, 1 << (kmax - 1).bit_length(), False
+    from .pipeline import _jits, _span
+    # the build of a join fused into its probe's scan chain: the same
+    # work under the same span as the unfused join's (`joinBuild`)
+    with _span(compiler.ctx.runtime_stats, "joinBuild"):
+        batch = compiler._materialize_node(build_node, cache=True)
+        if batch is None:
+            batch = _empty_build_batch(build_node)
+        # only semi-join markers need the null-key flag (three-valued
+        # output); join builds skip the device round-trip it costs
+        had_null = False if for_join else _build_has_null_key(batch, keys)
+        batch = _drop_null_keys(batch, keys)
+        if len(keys) == 1:
+            dt = try_direct_table(batch, keys[0], allow_dup=not for_join)
+            if dt is not None:
+                return dt, 1, had_null
+        table = _jits()[1](batch, keys)
+        if not for_join:
+            return table, 1, had_null
+        kmax = int(host_get(_max_run(table), "build_max_run"))
+        if kmax <= 1:
+            return table, 1, False
+        if kmax > MAX_EXPAND:
+            return None
+        return table, 1 << (kmax - 1).bit_length(), False
 
 
 def assemble_chain(compiler, node: P.PlanNode) -> Optional[FusedChain]:
@@ -993,8 +1073,9 @@ DENSE_STREAM_MAX_BYTES = 256 << 20
 DENSE_STREAM_MIN_CHUNKS = 8
 
 
-def fused_dense_stream(compiler, node: P.PlanNode):
-    """A selective Filter/Project chain over a resident scan as DENSE
+def fused_dense_stream(compiler, node: P.PlanNode, chain=None, prep=None,
+                       skip_root: bool = True):
+    """A selective Filter/Project/Join chain over a resident scan as DENSE
     batches: what the chunk-by-chunk stream hands up as one nearly empty
     batch a scan chunk (a launch an operator a chunk, each paying for its
     whole-column arguments, and per-batch work in everything downstream)
@@ -1003,10 +1084,16 @@ def fused_dense_stream(compiler, node: P.PlanNode):
     them back, with the per-step totals EXPLAIN ANALYZE reads); the
     second runs it again, moves each chunk's live rows to its front
     (`ops.compact_front`) and writes the chunk where the rows of the
-    chunks before it end.  Chosen by what the counts show: where a
-    quarter of the scanned rows or more stay live the stream is dense
-    already and this returns None (as it does for a chain it cannot
-    assemble), and the caller streams as before."""
+    chunks before it end.  A chain with a fanout-1 join is cut at its
+    last one (`ChainProgram.dense_cut`): both programs stop at that
+    join's lookup, and the join's build columns and the steps above it
+    are computed for the dense batches, a launch each, not for every
+    scanned row.  Chosen by what the counts show: where a quarter of the
+    scanned rows or more stay live the stream is dense already and this
+    returns None (as it does for a chain it cannot assemble), and the
+    caller streams as before.  `chain` and `prep`: the caller's own
+    chain over `node` and its `prep()`, where it has them; `skip_root`:
+    the caller's `_instrument` wrapper counts the root's rows itself."""
     cfg = compiler.ctx.config
     analyzing = compiler.ctx.stats is not None
     if compiler.ctx.memory.limited or not cfg.fuse_pipelines \
@@ -1015,28 +1102,52 @@ def fused_dense_stream(compiler, node: P.PlanNode):
     key = ("fdense", node.id)
     if compiler._jit_cache.get(key, False) is None:     # negative-cached
         return None
-    chain = assemble_chain(compiler, node)
+    if chain is None:
+        chain = assemble_chain(compiler, node)
     if chain is None or not chain.chunks or any(
-            s[0] not in ("filter", "project", "rename") for s in chain.steps):
+            s[0] not in ("filter", "project", "rename", "join", "semi")
+            for s in chain.steps):
         compiler._jit_cache[key] = None
         return None
-    aux, expands, _deferred = chain.prep()
+    if prep is None:
+        try:
+            prep = chain.prep()
+        except NotImplementedError:
+            prep = None
+    if prep is None or any(k != 1 for k in prep[1]):
+        # a build side the chain cannot hold, or one whose keys repeat
+        compiler._jit_cache[key] = None
+        return None
+    aux, expands, _deferred = prep
+    if chain.has_params:
+        aux = aux[:-1] + (compiler.ctx.params,)
     leaf_cap = chain.leaf_cap(expands)
     chunks = chain.chunks_for(expands, meter=True)
     if len(chunks) < DENSE_STREAM_MIN_CHUNKS:
         return None
     prog = chain.program
-    signature = prog.signature(expands, leaf_cap)
+    cut = prog.dense_cut(expands)
+    if analyzing and cut is not None and any(
+            s[0] not in ("project", "rename") for s in prog.steps[cut + 1:]):
+        # operator statistics read every step's rows from the count pass,
+        # which stops at the cut: only steps that keep every row may
+        # follow it (they read the cut join's count)
+        cut = None
+    signature = prog.signature(expands, leaf_cap) + (cut,)
     pos_arr = jnp.asarray([c[0] for c in chunks], dtype=jnp.int64)
     cnt_arr = jnp.asarray([c[1] for c in chunks], dtype=jnp.int64)
 
     def count(pos_arr, cnt_arr, aux):
         def step(pc):
             b, c = prog.make(pc[0], pc[1], aux, expands, leaf_cap,
-                             with_counts=True)
-            return jnp.sum(b.mask, dtype=jnp.int32), c
-        live, counts = jax.lax.map(step, (pos_arr, cnt_arr))
-        return live, jnp.sum(counts, axis=0)
+                             with_counts=True, cut=cut)
+            # a cut chain's lookup is most of its work (a gather a scanned
+            # row): what it found is kept on the device, 4 bytes a row,
+            # and the write pass does not look again
+            found = b.columns[BUILD_ROW].values if cut is not None else ()
+            return jnp.sum(b.mask, dtype=jnp.int32), c, found
+        live, counts, found = jax.lax.map(step, (pos_arr, cnt_arr))
+        return (live, jnp.sum(counts, axis=0)), found
 
     try:
         counted = compiler.shared_jit(
@@ -1045,10 +1156,13 @@ def fused_dense_stream(compiler, node: P.PlanNode):
     except NotImplementedError:     # an expression the chain cannot lower
         compiler._jit_cache[key] = None
         return None
-    live, totals = host_get(counted, "chain_dense_counts")
+    (live, totals), found = host_get(counted[0], "chain_dense_counts"), \
+        counted[1]
     total = int(live.sum())
-    # (8 value bytes + 1 null byte a column: _instrument's estimate)
-    row_bytes = 9 * max(1, len(node.output_variables))
+    # (8 value bytes + 1 null byte a column: _instrument's estimate; a cut
+    # chain's rows have no more columns than the scan and one index)
+    row_bytes = 9 * max(1, len(node.output_variables),
+                        len(prog.dicts) + 1 if cut is not None else 0)
     # the buffer: whole batches of the chain's capacity, a power of two
     # of them (one compiled program a size class)
     n_out = -(-total // chain.cap)
@@ -1057,8 +1171,10 @@ def fused_dense_stream(compiler, node: P.PlanNode):
             or rows * row_bytes > DENSE_STREAM_MAX_BYTES:
         return None
     if analyzing:
+        totals = list(totals) + [totals[-1]] * (
+            1 + len(prog.steps) - len(totals))
         fold_chain_counts(compiler.ctx.stats, chain, totals, len(chunks),
-                          skip_root=True)
+                          skip_root=skip_root)
     rs = compiler.ctx.runtime_stats
     if rs is not None:
         rs.add("denseStreamChunks", len(chunks))
@@ -1066,13 +1182,14 @@ def fused_dense_stream(compiler, node: P.PlanNode):
     if total == 0:
         return iter(())
 
-    def write(pos_arr, cnt_arr, offsets, aux):
+    def write(pos_arr, cnt_arr, offsets, aux, found):
         def body(i, out):
             # the chunk's live rows moved to its front (no scatter: 50-80
             # ns an index on the chip), the whole chunk written where the
             # rows before it end; the next one overwrites its dead tail
-            b = ops.compact_front(
-                prog.make(pos_arr[i], cnt_arr[i], aux, expands, leaf_cap))
+            b = ops.compact_front(prog.make(
+                pos_arr[i], cnt_arr[i], aux, expands, leaf_cap, cut=cut,
+                found=found[i] if cut is not None else None))
             return jax.tree_util.tree_map(
                 lambda dst, src: jax.lax.dynamic_update_slice_in_dim(
                     dst, src, offsets[i], axis=0), out, b)
@@ -1080,17 +1197,24 @@ def fused_dense_stream(compiler, node: P.PlanNode):
         # one chunk of room behind the rows for the last chunk's tail)
         empty = jax.tree_util.tree_map(
             lambda a: jnp.zeros((rows + leaf_cap,) + a.shape[1:], a.dtype),
-            prog.make(pos_arr[0], cnt_arr[0], aux, expands, leaf_cap))
+            prog.make(pos_arr[0], cnt_arr[0], aux, expands, leaf_cap,
+                      cut=cut))
         return jax.lax.fori_loop(0, pos_arr.shape[0], body, empty)
 
     offsets = np.zeros(len(chunks), dtype=np.int32)
     np.cumsum(live[:-1], out=offsets[1:])
     dense = compiler.shared_jit(
         node, "chain_dense_write", write, extra=signature + (rows,))(
-        pos_arr, cnt_arr, jnp.asarray(offsets), aux)
+        pos_arr, cnt_arr, jnp.asarray(offsets), aux, found)
     from .pipeline import _jit_rows_at
-    return (_jit_rows_at(dense, jnp.int32(i * chain.cap), chain.cap)
-            for i in range(n_out))
+    batches = (_jit_rows_at(dense, jnp.int32(i * chain.cap), chain.cap)
+               for i in range(n_out))
+    if cut is None:
+        return batches
+    finish = compiler.shared_jit(
+        node, "chain_dense_finish",
+        lambda b, aux: prog.finish(b, aux, expands, cut), extra=signature)
+    return (finish(b, aux) for b in batches)
 
 
 def _empty_build_batch(build_node: P.PlanNode) -> Batch:

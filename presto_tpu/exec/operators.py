@@ -1414,18 +1414,48 @@ def probe_join(batch: Batch, table: BuildTable, probe_keys: List[str],
             total + jnp.sum(extra_mask), matched)
 
 
+# a gather's cost an index grows with the table it reads (on the v5e 7 ns
+# into 64K entries, 13-27 ns into 16M: PERF.md, PR 34), so a lookup whose
+# live indices all lie within this many entries of each other reads them
+# from a window sliced out of the table
+GATHER_WINDOW = 1 << 16
+
+
+def gather_near(table, idx, live):
+    """`table[idx]`, through a GATHER_WINDOW-entry window where the live
+    rows' indices (already clipped to the table) all fall inside one: a
+    batch of a fact table scanned in its key's order, as `lineitem` is in
+    `l_orderkey`'s, probes a stretch of the key space no longer than
+    itself.  Which it is, the indices decide, a batch at a time; rows
+    that are not live read garbage."""
+    size = table.shape[0]
+    if size <= 2 * GATHER_WINDOW:
+        return table[idx]
+    lo = jnp.min(jnp.where(live, idx, size - 1))
+    hi = jnp.max(jnp.where(live, idx, 0))
+    start = jnp.minimum(lo, size - GATHER_WINDOW)
+
+    def near():
+        window = jax.lax.dynamic_slice(table, (start,), (GATHER_WINDOW,))
+        return window[jnp.clip(idx - start, 0, GATHER_WINDOW - 1)]
+    return jax.lax.cond(hi - start < GATHER_WINDOW, near,
+                        lambda: table[idx])
+
+
 def direct_lookup(batch: Batch, dt, probe_key: str):
     """(hit, build_row_index) for a direct-address table lookup —
     THE single definition of the slot math shared by the fused chain
     (fused.probe_direct), the streaming direct join, and the direct semi
     marker.  Misses return index 0 (in-bounds garbage; callers mask/null
-    those rows); NULL probe keys never match."""
+    those rows); NULL probe keys never match; of a row that is not live
+    both are garbage."""
     col = batch.columns[probe_key]
     v = col.values.astype(jnp.int64)
     size = dt.slots.shape[0]
     k = v - dt.base
     inb = (k >= 0) & (k < size)
-    slot = dt.slots[jnp.clip(k, 0, size - 1).astype(jnp.int32)]
+    slot = gather_near(dt.slots, jnp.clip(k, 0, size - 1).astype(jnp.int32),
+                       batch.mask & inb)
     hit = inb & (slot >= 0)
     if col.nulls is not None:
         hit = hit & ~col.nulls
@@ -1526,6 +1556,12 @@ def semi_join_mark(batch: Batch, table: BuildTable, probe_keys: List[str],
 def sort_indices(batch: Batch, keys: List[Tuple[str, str]]):
     """Stable sort permutation honoring sort orders; padding rows last.
     keys: [(column, ASC_NULLS_FIRST|...)]."""
+    return jnp.lexsort(_sort_keys(batch, keys))
+
+
+def _sort_keys(batch: Batch, keys: List[Tuple[str, str]]) -> tuple:
+    """The arrays whose ascending lexicographic order, LAST one first, is
+    the order `keys` ask for, padding rows after everything."""
     arrays = []
     # lexsort: last key is primary -> reverse
     for name, order in reversed(keys):
@@ -1565,12 +1601,45 @@ def sort_indices(batch: Batch, keys: List[Tuple[str, str]]):
         arrays.append(key)
     # padding sorts after everything
     pad_key = (~batch.mask).astype(jnp.int8)
-    return jnp.lexsort(tuple(arrays) + (pad_key,))
+    return tuple(arrays) + (pad_key,)
+
+
+# up to this many rows a TopN picks them one after another, n passes of
+# reductions over the batch, where a whole sort of it would be compiled
+# (97 s on the v5e for 256K rows, PERF.md PR 34) and run for ten rows
+TOPN_SELECT_MAX = 64
+
+
+def _first_indices(batch: Batch, keys: List[Tuple[str, str]], n: int):
+    """The first n rows of `sort_indices`' order without sorting: n times
+    the least remaining row under the same keys, ties to the earlier row
+    as the stable sort leaves them."""
+    arrays = _sort_keys(batch, keys)[::-1]      # primary key first
+    cap = batch.capacity
+    pos = jnp.arange(cap, dtype=jnp.int32)
+
+    def pick(i, carry):
+        taken, out = carry
+        cand = ~taken
+        for a in arrays:
+            best = jnp.min(jnp.where(cand, a, jnp.asarray(
+                jnp.inf if jnp.issubdtype(a.dtype, jnp.floating)
+                else jnp.iinfo(a.dtype).max, a.dtype)))
+            cand = cand & (a == best)
+        at = jnp.min(jnp.where(cand, pos, cap - 1))
+        return taken.at[at].set(True), out.at[i].set(at)
+    _, out = jax.lax.fori_loop(
+        0, n, pick, (jnp.zeros(cap, dtype=bool),
+                     jnp.zeros(n, dtype=jnp.int32)))
+    return out
 
 
 def topn(batch: Batch, keys: List[Tuple[str, str]], n: int) -> Batch:
     """Take first n rows by sort order; result capacity = n."""
-    perm = sort_indices(batch, keys)[:n]
+    if n <= TOPN_SELECT_MAX and n <= batch.capacity:
+        perm = _first_indices(batch, keys, n)
+    else:
+        perm = sort_indices(batch, keys)[:n]
     cols = {name: c.gather(perm) for name, c in batch.columns.items()}
     return Batch(cols, batch.mask[perm])
 

@@ -47,6 +47,77 @@ from .memory import (MemoryContext, MemoryExceededError, MemoryPool,
 from ..utils.runtime_stats import host_get, jit_as, named_jit
 
 DEFAULT_CAPACITY = 1 << 20
+# a grouped aggregation of a stream holds up to this many rows (by its
+# batches' capacities) and groups them by one sort; a longer stream goes
+# through the scatter hash table (164 ms a 64K-row batch on the v5e
+# against 31 ms a 256K-row sort: PERF.md, PR 34)
+SORT_STREAM_MAX_ROWS = 1 << 22
+
+
+def _sort_bucket(rows: int) -> int:
+    """The capacity a held input is sorted at: powers of four from 4096,
+    so that a sort program (43-60 s to compile on the v5e) is shared by
+    every input of its size class."""
+    bucket = 1 << 12
+    while bucket < rows:
+        bucket *= 4
+    return bucket
+
+
+class GrowthRefused(Exception):
+    """A hash aggregation's table could not be grown within its budget."""
+
+
+def hash_aggregate(state, window, num_slots, batches, update, grow,
+                   reserve=None, rs=None):
+    """THE loop over a scatter hash table: `batches` folded into `state`
+    (a table of `num_slots` slots, to which the rows of `window` are
+    still to be added) once through, the table grown IN PLACE where rows
+    found no slot.  The collision flag comes back once a window of
+    batches (1, 2, 4 .. 64); on a collision the last checked table is
+    rehashed into one four times the size (`ops.agg_merge`: the stored
+    key hashes place the groups, no input row is read again) and the
+    window's batches, which are still held, are folded into that.
+    Neither the source nor anything below it runs a second time.
+    `update(num_slots)` and `grow(old_slots, new_slots)` give the jitted
+    steps; `reserve(num_slots)` -> bool accounts for a grown table and
+    may refuse it (GrowthRefused: the caller splits its input).
+    Returns (state, num_slots)."""
+    checked, limit = state, 1
+    for b in window:
+        state = update(num_slots)(state, b)
+
+    def collided(st):
+        return bool(host_get(st["__collision"], "agg_hash_collision"))
+
+    def settle(state, checked, window, num_slots):
+        checked_slots = num_slots
+        while collided(state):
+            num_slots *= 4
+            if reserve is not None and not reserve(num_slots):
+                raise GrowthRefused(num_slots)
+            state = grow(checked_slots, num_slots)(checked)
+            if rs is not None:
+                rs.add("aggTableGrowths", 1)
+            # (a rehash that itself collides leaves the flag set and the
+            # table grows again before the window is folded: `checked`
+            # is never a table with lost rows)
+            if not collided(state):
+                checked, checked_slots = state, num_slots
+                for wb in window:
+                    state = update(num_slots)(state, wb)
+        return state, num_slots
+
+    for b in batches:
+        state = update(num_slots)(state, b)
+        window.append(b)
+        if len(window) >= limit:
+            state, num_slots = settle(state, checked, window, num_slots)
+            checked, window = state, []
+            limit = min(64, limit * 2)
+    return settle(state, checked, window, num_slots)
+
+
 # ceiling on the materialized (keys + agg inputs) bytes for sort-based
 # grouped aggregation; beyond it the scatter hash table takes over
 SORT_AGG_MAX_BYTES = 6 << 30
@@ -66,6 +137,12 @@ _jit_rows_at = named_jit(
     lambda batch, at, n: jax.tree_util.tree_map(
         lambda a: jax.lax.dynamic_slice_in_dim(a, at, n), batch),
     static_argnums=2)
+_jit_pad_rows = named_jit(
+    "batch_pad_rows",
+    lambda batch, n: jax.tree_util.tree_map(
+        lambda a: jnp.concatenate(
+            [a, jnp.zeros((n - a.shape[0],) + a.shape[1:], a.dtype)]), batch),
+    static_argnums=1)
 # live rows of a mask, on top of a running total where one is carried on
 # the device (operator statistics: summed a batch, fetched once a stream)
 _jit_count_live = named_jit(
@@ -273,9 +350,11 @@ def _jits():
 @dataclass
 class ExecutionConfig:
     batch_rows: int = DEFAULT_CAPACITY      # scan page/batch capacity
-    agg_slots: int = 4096                   # initial group table size
+    # the scatter hash table's FIRST size where a stream is aggregated
+    # through one: it grows in place (hash_aggregate), so no answer
+    # depends on it
+    agg_slots: int = 4096
     join_out_capacity: int = 1 << 21        # probe output capacity
-    max_agg_retries: int = 6
     splits_per_scan: int = 4
     # HBM accounting / spill (reference MemoryPool + spiller, exec/memory.py)
     memory_budget_bytes: Optional[int] = None   # None = unlimited
@@ -1597,12 +1676,20 @@ class PlanCompiler:
         first = self.shared_jit(node, "topn_first",
                                 lambda batch: ops.topn(batch, keys, n))
 
+        rs = self.ctx.runtime_stats
+
         def gen():
             key_names = [k for k, _o in keys]
-            buf = None
-            for b in src.batches():
-                b = _encode_unordered_lazy_keys(b, key_names)
-                buf = first(b) if buf is None else step(buf, b)
+            buf, rows = None, jnp.zeros((), dtype=jnp.int64)
+            with _span(rs, "topN"):
+                for b in src.batches():
+                    if rs is not None:
+                        rows = _jit_count_live(b.mask, rows)
+                    b = _encode_unordered_lazy_keys(b, key_names)
+                    buf = first(b) if buf is None else step(buf, b)
+            if rs is not None:
+                # (rows in, summed on the device: fetched once a stream)
+                rs.add("topNRowsIn", int(host_get(rows, "topn_rows_in")))
             if buf is not None:
                 yield buf
         return BatchSource(gen, src.names, src.types)
@@ -1862,8 +1949,8 @@ class PlanCompiler:
                 update_cache[("direct", G, strides)] = fn
             return fn
 
-        def make_update(num_slots: int, salt: int):
-            fn = update_cache.get((num_slots, salt))
+        def make_update(num_slots: int):
+            fn = update_cache.get(num_slots)
             if fn is None:
                 def fn(state, batch):
                     key_cols = [batch.columns[k] for k in key_names]
@@ -1874,92 +1961,11 @@ class PlanCompiler:
                     agg_cols2 = {out: low.eval(expr, batch)
                                  for out, expr in input_exprs2.items()}
                     return ops.agg_update(state, batch, key_cols, agg_cols,
-                                          specs, num_slots, salt, key_names,
+                                          specs, num_slots, 0, key_names,
                                           agg_cols2)
-                fn = self.shared_jit(node, "agg_upd", fn,
-                                     extra=(num_slots, salt))
-                update_cache[(num_slots, salt)] = fn
+                fn = update_cache[num_slots] = self.shared_jit(
+                    node, "agg_upd", fn, extra=(num_slots,))
             return fn
-
-        def run_once(num_slots: int, salt: int, batches_fn=None,
-                     allow_direct: bool = True):
-            batches = (self._compile(src_node).batches()
-                       if batches_fn is None else batches_fn())
-            state = None
-            key_dicts: Dict[str, Tuple[str, ...]] = {}
-            key_lazy: Dict[str, Tuple] = {}
-            encode_keys: List[str] = []
-            update = make_update(num_slots, salt)
-
-            direct = None        # (doms, dtypes) when small-domain mode
-            hll_outs = {s.output for s in specs if s.name in ops.HLL_AGGS}
-            for batch in batches:
-                if state is None:
-                    for k in key_names:
-                        col = batch.columns[k]
-                        if col.lazy is not None:
-                            _, tbl, coln, _sf = col.lazy
-                            if (tbl, coln) in catalog.ROWID_DISTINCT:
-                                # row id IS the group identity; keep lazy tag
-                                key_lazy[k] = col.lazy
-                            else:
-                                # small-pool column (orders.clerk): grouping
-                                # by row id would split groups — encode to a
-                                # real whole-column dictionary on the host
-                                encode_keys.append(k)
-                    # HLL sketches hash the device values: a lazy column's
-                    # row ids are only distinct-faithful when the row id is
-                    # unique per VALUE; otherwise encode to dictionary codes
-                    for out in hll_outs:
-                        expr = input_exprs[out]
-                        if isinstance(expr, VariableReferenceExpression):
-                            col = batch.columns.get(expr.name)
-                            if col is not None and col.lazy is not None:
-                                _, tbl, coln, _sf = col.lazy
-                                if (tbl, coln) not in catalog.ROWID_DISTINCT \
-                                        and expr.name not in encode_keys:
-                                    encode_keys.append(expr.name)
-                    if encode_keys:
-                        batch = _encode_lazy_keys(batch, encode_keys)
-                    key_cols = [batch.columns[k] for k in key_names]
-                    key_dtypes = [c.values.dtype for c in key_cols]
-                    for k, c in zip(key_names, key_cols):
-                        if c.dictionary is not None:
-                            key_dicts[k] = c.dictionary
-                    # closed small domains: combined code IS the slot index
-                    info = (_direct_mode_info(key_names, key_cols)
-                            if basic_specs and allow_direct else None)
-                    if info is not None:
-                        doms, G, strides, kdts, _kd = info
-                        direct = (doms, kdts)
-                        update = make_direct_update(G, strides)
-                        state = ops.agg_direct_init(G, specs)
-                    else:
-                        state = ops.agg_init(num_slots, specs, key_names,
-                                             key_dtypes)
-                elif encode_keys:
-                    batch = _encode_lazy_keys(batch, encode_keys)
-                if direct is not None and any(
-                        batch.columns[k].nulls is not None
-                        for k in key_names):
-                    # direct mode was chosen on a null-free first batch,
-                    # but this batch carries a NULL key (nullable storage
-                    # connectors): the code grid has no null slot, so
-                    # RESTART the whole aggregation on the hash path.
-                    # Close the abandoned iterator FIRST — source
-                    # generators release pool reservations in finally
-                    # blocks.  The restart replays through the _share tee
-                    # buffer like a collision retry does (same stats
-                    # double-count caveat under EXPLAIN ANALYZE).
-                    if hasattr(batches, "close"):
-                        batches.close()
-                    return run_once(num_slots, salt, batches_fn,
-                                    allow_direct=False)
-                state = update(state, batch)
-            if state is None:
-                key_dtypes = [jnp.int64] * len(key_names)
-                state = ops.agg_init(num_slots, specs, key_names, key_dtypes)
-            return state, key_dicts, key_lazy, direct
 
         fused_cache: dict = {}
 
@@ -2031,8 +2037,8 @@ class PlanCompiler:
             import time
             t0 = time.perf_counter()  # lint: allow-wall-clock
             out = _run_fused_inner(chain, counts_out)
-            if out is None:
-                return None
+            if not isinstance(out, Batch):
+                return out      # nothing, or the chain's dense stream
             out = jax.block_until_ready(out)
             wall = time.perf_counter() - t0  # lint: allow-wall-clock
             counts = counts_out.get("counts")
@@ -2054,11 +2060,15 @@ class PlanCompiler:
             return out
 
         def _run_fused_inner(chain, counts_out):
-            """Execute a fused chain to a finalized output Batch, or None
-            to fall back to the streaming executor.  Four modes by group-key
-            shape: one-hot grid (G<=64, MXU-friendly), static span (closed
-            dictionary domains), runtime span (single integer key — probe
-            min/max, then collision-free scatter-direct), hash table."""
+            """Execute a fused chain to a finalized output Batch, or hand
+            back its rows as a dense stream (an iterator of batches) for
+            `aggregate_stream`, or None to fall back to the streaming
+            executor.  By group-key shape: one-hot grid (G<=64,
+            MXU-friendly), static span (closed dictionary domains); then,
+            where a join leaves under a quarter of the scanned rows, the
+            dense stream; else runtime span (single integer key — probe
+            min/max, then collision-free scatter-direct) or the sort of
+            the stacked chain output."""
             analyzing = self.ctx.stats is not None
             pool = self.ctx.memory
             if pool.limited:
@@ -2244,6 +2254,21 @@ class PlanCompiler:
                         key_lazy))
                 finally:
                     pool.free(G * 24 * max(1, len(specs)))
+
+            # open key domains over a chain that a join leaves sparse: a
+            # scatter or a gather an accumulator costs an index whether
+            # its row is live or not (PERF.md, PR 34), so the few live
+            # rows are made dense first -- by the chain's own count pass,
+            # which also says whether it is worth it -- and aggregated as
+            # a stream.  A chain without a join keeps the strategies
+            # below: its scan's key order is what they use.
+            if key_names and any(s[0] in ("join", "semi")
+                                 for s in chain.steps):
+                from .fused import fused_dense_stream
+                dense = fused_dense_stream(self, src_node, chain=chain,
+                                           prep=prep_res, skip_root=False)
+                if dense is not None:
+                    return dense
 
             # runtime span: one integer ANCHOR key indexes the
             # accumulators directly (collision-free scatter-direct); any
@@ -2438,54 +2463,207 @@ class PlanCompiler:
                 # spilled-bucket paths in gen() take over
                 return None
 
-            # scatter hash table fallback, sized from the scan row count
-            # so the common case completes without a doubling recompile
-            # initial size from the pre-filter scan rows, capped so a
-            # selective query doesn't over-allocate; collision retries
-            # double from there when the group count really is huge
-            num_slots = max(cfg.agg_slots,
-                            1 << (min(2 * total, 1 << 22) - 1).bit_length())
-            salt = 0
-            for _attempt in range(cfg.max_agg_retries):
-                est = _agg_state_bytes(num_slots, key_names, specs)
-                if not pool.try_reserve(est):
-                    return None
-                try:
-                    def update(st, b, _n=num_slots, _s=salt):
-                        kc = [b.columns[k] for k in key_names]
-                        return ops.agg_update(st, b, kc, _agg_exprs(b),
-                                              specs, _n, _s, key_names,
-                                              _agg_exprs2(b))
-                    state = loop("hash", update,
-                                 ops.agg_init(num_slots, specs, key_names,
-                                              key_dtypes), num_slots, salt)
-                    if not bool(host_get(state["__collision"],
-                                         "agg_hash_collision")):
-                        if not key_names and not bool(host_get(
-                                jnp.any(state["__occupied"]),
-                                "agg_occupied")):
-                            state["__occupied"] = \
-                                state["__occupied"].at[0].set(True)
-                        return _maybe_compact(ops.agg_finalize(
-                            state, specs, key_names, key_dicts, key_lazy))
-                finally:
-                    pool.free(est)
-                num_slots *= 2
-                salt += 1
-            raise RuntimeError("fused aggregation collision retries "
-                               "exhausted")
+            # no fused strategy holds these keys: the chain streams, and
+            # the stream is aggregated once through (aggregate_stream)
+            return None
 
-        def run_retrying(batches_fn=None, start_slots=None):
-            num_slots, salt = start_slots or initial_slots, 0
-            for attempt in range(cfg.max_agg_retries):
-                state, key_dicts, key_lazy, direct = run_once(
-                    num_slots, salt, batches_fn)
-                if direct is not None \
-                        or not bool(state["__collision"]):
-                    return state, key_dicts, key_lazy, direct
-                num_slots *= 2
-                salt += 1
-            raise RuntimeError("aggregation collision retries exhausted")
+        rs = self.ctx.runtime_stats
+
+        def note(name, value):
+            if rs is not None:
+                rs.add(name, value)
+
+        def note_table(out, num_slots):
+            """The groups of a finalized table (one fetch) and its slots
+            (0: grouped by a sort)."""
+            if rs is not None:
+                rs.add("aggGroups", int(host_get(
+                    _jit_count_live(out.mask), "agg_groups")))
+                rs.add("aggTableSlots", num_slots)
+
+        def make_grow(old_slots: int, new_slots: int):
+            key = ("grow", old_slots, new_slots)
+            fn = update_cache.get(key)
+            if fn is None:
+                def fn(state):
+                    key_dtypes = [state[f"__key_{k}"].dtype
+                                  for k in key_names]
+                    return ops.agg_merge(
+                        ops.agg_init(new_slots, specs, key_names,
+                                     key_dtypes),
+                        state, specs, key_names, new_slots)
+                fn = update_cache[key] = self.shared_jit(
+                    node, "agg_grow", fn, extra=(old_slots, new_slots))
+            return fn
+
+        def aggregate_stream(batches, start_slots, reserve=None,
+                             allow_direct=True, restream=None):
+            """One grouped aggregation of a stream of batches, the stream
+            read ONCE: the rule for every aggregation that is not fused
+            with its scan (above a join, above an exchange, over a spill
+            bucket).  Closed small key domains take the code grid; else
+            an input of up to SORT_STREAM_MAX_ROWS rows (by the batches'
+            capacities: no sync) is held and grouped by one sort, which
+            has no table to size; a longer one goes through the scatter
+            hash table, sized from the rows already held and grown in
+            place (`hash_aggregate`).  Yields the finalized batches.
+            `restream`: the one case that reads its input again, a NULL
+            key that turns up after the code grid was chosen on a batch
+            without one (counted as `aggRestreams`)."""
+            note("aggRestreams", 0)
+            with _span(rs, "aggUpdate"):
+                it = iter(batches)
+                first = next(it, None)
+            key_dicts: Dict[str, Tuple[str, ...]] = {}
+            key_lazy: Dict[str, Tuple] = {}
+            encode_keys: List[str] = []
+            hll_outs = {s.output for s in specs if s.name in ops.HLL_AGGS}
+            if first is None:
+                state = ops.agg_init(start_slots, specs, key_names,
+                                     [jnp.int64] * len(key_names))
+                yield finalize_hash(state, key_dicts, key_lazy,
+                                    start_slots)
+                return
+            for k in key_names:
+                col = first.columns[k]
+                if col.lazy is not None:
+                    _, tbl, coln, _sf = col.lazy
+                    if (tbl, coln) in catalog.ROWID_DISTINCT:
+                        # row id IS the group identity; keep lazy tag
+                        key_lazy[k] = col.lazy
+                    else:
+                        # small-pool column (orders.clerk): grouping
+                        # by row id would split groups — encode to a
+                        # real whole-column dictionary on the host
+                        encode_keys.append(k)
+            # HLL sketches hash the device values: a lazy column's
+            # row ids are only distinct-faithful when the row id is
+            # unique per VALUE; otherwise encode to dictionary codes
+            for out in hll_outs:
+                expr = input_exprs[out]
+                if isinstance(expr, VariableReferenceExpression):
+                    col = first.columns.get(expr.name)
+                    if col is not None and col.lazy is not None:
+                        _, tbl, coln, _sf = col.lazy
+                        if (tbl, coln) not in catalog.ROWID_DISTINCT \
+                                and expr.name not in encode_keys:
+                            encode_keys.append(expr.name)
+
+            if encode_keys:
+                first = _encode_lazy_keys(first, encode_keys)
+                it = (_encode_lazy_keys(b, encode_keys) for b in it)
+            key_cols = [first.columns[k] for k in key_names]
+            key_dtypes = [c.values.dtype for c in key_cols]
+            for k, c in zip(key_names, key_cols):
+                if c.dictionary is not None:
+                    key_dicts[k] = c.dictionary
+            # closed small domains: combined code IS the slot index
+            info = (_direct_mode_info(key_names, key_cols)
+                    if basic_specs and allow_direct else None)
+            if info is not None:
+                doms, G, strides, kdts, _kd = info
+                update = make_direct_update(G, strides)
+                with _span(rs, "aggUpdate"):
+                    state = ops.agg_direct_init(G, specs)
+                    batch = first
+                    while batch is not None:
+                        if any(batch.columns[k].nulls is not None
+                               for k in key_names):
+                            # the code grid has no NULL slot and was
+                            # chosen on a null-free first batch (nullable
+                            # storage connectors): the input is read
+                            # again for the hash path.  Close the
+                            # abandoned iterator FIRST — source
+                            # generators release pool reservations in
+                            # finally blocks.
+                            if hasattr(batches, "close"):
+                                batches.close()
+                            if restream is None:
+                                raise RuntimeError(
+                                    "a NULL group key after the code "
+                                    "grid was chosen, and an input that "
+                                    "cannot be read again")
+                            note("aggRestreams", 1)
+                            state = None
+                            break
+                        state = update(state, batch)
+                        batch = next(it, None)
+                if state is None:
+                    yield from aggregate_stream(
+                        restream(), start_slots, reserve,
+                        allow_direct=False)
+                    return
+                with _span(rs, "aggFinalize"):
+                    out = ops.agg_direct_finalize(
+                        state, specs, key_names, doms, kdts, key_dicts,
+                        force_row=not key_names)
+                note("aggTableSlots", G)
+                yield out
+                return
+            held, rows = [first], first.capacity
+            sortable = bool(key_names) and not hll_outs \
+                and reserve is None and rows <= SORT_STREAM_MAX_ROWS
+            with _span(rs, "aggUpdate"):
+                if sortable:
+                    for b in it:
+                        held.append(b)
+                        rows += b.capacity
+                        if rows > SORT_STREAM_MAX_ROWS:
+                            sortable = False
+                            break
+                if sortable:
+                    merged = _compact_concat(held)
+                    bucket = _sort_bucket(merged.capacity)
+                    if bucket > merged.capacity:
+                        merged = _jit_pad_rows(merged, bucket)
+                    out = sort_aggregate()(merged)
+            if sortable:
+                with _span(rs, "aggFinalize"):
+                    out = _maybe_compact(out)
+                note_table(out, 0)
+                yield out
+                return
+            # a table for the rows held so far, at half load or less
+            num_slots = max(start_slots,
+                            1 << max(0, 2 * rows - 1).bit_length()
+                            if len(held) > 1 else 0)
+            if reserve is not None and num_slots > start_slots \
+                    and not reserve(num_slots):
+                num_slots = start_slots
+            with _span(rs, "aggUpdate"):
+                state, num_slots = hash_aggregate(
+                    ops.agg_init(num_slots, specs, key_names, key_dtypes),
+                    held, num_slots, it,
+                    make_update, make_grow, reserve, rs)
+            yield finalize_hash(state, key_dicts, key_lazy, num_slots)
+
+        def finalize_hash(state, key_dicts, key_lazy, num_slots):
+            with _span(rs, "aggFinalize"):
+                if not key_names and not bool(host_get(
+                        jnp.any(state["__occupied"]), "agg_occupied")):
+                    # global aggregation over empty input: one row
+                    state["__occupied"] = \
+                        state["__occupied"].at[0].set(True)
+                out = ops.agg_finalize(state, specs, key_names, key_dicts,
+                                       key_lazy)
+            note_table(out, num_slots)
+            return out
+
+        def sort_aggregate():
+            low2 = self.lowering
+            fn = update_cache.get("sort")
+            if fn is None:
+                def fn(b):
+                    inputs = {out: (low2.eval(e, b) if e is not None
+                                    else None)
+                              for out, e in input_exprs.items()}
+                    inputs2 = {out: low2.eval(e, b)
+                               for out, e in input_exprs2.items()}
+                    return ops.sort_group_aggregate(b, key_names, inputs,
+                                                    specs, inputs2)
+                fn = update_cache["sort"] = self.shared_jit(
+                    node, "agg_sort", fn)
+            return fn
 
         # size the scatter table from the optimizer's group-count estimate
         # so the common case never pays a collision retry (each retry
@@ -2520,25 +2698,9 @@ class PlanCompiler:
         # + per-key value/null + per-aggregate state columns)
         est_state_bytes = _agg_state_bytes(initial_slots, key_names, specs)
 
-        def _sortagg_fn():
-            low2 = self.lowering
-            key = ("sortagg_fallback", node.id)
-            fn = self._jit_cache.get(key)
-            if fn is None:
-                @jit_as("agg_sort_fallback")
-                def fn(b):
-                    inputs = {out: (low2.eval(e, b) if e is not None
-                                    else None)
-                              for out, e in input_exprs.items()}
-                    inputs2 = {out: low2.eval(e, b)
-                               for out, e in input_exprs2.items()}
-                    return ops.sort_group_aggregate(b, key_names, inputs,
-                                                    specs, inputs2)
-                self._jit_cache[key] = fn
-            return fn
-
-        def drain_sort_input():
-            """Drain the source once under per-batch reservation.
+        def drain_sort_input(source=None):
+            """Drain the source (`source`, where the caller already holds
+            its stream) once under per-batch reservation.
             Returns (merged, None) when the whole input fit the budget;
             else (None, stream) where the stream replays the collected
             (still-reserved) batches and then continues the SAME source
@@ -2546,7 +2708,8 @@ class PlanCompiler:
             and device bytes stay accounted until consumed."""
             pool = self.ctx.memory
             collected, reserved = [], 0
-            it = self._compile(src_node).batches()
+            it = source if source is not None \
+                else self._compile(src_node).batches()
             over_batch = None
             for b in it:
                 nb = batch_bytes(b)
@@ -2704,7 +2867,7 @@ class PlanCompiler:
             the grouped-execution Lifespan model, same store the hash
             path spills through."""
             store = fill_spill_store(batches)
-            fn = _sortagg_fn()
+            fn = sort_aggregate()
             pool = self.ctx.memory
             work = [(store, p, 0) for p in range(cfg.spill_partitions)]
             while work:
@@ -2765,11 +2928,13 @@ class PlanCompiler:
                 # everything; the other shards contribute nothing, so no
                 # group is double-counted
                 return
+            source = None       # the stream to aggregate, where not src_node's
             if fused is not None:
                 out = run_fused(fused)
-                if out is not None:
+                if isinstance(out, Batch):
                     yield out
                     return
+                source = out        # a chain's dense stream, or None
             if sort_only_specs:
                 if any(s.name in ops.HLL_AGGS for s in specs):
                     # percentile needs value-ordered segments (sort path),
@@ -2779,9 +2944,9 @@ class PlanCompiler:
                         "approx_percentile and approx_distinct in the "
                         "same aggregation are not supported; split the "
                         "query into two aggregations")
-                merged, stream = drain_sort_input()
+                merged, stream = drain_sort_input(source)
                 if stream is None:
-                    yield _maybe_compact(_sortagg_fn()(merged))
+                    yield _maybe_compact(sort_aggregate()(merged))
                     return
                 if not cfg.spill_enabled:
                     raise MemoryExceededError(
@@ -2792,6 +2957,9 @@ class PlanCompiler:
                 else:
                     yield run_global_percentile_stream(stream)
                 return
+
+            def source_batches():
+                return self._compile(src_node).batches()
             # grouped aggregation state is registered as a revocable
             # holder so arbitration/admission see it, but its callback
             # DECLINES (returns 0): a device hash table mid-scatter cannot
@@ -2806,19 +2974,21 @@ class PlanCompiler:
                 agg_holder.close()
             if got:
                 try:
-                    state, key_dicts, key_lazy, direct = run_retrying()
-                    if direct is not None:
-                        yield ops.agg_direct_finalize(
-                            state, specs, key_names, direct[0], direct[1],
-                            key_dicts, force_row=not key_names)
-                        return
-                    if not key_names and not bool(host_get(
-                            jnp.any(state["__occupied"]), "agg_occupied")):
-                        # global aggregation over empty input: one row
-                        state["__occupied"] = \
-                            state["__occupied"].at[0].set(True)
-                    yield ops.agg_finalize(state, specs, key_names,
-                                           key_dicts, key_lazy)
+                    # (a budgeted pool sees every growth of the table; an
+                    # unlimited one has nothing to refuse it with)
+                    reserve = None if agg_holder is None \
+                        or not pool.limited else (
+                            lambda n: agg_holder.try_reserve(
+                                _agg_state_bytes(n, key_names, specs)))
+                    try:
+                        yield from aggregate_stream(
+                            source if source is not None
+                            else source_batches(), initial_slots, reserve,
+                            restream=source_batches)
+                    except GrowthRefused as e:
+                        raise MemoryExceededError(
+                            f"aggregation table of {e.args[0]} slots "
+                            f"exceeds memory budget {pool.budget} bytes")
                 finally:
                     if agg_holder is not None:
                         agg_holder.close()
@@ -2830,13 +3000,13 @@ class PlanCompiler:
             # budget too small for one table: hash-partition the input by
             # group keys into host-staged buckets and aggregate per bucket
             # (buckets hold disjoint key sets, so each finalize is exact)
-            store = fill_spill_store()
+            store = fill_spill_store(source)
             # each bucket sees ~1/K of the keys: start with a
             # proportionally smaller table, and account for it.  A bucket
             # never holds more distinct keys than rows, so cap by the
             # bucket's actual row count; if even that over-runs the pool,
-            # halve the table until the reservation fits (more retry
-            # passes instead of failure, mirroring the reference's
+            # halve the table until the reservation fits (a table that
+            # grows in place instead of failure, mirroring the reference's
             # spill-don't-throw behavior, HashBuilderOperator.java:56).
             # Only when even the 256-slot minimum exceeds the remaining
             # budget does reserve() raise — no smaller table exists.
@@ -2851,16 +3021,16 @@ class PlanCompiler:
                 bucket_slots = max(
                     256, min(initial_slots // cfg.spill_partitions,
                              1 << (2 * rows_p - 1).bit_length()))
-                held = 0
+                held = [0]
                 while True:
                     bucket_bytes = bucket_slots * per_slot
                     if pool.try_reserve(bucket_bytes):
-                        held = bucket_bytes
+                        held[0] = bucket_bytes
                         break
                     if bucket_slots <= 256:
                         break
                     bucket_slots = max(256, bucket_slots // 2)
-                if not held:
+                if not held[0]:
                     if depth < 4:
                         subdivide_bucket(bstore, p, depth, work)
                         continue
@@ -2868,50 +3038,40 @@ class PlanCompiler:
                     # budget after 4 re-partitions: raise the engine's
                     # exceeded-limit error
                     pool.reserve(bucket_bytes)
-                # collision retries double the table — each growth is
-                # re-reserved so device bytes never silently exceed the
-                # budget; when the needed table cannot fit, sub-partition
-                # instead of over-reserving
-                num_slots, salt = bucket_slots, 0
-                done = False
+
+                def regrow(n, held=held):
+                    # each growth of the table is re-reserved so device
+                    # bytes never silently exceed the budget
+                    pool.free(held[0])
+                    held[0] = 0
+                    if not pool.try_reserve(n * per_slot):
+                        return False
+                    held[0] = n * per_slot
+                    return True
+
+                def bucket_batches(b=bstore, p=p):
+                    return b.bucket_batches(p, cfg.batch_rows)
                 try:
-                    for _attempt in range(cfg.max_agg_retries):
-                        state, key_dicts, key_lazy, direct = run_once(
-                            num_slots, salt,
-                            lambda b=bstore, p=p: b.bucket_batches(
-                                p, cfg.batch_rows))
-                        if direct is not None:
-                            yield ops.agg_direct_finalize(
-                                state, specs, key_names, direct[0],
-                                direct[1], key_dicts)
-                            done = True
-                            break
-                        if not bool(state["__collision"]):
-                            yield ops.agg_finalize(state, specs,
-                                                   key_names, key_dicts,
-                                                   key_lazy)
-                            done = True
-                            break
-                        grown = 2 * num_slots * per_slot
-                        pool.free(held)
-                        held = 0
-                        if not pool.try_reserve(grown):
-                            if depth < 4:
-                                subdivide_bucket(bstore, p, depth, work)
-                                done = True   # handled via sub-buckets
-                                break
-                            raise MemoryExceededError(
-                                f"aggregation table of {grown} bytes "
-                                f"exceeds memory budget {pool.budget} "
-                                f"after {depth} re-partitions")
-                        held = grown
-                        num_slots *= 2
-                        salt += 1
-                    if not done:
-                        raise RuntimeError(
-                            "aggregation collision retries exhausted")
+                    # (list: a refused growth must not leave half a
+                    # bucket's groups handed up before its sub-buckets'
+                    # are)
+                    done = list(aggregate_stream(
+                        bucket_batches(), bucket_slots, regrow,
+                        restream=bucket_batches))
+                except GrowthRefused as e:
+                    # the needed table cannot fit: sub-partition instead
+                    # of over-reserving
+                    if depth >= 4:
+                        raise MemoryExceededError(
+                            f"aggregation table of "
+                            f"{e.args[0] * per_slot} bytes "
+                            f"exceeds memory budget {pool.budget} "
+                            f"after {depth} re-partitions")
+                    subdivide_bucket(bstore, p, depth, work)
+                    done = []
                 finally:
-                    pool.free(held)
+                    pool.free(held[0])
+                yield from done
         return BatchSource(gen, out_names, out_types)
 
     def _skewed_percentile_bucket(self, bstore, p, key_names, specs,
@@ -3009,48 +3169,53 @@ class PlanCompiler:
                     if key_batch0.columns[k].lazy is not None}
         out_batch = None
         if other_specs:
-            num_slots, salt = 256, 0
-            for _attempt in range(cfg.max_agg_retries):
-                est = _agg_state_bytes(num_slots, key_names, other_specs)
+            names = tuple(key_names)
+
+            def update(num_slots):
+                jk = ("skewagg", names, other_specs, num_slots)
+                upd = self._jit_cache.get(jk)
+                if upd is None:
+                    @jit_as("agg_skew_update")
+                    def upd(state, b):
+                        kc = [b.columns[k] for k in key_names]
+                        ac = {s.output: (low.eval(
+                            input_exprs[s.output], b)
+                            if input_exprs[s.output] is not None
+                            else None) for s in other_specs}
+                        ac2 = {s.output: low.eval(
+                            input_exprs2[s.output], b)
+                            for s in other_specs
+                            if s.name in ops.CORR_AGGS}
+                        return ops.agg_update(
+                            state, b, kc, ac, other_specs,
+                            num_slots, 0, names, ac2)
+                    self._jit_cache[jk] = upd
+                return upd
+
+            def grow(_old_slots, new_slots):
+                return jit_as("agg_skew_grow")(lambda state: ops.agg_merge(
+                    ops.agg_init(new_slots, other_specs, names, key_dtypes),
+                    state, other_specs, names, new_slots))
+
+            held = [_agg_state_bytes(256, key_names, other_specs)]
+            pool.reserve(held[0])
+
+            def regrow(n):
+                # (raises where the budget cannot hold the grown table)
+                est = _agg_state_bytes(n, key_names, other_specs)
                 pool.reserve(est)
-                try:
-                    jk = ("skewagg", tuple(key_names), other_specs,
-                          num_slots, salt)
-                    upd = self._jit_cache.get(jk)
-                    if upd is None:
-                        @jit_as("agg_skew_update")
-                        def upd(state, b):
-                            kc = [b.columns[k] for k in key_names]
-                            ac = {s.output: (low.eval(
-                                input_exprs[s.output], b)
-                                if input_exprs[s.output] is not None
-                                else None) for s in other_specs}
-                            ac2 = {s.output: low.eval(
-                                input_exprs2[s.output], b)
-                                for s in other_specs
-                                if s.name in ops.CORR_AGGS}
-                            return ops.agg_update(
-                                state, b, kc, ac, other_specs,
-                                num_slots, salt, tuple(key_names), ac2)
-                        self._jit_cache[jk] = upd
-                    state = ops.agg_init(num_slots, other_specs,
-                                         tuple(key_names), key_dtypes)
-                    for b in bstore.bucket_batches(p, cfg.batch_rows):
-                        state = upd(state, b)
-                    if not bool(host_get(state["__collision"],
-                                         "agg_skew_collision")):
-                        out_batch = ops.agg_finalize(
-                            state, other_specs, tuple(key_names),
-                            key_dicts, key_lazy)
-                        break
-                finally:
-                    pool.free(est)
-                num_slots *= 2
-                salt += 1
-            if out_batch is None:
-                raise RuntimeError(
-                    "skewed-bucket aggregation collision retries "
-                    "exhausted")
+                pool.free(held[0])
+                held[0] = est
+                return True
+            try:
+                state, _slots = hash_aggregate(
+                    ops.agg_init(256, other_specs, names, key_dtypes), [],
+                    256, bstore.bucket_batches(p, cfg.batch_rows), update,
+                    grow, regrow)
+                out_batch = ops.agg_finalize(
+                    state, other_specs, names, key_dicts, key_lazy)
+            finally:
+                pool.free(held[0])
             # attach percentile columns by key lookup on the host
             kcols = [np.asarray(out_batch.columns[k].values)
                      for k in key_names]
@@ -3330,8 +3495,11 @@ class PlanCompiler:
 
         def gen():
             pool = self.ctx.memory
-            from .fused import fused_stream
-            fs = fused_stream(self, node)
+            from .fused import fused_dense_stream, fused_stream
+            # a join fused into its probe's scan chain: as dense batches
+            # where the chain's own counts say it leaves few rows, else
+            # chunk by chunk
+            fs = fused_dense_stream(self, node) or fused_stream(self, node)
             if fs is not None:
                 for b in fs:
                     yield b.select(out_names)

@@ -726,13 +726,14 @@ class DistributedQueryRunner(LocalQueryRunner):
 
     def __init__(self, schema: str = "sf0.01",
                  config: Optional[ExecutionConfig] = None,
-                 n_tasks: int = 2, broadcast_threshold: int = 600_000,
+                 n_tasks: int = 2,
+                 join_max_broadcast_table_size: int = 100 << 20,
                  catalog: str = "tpch", mesh=None, tracer_provider=None,
                  history=None):
         super().__init__(schema, config, catalog,
                          tracer_provider=tracer_provider, history=history)
         self.n_tasks = n_tasks
-        self.broadcast_threshold = broadcast_threshold
+        self.join_max_broadcast_table_size = join_max_broadcast_table_size
         # jax.sharding.Mesh: hashed exchanges between stages whose task
         # count equals the mesh size run as ICI all_to_all collectives
         self.mesh = mesh
@@ -776,7 +777,8 @@ class DistributedQueryRunner(LocalQueryRunner):
     def _fragmenter_config(self):
         from ..sql.fragmenter import FragmenterConfig
         return FragmenterConfig(
-            broadcast_threshold=self.broadcast_threshold)
+            join_max_broadcast_table_size=self.join_max_broadcast_table_size,
+            n_tasks=self.n_tasks)
 
     def _explain_distributed(self, ast, sql: str = "") -> QueryResult:
         """EXPLAIN over the fragmented (distributed) plan — the analog of
@@ -948,7 +950,7 @@ class DistributedQueryRunner(LocalQueryRunner):
             exec_config=self.config, source_tasks=self.n_tasks,
             hash_tasks=self._history_tasks or self.n_tasks,
             mesh=self.mesh,
-            broadcast_threshold=self.broadcast_threshold)
+            join_max_broadcast_table_size=self.join_max_broadcast_table_size)
 
 
 class BatchQueryRunner(DistributedQueryRunner):

@@ -31,6 +31,7 @@ from .adaptive import (AdaptiveState, DynamicFilterCollector,
                        decide_exchange, decide_side_swap,
                        summaries_to_runtime, summarize_key_column)
 from .pipeline import ExecutionConfig, PlanCompiler, TaskContext
+from ..sql.fragmenter import row_bytes
 from ..utils.runtime_stats import host_get
 
 
@@ -41,10 +42,10 @@ class SchedulerConfig:
     source_tasks: int = 2
     # tasks per FIXED_HASH intermediate stage
     hash_tasks: int = 2
-    # broadcast row budget for runtime partitioned->broadcast flips
-    # (exec/adaptive.decide_exchange) — mirrors the fragmenter's
-    # plan-time FragmenterConfig.broadcast_threshold
-    broadcast_threshold: int = 600_000
+    # byte budget of a build side for runtime partitioned->broadcast flips
+    # (exec/adaptive.decide_exchange) -- the fragmenter's plan-time
+    # FragmenterConfig.join_max_broadcast_table_size
+    join_max_broadcast_table_size: int = 100 << 20
     # jax.sharding.Mesh over parallel.mesh.WORKER_AXIS: when set, a
     # source or hashed stage whose task count equals the mesh size has
     # its tasks pinned 1:1 to mesh devices -- a source task scans the
@@ -529,7 +530,8 @@ class InProcessScheduler:
                 acted = True
             if observed_b is not None and decide_exchange(
                     node.planned_build_rows, observed_b,
-                    self.config.broadcast_threshold):
+                    self.config.join_max_broadcast_table_size
+                    // row_bytes(node.right)):
                 side = node.right
                 while isinstance(side, P.FilterNode):
                     side = side.source

@@ -339,6 +339,9 @@ class JoinNode(PlanNode):
     # exec/adaptive.decide_exchange compares it against the observed
     # count at the stage boundary
     planned_build_rows: Optional[int] = None
+    # and its estimate of the build side's bytes, which it compared with
+    # join-max-broadcast-table-size (sql/fragmenter.py FragmenterConfig)
+    planned_build_bytes: Optional[int] = None
 
     @property
     def sources(self):
@@ -359,6 +362,8 @@ class JoinNode(PlanNode):
              "dynamicFilters": dict(self.dynamic_filters)}
         if self.planned_build_rows is not None:
             d["plannedBuildRows"] = self.planned_build_rows
+        if self.planned_build_bytes is not None:
+            d["plannedBuildBytes"] = self.planned_build_bytes
         return d
 
     @classmethod
@@ -371,7 +376,7 @@ class JoinNode(PlanNode):
                    RowExpression.from_dict(d["filter"]) if d.get("filter") else None,
                    d.get("distributionType"),
                    d.get("dynamicFilters", {}),
-                   d.get("plannedBuildRows"))
+                   d.get("plannedBuildRows"), d.get("plannedBuildBytes"))
 
 
 @_node

@@ -9,9 +9,10 @@ planner emits is rewritten so that:
   repartition-by-group-keys exchange (or gather, for global aggs) + FINAL
   (the reference's PushPartialAggregationThroughExchange rule);
 - joins pick a distribution: REPLICATED (broadcast the build side, the
-  reference's join_distribution_type=BROADCAST) when the build estimate is
-  under the threshold, else PARTITIONED (both sides repartitioned on the
-  join keys, FIXED_HASH_DISTRIBUTION);
+  reference's join_distribution_type=BROADCAST) when the build side's
+  estimated bytes fit join-max-broadcast-table-size and replicating moves
+  no more bytes than partitioning (FragmenterConfig), else PARTITIONED
+  (both sides repartitioned on the join keys, FIXED_HASH_DISTRIBUTION);
 - sort/topN/limit split into partial (distributed) + final (after a gather);
 - the root gets a GATHER exchange (the coordinator's result pump reads a
   SINGLE-distribution root stage, Query.java:116).
@@ -38,11 +39,42 @@ from ..spi.expr import (CallExpression, RowExpression,
 Variable = VariableReferenceExpression
 
 
+AUTOMATIC, BROADCAST, PARTITIONED = "AUTOMATIC", "BROADCAST", "PARTITIONED"
+
+
 @dataclass
 class FragmenterConfig:
-    # broadcast the join build side when its estimated rows fall below this
-    # (reference: join_distribution_type AUTOMATIC + JoinSwappingRules)
-    broadcast_threshold: int = 600_000
+    """Presto's own two join-distribution properties, with its documented
+    defaults (`join-distribution-type`, `join-max-broadcast-table-size`;
+    DetermineJoinDistributionType.java): under AUTOMATIC a side is
+    replicated where its estimated BYTES fit the limit and sending it to
+    every task moves no more bytes than partitioning both sides would;
+    BROADCAST replicates every build side, PARTITIONED none."""
+    join_distribution_type: str = AUTOMATIC
+    join_max_broadcast_table_size: int = 100 << 20
+    # tasks that would each receive a replicated side: the runner's task
+    # count (the cost model's estimatedSourceDistributedTaskCount)
+    n_tasks: int = 2
+
+    def __post_init__(self):
+        self.join_distribution_type = self.join_distribution_type.upper()
+        if self.join_distribution_type not in (AUTOMATIC, BROADCAST,
+                                               PARTITIONED):
+            raise ValueError("join-distribution-type must be AUTOMATIC, "
+                             "BROADCAST or PARTITIONED, not "
+                             f"{self.join_distribution_type!r}")
+
+    def replicates(self, side_bytes: Optional[float],
+                   other_bytes: Optional[float]) -> bool:
+        """Whether a join side of `side_bytes` is sent whole to every
+        task, the other side (`other_bytes`) staying where it is."""
+        if self.join_distribution_type != AUTOMATIC:
+            return self.join_distribution_type == BROADCAST
+        if side_bytes is None \
+                or side_bytes > self.join_max_broadcast_table_size:
+            return False
+        return other_bytes is None \
+            or side_bytes * self.n_tasks <= side_bytes + other_bytes
 
 
 # ---------------------------------------------------------------------------
@@ -78,6 +110,32 @@ def estimate_rows(node: P.PlanNode, calc=None) -> Optional[float]:
     if est is not None:
         return est
     return _estimate_rows_heuristic(node, calc)
+
+
+def type_bytes(typ: Type) -> int:
+    """Estimated bytes of one value of `typ` in a page: the fixed width,
+    and for a string its declared length (32 where it has none)."""
+    from ..common.types import CharType, VarcharType
+    if isinstance(typ, (VarcharType, CharType)):
+        length = getattr(typ, "length", None)
+        return int(length) if length and length < (1 << 16) else 32
+    if isinstance(typ, DecimalType):
+        return 8 if typ.precision <= 18 else 16
+    name = str(typ).lower()
+    return {"boolean": 1, "tinyint": 1, "smallint": 2, "integer": 4,
+            "date": 4, "real": 4}.get(name, 8)
+
+
+def row_bytes(node: P.PlanNode) -> int:
+    """Estimated bytes of one output row of `node`."""
+    return max(1, sum(type_bytes(v.type) for v in node.output_variables))
+
+
+def estimate_bytes(node: P.PlanNode, calc=None) -> Optional[float]:
+    """Estimated bytes of `node`'s output, rows times row width: what
+    Presto's cost model compares with join-max-broadcast-table-size."""
+    rows = estimate_rows(node, calc)
+    return None if rows is None else rows * row_bytes(node)
 
 
 def _estimate_rows_heuristic(node: P.PlanNode, calc) -> Optional[float]:
@@ -312,7 +370,6 @@ class ExchangeInserter:
         # (the optimizer's SwapJoinSides chose so already; this keeps it)
         from .stats import primary_key_sides
         pk_left, pk_right = primary_key_sides(self._calc, node)
-        threshold = self.config.broadcast_threshold
         if node.join_type == P.INNER and lest is not None and rest is not None \
                 and (pk_left if pk_left != pk_right else lest < rest):
             node.left, node.right = node.right, node.left
@@ -325,15 +382,17 @@ class ExchangeInserter:
         # compare it against observed rows at the stage boundary and flip
         # the exchange strategy (exec/adaptive.decide_exchange)
         node.planned_build_rows = int(rest) if rest is not None else None
-        broadcast = (rest is not None and rest <= threshold
-                     and node.join_type in (P.INNER, P.LEFT))
-        if broadcast:
+        lbytes = estimate_bytes(node.left, self._calc)
+        rbytes = estimate_bytes(node.right, self._calc)
+        node.planned_build_bytes = int(rbytes) if rbytes is not None else None
+        if node.join_type in (P.INNER, P.LEFT) \
+                and self.config.replicates(rbytes, lbytes):
             node.distribution = P.REPLICATED
             if right.dist != SINGLE or left.dist != SINGLE:
                 node.right = self._broadcast(node.right)
             return _Placed(node, left.dist, left.hash_keys)
         if pk_right and node.join_type == P.INNER and right.dist == SOURCE \
-                and lest is not None and lest <= threshold:
+                and self.config.replicates(lbytes, rbytes):
             # the small side is the PROBE: it is broadcast, every task
             # builds over its own splits of the key's table and emits
             # the matches that fall there (INNER only: a probe row that
@@ -359,8 +418,9 @@ class ExchangeInserter:
         node.source, node.filtering_source = src.node, filt.node
         if src.dist == SINGLE and filt.dist == SINGLE:
             return _Placed(node, SINGLE)
-        fest = estimate_rows(node.filtering_source, self._calc)
-        if fest is not None and fest <= self.config.broadcast_threshold:
+        if self.config.replicates(
+                estimate_bytes(node.filtering_source, self._calc),
+                estimate_bytes(node.source, self._calc)):
             if filt.dist != SINGLE or src.dist != SINGLE:
                 node.filtering_source = self._broadcast(node.filtering_source)
             return _Placed(node, src.dist, src.hash_keys)

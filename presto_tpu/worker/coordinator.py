@@ -1011,14 +1011,17 @@ class HttpQueryRunner(LocalQueryRunner):
     def __init__(self, worker_uris: List[str], schema: str = "sf0.01",
                  failure_detector: Optional[HeartbeatFailureDetector] = None,
                  config: Optional[ExecutionConfig] = None,
-                 n_tasks: int = 2, broadcast_threshold: int = 600_000,
+                 n_tasks: int = 2,
+                 join_distribution_type: str = "AUTOMATIC",
+                 join_max_broadcast_table_size: int = 100 << 20,
                  session: Optional[Dict[str, str]] = None,
                  catalog: str = "tpch"):
         super().__init__(schema, config, catalog)
         self.worker_uris = worker_uris
         self.failure_detector = failure_detector
         self.n_tasks = n_tasks
-        self.broadcast_threshold = broadcast_threshold
+        self.join_distribution_type = join_distribution_type
+        self.join_max_broadcast_table_size = join_max_broadcast_table_size
         self.session = session or {}
         self._rr = itertools.count()
         # lifetime counters across queries (surfaced via /v1/metrics when
@@ -1061,10 +1064,28 @@ class HttpQueryRunner(LocalQueryRunner):
             output = Planner.optimize_output(unopt)
         names = output.column_names
         types = [v.type for v in output.outputs]
-        cfg = FragmenterConfig(broadcast_threshold=self.broadcast_threshold)
         with stats.span("queryFragment"), self._validation():
-            sub = plan_distributed(output, cfg, exec_config=self.config)
+            sub = plan_distributed(output, self._fragmenter_config(),
+                                   exec_config=self.config)
+        # the distribution choice: what the fragmenter compared with
+        # join-max-broadcast-table-size, and how it came out
+        for frag in sub.all_fragments():
+            for node in P.walk_plan(frag.root):
+                if isinstance(node, P.JoinNode) and node.distribution:
+                    stats.add("joinBuildBroadcastBytes",
+                              node.planned_build_bytes or 0, "BYTE")
+                    stats.add("joinsReplicated",
+                              int(node.distribution == P.REPLICATED))
+                    stats.add("joinsPartitioned",
+                              int(node.distribution == P.PARTITIONED))
         return sub, names, types
+
+    def _fragmenter_config(self):
+        from ..sql.fragmenter import FragmenterConfig
+        return FragmenterConfig(
+            join_distribution_type=self.join_distribution_type,
+            join_max_broadcast_table_size=self.join_max_broadcast_table_size,
+            n_tasks=self.n_tasks)
 
     def _build_stages(self, subplan: P.SubPlan,
                       stage_path: str = "0") -> _Stage:
@@ -1095,10 +1116,7 @@ class HttpQueryRunner(LocalQueryRunner):
                              default_catalog=self.catalog) \
                 .plan_query_to_output(ast.query)
             subplan = plan_distributed(
-                output,
-                FragmenterConfig(
-                    broadcast_threshold=self.broadcast_threshold),
-                exec_config=self.config)
+                output, self._fragmenter_config(), exec_config=self.config)
         stats = None
         footer = ""
         if ast.analyze:

@@ -360,6 +360,15 @@ def server_kwargs_from_properties(props: Dict[str, str]) -> dict:
         if n < 1:
             raise ValueError(f"node.devices must be >= 1, got {n}")
         kwargs["devices"] = n
+    # the optimizer's join distribution (Presto's own keys and values;
+    # sql/fragmenter.py FragmenterConfig holds their documented defaults)
+    if "join-distribution-type" in props:
+        kwargs["join_distribution_type"] = \
+            props["join-distribution-type"].upper()
+    if "join-max-broadcast-table-size" in props:
+        from .protocol import parse_data_size
+        kwargs["join_max_broadcast_table_size"] = int(parse_data_size(
+            props["join-max-broadcast-table-size"]))
     if "announcement-interval-ms" in props:
         kwargs["announce_interval_s"] = \
             int(props["announcement-interval-ms"]) / 1000.0
@@ -449,21 +458,30 @@ def register_catalogs_from_etc(etc_dir: str) -> Dict[str, str]:
     """Mount every etc/catalog/*.properties connector (CatalogManager
     analog): connector.name picks the connector; returns
     {catalog_name: connector.name} for what was mounted."""
-    from ..connectors import catalog as registry
     catalog_dir = os.path.join(etc_dir, "catalog")
-    mounted: Dict[str, str] = {}
     if not os.path.isdir(catalog_dir):
-        return mounted
-    for fn in sorted(os.listdir(catalog_dir)):
-        if not fn.endswith(".properties"):
-            continue
-        name = fn[:-len(".properties")]
-        props = load_properties(os.path.join(catalog_dir, fn))
+        return {}
+    return register_catalogs(
+        {fn[:-len(".properties")]:
+         load_properties(os.path.join(catalog_dir, fn))
+         for fn in sorted(os.listdir(catalog_dir))
+         if fn.endswith(".properties")},
+        warehouse_root=etc_dir)
+
+
+def register_catalogs(catalogs: Dict[str, Dict[str, str]],
+                      warehouse_root: str = ".") -> Dict[str, str]:
+    """Mount {catalog name: the keys of its etc/catalog/<name>.properties}:
+    what `--etc-dir` reads from files and `WorkerServer(catalogs=...)`
+    takes as a dict.  An unknown connector.name is refused."""
+    from ..connectors import catalog as registry
+    mounted: Dict[str, str] = {}
+    for name, props in catalogs.items():
         kind = props.get("connector.name", "")
         if kind == "hive" or kind == "hive-hadoop2":
             from ..connectors import hive
             warehouse = props.get("hive.warehouse.dir",
-                                  os.path.join(etc_dir, "warehouse"))
+                                  os.path.join(warehouse_root, "warehouse"))
             registry.register_connector(
                 name, hive.HiveConnector(
                     warehouse,

@@ -983,13 +983,20 @@ def _with_properties(init):
     config.properties / node.properties as a dict, applied through the
     mapping `--etc-dir` uses (worker/properties.py).  An argument given
     explicitly wins over a property; `config` given explicitly replaces
-    the properties' ExecutionConfig whole."""
+    the properties' ExecutionConfig whole.  `catalogs={name: {...}}` is
+    etc/catalog/<name>.properties the same way: each is mounted by the
+    code that mounts the files (an unknown connector.name is refused)."""
     import functools
     import inspect
     signature = inspect.signature(init)
 
     @functools.wraps(init)
-    def with_properties(self, *args, properties=None, **kwargs):
+    def with_properties(self, *args, properties=None, catalogs=None,
+                        **kwargs):
+        if catalogs:
+            from .properties import register_catalogs
+            register_catalogs({str(n): {str(k): str(v) for k, v in p.items()}
+                               for n, p in catalogs.items()})
         if properties:
             from .properties import server_kwargs_from_properties
             given = signature.bind_partial(self, *args, **kwargs).arguments
@@ -1001,6 +1008,8 @@ def _with_properties(init):
     with_properties.__signature__ = signature.replace(parameters=[
         *signature.parameters.values(),
         inspect.Parameter("properties", inspect.Parameter.KEYWORD_ONLY,
+                          default=None),
+        inspect.Parameter("catalogs", inspect.Parameter.KEYWORD_ONLY,
                           default=None)])
     return with_properties
 
@@ -1043,8 +1052,15 @@ class WorkerServer:
                  history_path: Optional[str] = None,
                  history_max_count: int = 200,
                  history_max_age_s: Optional[float] = None,
-                 devices: int = 1):
+                 devices: int = 1,
+                 join_distribution_type: str = "AUTOMATIC",
+                 join_max_broadcast_table_size: int = 100 << 20):
         self.environment = environment
+        # Presto's join-distribution-type / join-max-broadcast-table-size
+        # (documented defaults): handed to every runner's fragmenter
+        self.join_distribution = dict(
+            join_distribution_type=join_distribution_type,
+            join_max_broadcast_table_size=join_max_broadcast_table_size)
         self.coordinator = coordinator
         # chips this node owns.  More than one, and a coordinator with no
         # worker announced runs its statements as stages of `devices`
@@ -1286,14 +1302,17 @@ class WorkerServer:
                     runner = HttpQueryRunner(list(uris), schema=schema,
                                              config=cfg, session=session,
                                              failure_detector=det,
-                                             catalog=catalog)
+                                             catalog=catalog,
+                                             **self.join_distribution)
                     self.failure_detector = det
                 elif self.devices > 1:
                     from ..exec.runner import DistributedQueryRunner
                     from ..parallel.mesh import make_mesh
                     runner = DistributedQueryRunner(
                         schema, config=cfg, n_tasks=self.devices,
-                        catalog=catalog, mesh=make_mesh(self.devices))
+                        catalog=catalog, mesh=make_mesh(self.devices),
+                        join_max_broadcast_table_size=self.join_distribution[
+                            "join_max_broadcast_table_size"])
                 else:
                     from ..exec.runner import LocalQueryRunner
                     runner = LocalQueryRunner(schema, config=cfg,

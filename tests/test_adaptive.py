@@ -111,16 +111,16 @@ def test_collector_merges_partials_per_filter_id():
 
 def test_decide_exchange_flip_needs_big_estimate_gap():
     assert decide_exchange(planned_rows=10_000, observed_rows=100,
-                           broadcast_threshold=5_000)
+                           max_broadcast_rows=5_000)
     # observed close to plan: the planner was right, keep partitioned
     assert not decide_exchange(planned_rows=10_000, observed_rows=4_000,
-                               broadcast_threshold=5_000)
+                               max_broadcast_rows=5_000)
     # observed over the threshold never broadcasts, whatever the plan said
     assert not decide_exchange(planned_rows=10_000_000, observed_rows=6_000,
-                               broadcast_threshold=5_000)
+                               max_broadcast_rows=5_000)
     # absent estimate counts as a wrong estimate
     assert decide_exchange(planned_rows=None, observed_rows=10,
-                           broadcast_threshold=5_000)
+                           max_broadcast_rows=5_000)
 
 
 def test_decide_side_swap():
@@ -366,7 +366,7 @@ _AQE_CFG = dict(batch_rows=1 << 14, storage_zone_rows=4096)
 def _dist_runner(**over):
     cfg = ExecutionConfig(**{**_AQE_CFG, **over})
     return DistributedQueryRunner("sf0.01", config=cfg, n_tasks=2,
-                                  broadcast_threshold=5000)
+                                  join_max_broadcast_table_size=64 << 10)
 
 
 def test_adaptive_on_off_fallback_bit_identical():

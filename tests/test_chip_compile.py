@@ -127,9 +127,11 @@ def _capture_program(monkeypatch, sql, program, **capture):
     (SPAN_4KEYS, "scan_agg_static_span", 0, None),
     (ORDERKEY_COUNT, "scan_agg_runtime_span", 0, None),
     (Q3_SHAPE, "scan_agg_runtime_span", 0, None),
-    # over the sort budget: the scatter hash table.  (scan_agg_sort itself
-    # compiles too, in 89 s on this host: too long for this file)
-    (MODULUS_KEY, "scan_agg_hash", 0, 0),
+    # over the sort budgets (the fused one's bytes and the stream's rows):
+    # the scatter hash table's update (its growth: the case below this
+    # function).  (scan_agg_sort itself compiles
+    # too, in 89 s on this host: too long for this file)
+    (MODULUS_KEY, "agg_upd", 0, 0),
     # window_batch(batch, part_names, orderings, specs): three static
     (RUNNING_SUM, "window_batch", 3, None),
 ], ids=["q6", "q1", "static_span", "anchored_span", "join", "hash",
@@ -141,9 +143,12 @@ def test_fused_xla_step_compiles_and_fits(monkeypatch, one_chip, sql,
     strategy at BATCH_ROWS, and the window's segmented scans at the
     capacity the query materializes -- compiles for the v5e and fits its
     16 GB, with no hand-written kernel in it."""
+    config = {}
     if sort_budget is not None:
         monkeypatch.setattr(pipeline, "SORT_AGG_MAX_BYTES", sort_budget)
-    fn, args = _capture_program(monkeypatch, sql, program)
+        monkeypatch.setattr(pipeline, "SORT_STREAM_MAX_ROWS", sort_budget)
+        config["agg_slots"] = 8
+    fn, args = _capture_program(monkeypatch, sql, program, **config)
     n = len(args) - n_static
     compiled = fn.lower(*_on(one_chip, args[:n]), *args[n:]).compile()
     assert "tpu_custom_call" not in compiled.as_text()
@@ -233,8 +238,34 @@ def test_ici_exchange_compiles_on_four_chip_mesh(topo):
 
 SPARSE_FILTER = ("select l_orderkey, l_partkey, l_extendedprice from lineitem "
                  "where l_shipdate = date '1996-03-13'")
+def test_hash_table_growth_compiles(one_chip):
+    """`hash_aggregate`'s growth step (PR 34): a checked table of 1 M
+    slots rehashed into one of 4 M by its stored key hashes."""
+    from presto_tpu.exec import operators as ops
+    specs = (ops.AggSpec("sum", "revenue", False, None),
+             ops.AggSpec("count_star", "n", False, None))
+    names, dtypes = ("k", "day"), (jnp.int64, jnp.int32)
+    old, new = 1 << 20, 1 << 22
+
+    def grow(state):
+        return ops.agg_merge(ops.agg_init(new, specs, names, dtypes), state,
+                             specs, names, new)
+    state = _on(one_chip, ops.agg_init(old, specs, names, dtypes))
+    mem = jax.jit(grow).lower(state).compile().memory_analysis()
+    assert 0 < mem.temp_size_in_bytes + mem.output_size_in_bytes < HBM_BYTES
+
+
 FULL_JOIN = ("select l_orderkey, o_custkey from lineitem full join orders "
              "on l_orderkey = o_orderkey")
+TPCH_Q3 = """
+select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
+  o_orderdate, o_shippriority
+from customer, orders, lineitem
+where c_mktsegment = 'BUILDING' and c_custkey = o_custkey
+  and l_orderkey = o_orderkey and o_orderdate < date '1995-03-15'
+  and l_shipdate > date '1995-03-15'
+group by l_orderkey, o_orderdate, o_shippriority
+order by revenue desc, o_orderdate limit 10"""
 
 
 @pytest.mark.parametrize("sql,program,config", [
@@ -247,7 +278,18 @@ FULL_JOIN = ("select l_orderkey, o_custkey from lineitem full join orders "
     # the sorted-hash probe step with its live count
     ("select o_orderkey, l_linenumber from orders full join lineitem "
      "on o_orderkey = l_orderkey", "join_step", {}),
-], ids=["dense_counts", "dense_write", "join_direct", "join_step"])
+    # TPC-H Q3 (PR 34): a chain cut at its join's lookup (the windowed
+    # gather of `ops.gather_near` in both passes), the build columns
+    # gathered for the dense rows alone, the held rows grouped by one
+    # sort, the first ten picked without one
+    (TPCH_Q3, "chain_dense_counts", {}),
+    (TPCH_Q3, "chain_dense_write", {}),
+    (TPCH_Q3, "chain_dense_finish", {}),
+    (TPCH_Q3, "agg_sort", {}),
+    (TPCH_Q3, "topn_first", {}),
+], ids=["dense_counts", "dense_write", "join_direct", "join_step",
+        "q3_dense_counts", "q3_dense_write", "q3_dense_finish",
+        "q3_agg_sort", "q3_topn"])
 def test_join_path_program_compiles_and_fits(monkeypatch, one_chip, sql,
                                              program, config):
     """At the served chunk (64K rows) over sf0.1's resident columns."""

@@ -18,7 +18,7 @@ def runner():
 @pytest.fixture(scope="module")
 def part_runner():
     # force hash-partitioned joins + exchanges everywhere
-    return DistributedQueryRunner("sf0.01", n_tasks=3, broadcast_threshold=0)
+    return DistributedQueryRunner("sf0.01", n_tasks=3, join_max_broadcast_table_size=0)
 
 
 def check(r, sql, ordered=False):

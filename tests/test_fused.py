@@ -403,17 +403,21 @@ TINY_POOL = dict(batch_rows=1 << 14, memory_budget_bytes=200_000,
     (ORDERKEY_COUNT, {}, None,
      {"scan_agg_span_probe", "scan_agg_runtime_span"}, set()),
     (MODULUS_KEY, {}, None, {"scan_agg_sort"}, set()),
-    (MODULUS_KEY, {}, 0, {"scan_agg_hash"}, set()),
+    # over the sort budget the chain streams and the stream is aggregated
+    # once through: held and grouped by one sort (PR 34; before it a fused
+    # scatter hash table, re-run whole for every doubling)
+    (MODULUS_KEY, {}, 0, {"agg_sort"}, set()),
     # a budgeted pool keeps the streaming executor; its table does not fit
     # 200 kB, so the keys are hash-partitioned into host-staged buckets
     (ORDERKEY_COUNT, TINY_POOL, None, {"agg_upd"}, {"BudgetedPool"}),
 ], ids=["q6-direct", "q1-direct", "4keys-static_span",
-        "orderkey-anchored_span", "modulus-sort", "modulus-hash",
+        "orderkey-anchored_span", "modulus-sort", "modulus-stream_sort",
         "orderkey-spilled_buckets"])
 def test_agg_strategy_by_shape(monkeypatch, sql, config, sort_budget,
                                programs, declined):
-    """direct -> static span -> anchored span -> sort -> hash, and the
-    streaming executor's spilled buckets under a budget: the shape decides,
+    """direct -> static span -> anchored span -> sort -> the stream's
+    own aggregation, and the streaming executor's spilled buckets under
+    a budget: the shape decides,
     and the answer is the oracle's whichever program ran."""
     if sort_budget is not None:
         from presto_tpu.exec import pipeline

@@ -279,7 +279,8 @@ def test_q12_at_sf10_builds_on_orders_and_broadcasts_the_filtered_probe(
     truth), so the filtered lineitem side is seen under the broadcast
     threshold; orders, scanned on its dense primary key, builds a
     direct-address table over each task's own splits and never moves."""
-    from presto_tpu.sql.fragmenter import FragmenterConfig, estimate_rows
+    from presto_tpu.sql.fragmenter import (FragmenterConfig, estimate_bytes,
+                                           estimate_rows)
     _cell, plan, _ref = bench
     sql = plan.statement("tpch/q12", plan.pool["tpch/q12"][0])
     fragments = _fragments(sql, "sf10")
@@ -290,27 +291,39 @@ def test_q12_at_sf10_builds_on_orders_and_broadcasts_the_filtered_probe(
     assert join.distribution == P.REPLICATED
     filtered = next(f.root for f in fragments
                     if isinstance(f.root, P.FilterNode))
-    estimate = estimate_rows(filtered)
-    assert 200_000 < estimate <= FragmenterConfig().broadcast_threshold
+    assert estimate_rows(filtered) > 200_000
+    assert FragmenterConfig().replicates(
+        estimate_bytes(filtered), estimate_bytes(join.right))
     # nothing waits for a key summary of a whole table
     scans = [n for f in fragments for n in P.walk_plan(f.root)
              if isinstance(n, P.TableScanNode)]
     assert not any(s.runtime_filters for s in scans)
 
 
-def test_q14_at_sf10_repartitions_both_sides_and_builds_on_part(bench):
+def test_q14_at_sf10_moves_the_smaller_side_and_builds_on_part(bench):
+    """Since the distribution is chosen by bytes (PR 34): lineitem's month
+    (~758k rows of four narrow columns, ~21 MB) sent to both tasks moves
+    42 MB where partitioning both sides moves it and part's 2 M rows
+    (~66 MB with p_type); part, scanned on its dense primary key, builds
+    over each task's own splits and never moves -- Q12's plan.  With the
+    limit at 0 nothing is replicated: both sides are hash-partitioned, as
+    the row-count threshold had it."""
     _cell, plan, _ref = bench
     sql = plan.statement("tpch/q14", plan.pool["tpch/q14"][0])
     fragments = _fragments(sql, "sf10")
     join = _join_of(fragments)
+    assert join.distribution == P.REPLICATED
+    assert isinstance(join.left, P.RemoteSourceNode)
+    assert isinstance(join.right, P.TableScanNode) \
+        and join.right.table.table_name == "part"
+    from presto_tpu.worker.coordinator import HttpQueryRunner
+    sub, _names, _types = HttpQueryRunner(
+        [], schema="sf10", join_max_broadcast_table_size=0).plan_subplan(sql)
+    fragments = sub.all_fragments()
+    join = _join_of(fragments)
     assert join.distribution == P.PARTITIONED
     assert isinstance(join.left, P.RemoteSourceNode) \
         and isinstance(join.right, P.RemoteSourceNode)
-    part = next(f.root for f in fragments
-                if isinstance(f.root, P.TableScanNode))
-    assert part.table.table_name == "part"
-    assert join.right.source_fragment_ids == [
-        f.fragment_id for f in fragments if f.root is part]
 
 
 def test_a_table_the_sample_would_hold_whole_is_not_sampled():

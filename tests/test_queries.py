@@ -799,7 +799,7 @@ def test_full_outer_join(runner):
 
 def test_full_join_distributed():
     from presto_tpu.exec.runner import DistributedQueryRunner
-    d = DistributedQueryRunner("sf0.01", n_tasks=3, broadcast_threshold=0)
+    d = DistributedQueryRunner("sf0.01", n_tasks=3, join_max_broadcast_table_size=0)
     d.assert_same_as_reference("""
         select a.n_nationkey, b.k from nation a
         full outer join (select n_nationkey + 20 k from nation) b

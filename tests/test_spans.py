@@ -462,14 +462,17 @@ def test_exported_spans_are_nested_with_real_intervals(traced_cluster):
         drain = next(k for k in kids if k["name"].startswith(
             "pipelineDrain"))
         assert interval(build)[1] <= interval(drain)[0] + 1_000_000
-    # a launch hangs off the drain that pulled it (a column generator's
-    # off the storage build that ran it), on the worker's side; the
-    # coordinator's phases hang off the query
+    # a launch hangs off the drain that pulled it -- or off the span of the
+    # operator inside that drain that launched it (PR 34: the grouped
+    # update of a stream, the TopN, a fused join's build) -- a column
+    # generator's off the storage build that ran it, on the worker's
+    # side; the coordinator's phases hang off the query
     launches = named("pipelineDispatch ")
     parents = {by_id[s["parentSpanId"]]["name"].split(" ")[0]
                for s in launches}
     assert launches and "pipelineDrain" in parents
-    assert parents <= {"pipelineDrain", "storageBuild", "pipelineBuild"}
+    assert parents <= {"pipelineDrain", "storageBuild", "pipelineBuild",
+                       "aggUpdate", "aggFinalize", "topN", "joinBuild"}
     for phase in ("queryParse", "queryPlan", "schedCreateTasks",
                   "schedAwaitStages"):
         s = next(s for s in spans if s["name"] == phase)

@@ -158,7 +158,7 @@ def test_returned_orders_semi_join(runner):
 
 
 def test_tpcds_distributed_q3(runner):
-    d = DistributedQueryRunner("sf0.01", n_tasks=3, broadcast_threshold=0,
+    d = DistributedQueryRunner("sf0.01", n_tasks=3, join_max_broadcast_table_size=0,
                                catalog="tpcds")
     d.assert_same_as_reference("""
         select d_year, i_brand_id, sum(ss_ext_sales_price)

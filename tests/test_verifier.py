@@ -23,7 +23,7 @@ def test_engine_vs_reference_matches():
 
 def test_local_vs_distributed_matches():
     local = LocalQueryRunner("sf0.01")
-    dist = DistributedQueryRunner("sf0.01", n_tasks=3, broadcast_threshold=0)
+    dist = DistributedQueryRunner("sf0.01", n_tasks=3, join_max_broadcast_table_size=0)
     results = verify(local.execute, dist.execute, QUERIES[:2])
     assert [v.status for v in results] == [MATCH, MATCH]
 
