@@ -651,6 +651,13 @@ def encoding_hint(connector: str, table: str, column: str) -> Optional[str]:
     return _ENCODING_HINTS.get((connector, table, column))
 
 
+def generated(connector: str, table: str) -> bool:
+    """Whether the table is one of this registry's: every column a
+    counter-hash function of the row id and the scale factor, so its
+    contents never change while the process lives."""
+    return (connector, table) in _TABLES
+
+
 def supported(connector: str, table: str, column: str) -> bool:
     entry = _TABLES.get((connector, table))
     return entry is not None and column in entry[1]

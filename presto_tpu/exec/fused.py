@@ -33,8 +33,8 @@ fallback.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from functools import lru_cache
+from dataclasses import dataclass, field, replace
+from functools import cached_property, lru_cache
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -706,6 +706,118 @@ def try_direct_table(batch: Batch, key: str,
     return DirectTable(slots, jnp.int64(int(vmin)), dict(batch.columns))
 
 
+@dataclass
+class JoinBuild:
+    """One join's build side, ready to probe: what `PlanCompiler.
+    shared_build` hands out and, where the plan allows, keeps in the
+    process-wide cache (serving/builds.py).  It holds device arrays and
+    host facts only -- nothing of the task that built it -- and whatever
+    a later asker would otherwise launch or fetch again is remembered in
+    `memo`, so a cached entry is probed with no launch and no sync."""
+    # the subtree's whole output (None: it gave no batch), and the same
+    # rows with NULL keys masked out: what the table indexes
+    batch: Optional[Batch]
+    keyed: Optional[Batch]
+    # DirectTable | ops.BuildTable over `keyed` (None with `batch`)
+    table: object
+    # a live build row had a NULL key (fetched for semi builds only: the
+    # marker's three-valued output; False for joins)
+    had_null: bool
+    # the subtree's output names when built: a structural twin renames
+    names: Tuple[str, ...] = ()
+    # facts fetched or launched on demand, once: "rows" (`joinBuildRows`),
+    # "max_run" (the hash table's fanout), the dynamic filter's bounds
+    memo: dict = field(default_factory=dict)
+    # operator statistics the build recorded, by the subtree's pre-order
+    # position (set by the door): replayed into a task that hits
+    op_stats: tuple = ()
+
+    @cached_property
+    def nbytes(self) -> int:
+        """Device bytes held, every array once (the table's columns are
+        the batch's)."""
+        seen, total = set(), 0
+        for leaf in jax.tree_util.tree_leaves(
+                (self.batch, self.keyed, self.table)):
+            if id(leaf) not in seen:
+                seen.add(id(leaf))
+                total += int(getattr(leaf, "nbytes", 0))
+        return total
+
+    def rows(self) -> int:
+        """Live build rows with non-NULL keys (`joinBuildRows`)."""
+        if "rows" not in self.memo:
+            from .pipeline import _jit_count_live
+            self.memo["rows"] = 0 if self.keyed is None else int(host_get(
+                _jit_count_live(self.keyed.mask), "join_build_rows"))
+        return self.memo["rows"]
+
+    def max_run(self) -> int:
+        """Largest duplicate run of the hash-sorted table's keys."""
+        if "max_run" not in self.memo:
+            self.memo["max_run"] = int(host_get(_max_run(self.table),
+                                                "build_max_run"))
+        return self.memo["max_run"]
+
+    def renamed(self, names: Tuple[str, ...]) -> "JoinBuild":
+        """This build under a structural twin's output names (structural
+        equality aligns the output order); `memo` is shared."""
+        if self.batch is None or tuple(names) == self.names:
+            return self
+        to = dict(zip(self.names, names))
+
+        def cols(columns):
+            return {to[n]: c for n, c in columns.items() if n in to}
+        return replace(
+            self, names=tuple(names),
+            batch=Batch(cols(self.batch.columns), self.batch.mask),
+            keyed=Batch(cols(self.keyed.columns), self.keyed.mask),
+            table=replace(self.table, columns=cols(self.table.columns)))
+
+
+def finish_join_build(batch: Optional[Batch], keys: Tuple[str, ...],
+                      for_join: bool) -> JoinBuild:
+    """The lookup table over a materialised build side: a direct-address
+    table where one integer key is dense (and, for a join, unique), else
+    the hash-sorted table.  NULL keys never match and are dropped; a semi
+    build (for_join=False) also learns whether it had one (joins skip the
+    device round trip that costs)."""
+    if batch is None:
+        return JoinBuild(None, None, None, False)
+    from .pipeline import _jits
+    had_null = False if for_join else _build_has_null_key(batch, keys)
+    keyed = _drop_null_keys(batch, keys)
+    table = (try_direct_table(keyed, keys[0], allow_dup=not for_join)
+             if len(keys) == 1 else None)
+    if table is None:
+        table = _jits()[1](keyed, tuple(keys))
+    return JoinBuild(batch, keyed, table, had_null)
+
+
+def join_build(compiler, node: P.PlanNode, keys: Tuple[str, ...],
+               for_join: bool, dense: Optional[str] = None) -> JoinBuild:
+    """The build side `node` of a join (or semi join) on `keys`, through
+    the one door that remembers build sides (`PlanCompiler.shared_build`):
+    materialised -- as one fused program where the subtree is a fusible
+    chain, else by draining its stream (`dense`: through `dense_batches`
+    under that key) -- and indexed on a miss, taken whole from the
+    process-wide cache on a hit."""
+    def build():
+        batch = compiler._materialize_node(node, dense)
+        if batch is not None and compiler.ctx.shared_jits is not None:
+            # stage-shared tracing: sibling tasks' build sides differ by
+            # a few rows, which would retrace every shared join program
+            # per task -- normalize to a power-of-two bucket so the stage
+            # converges on one build shape (costs one live-count sync)
+            from .pipeline import _bucket_for, _jit_compact
+            live = int(host_get(batch.mask.sum(), "join_build_live"))
+            bucket = _bucket_for(live) or 1 << max(0, live - 1).bit_length()
+            if bucket != batch.capacity:
+                batch = _jit_compact(batch, bucket)
+        return finish_join_build(batch, keys, for_join)
+    return compiler.shared_build(node, keys, for_join, build)
+
+
 def build_lookup(compiler, build_node: P.PlanNode, keys: Tuple[str, ...],
                  for_join: bool):
     """Returns (table, fanout, build_had_null_key) — fanout is the
@@ -713,30 +825,22 @@ def build_lookup(compiler, build_node: P.PlanNode, keys: Tuple[str, ...],
     fanout > MAX_EXPAND.  The null flag is computed only for semi builds
     (for_join=False); join builds report False unconditionally (they drop
     NULL keys either way)."""
-    from .pipeline import _jits, _span
+    from .pipeline import _span
     # the build of a join fused into its probe's scan chain: the same
     # work under the same span as the unfused join's (`joinBuild`)
     with _span(compiler.ctx.runtime_stats, "joinBuild"):
-        batch = compiler._materialize_node(build_node, cache=True)
-        if batch is None:
-            batch = _empty_build_batch(build_node)
-        # only semi-join markers need the null-key flag (three-valued
-        # output); join builds skip the device round-trip it costs
-        had_null = False if for_join else _build_has_null_key(batch, keys)
-        batch = _drop_null_keys(batch, keys)
-        if len(keys) == 1:
-            dt = try_direct_table(batch, keys[0], allow_dup=not for_join)
-            if dt is not None:
-                return dt, 1, had_null
-        table = _jits()[1](batch, keys)
-        if not for_join:
-            return table, 1, had_null
-        kmax = int(host_get(_max_run(table), "build_max_run"))
+        jb = join_build(compiler, build_node, keys, for_join)
+        if jb.batch is None:
+            jb = finish_join_build(_empty_build_batch(build_node), keys,
+                                   for_join)
+        if isinstance(jb.table, DirectTable) or not for_join:
+            return jb.table, 1, jb.had_null
+        kmax = jb.max_run()
         if kmax <= 1:
-            return table, 1, False
+            return jb.table, 1, False
         if kmax > MAX_EXPAND:
             return None
-        return table, 1 << (kmax - 1).bit_length(), False
+        return jb.table, 1 << (kmax - 1).bit_length(), False
 
 
 def assemble_chain(compiler, node: P.PlanNode) -> Optional[FusedChain]:
@@ -791,52 +895,17 @@ def assemble_chain(compiler, node: P.PlanNode) -> Optional[FusedChain]:
             return None
 
 
-# PROCESS-WIDE cap on device-resident cached build materializations
-# (the runner's plan cache can hold ~64 live compilers; a per-compiler
-# budget would multiply); a compiler's contribution is returned to the
-# pool when the compiler is garbage-collected (plan-cache eviction)
-_FMAT_CACHE_BYTES = 1 << 31
-_fmat_pool = {"bytes": 0}
-
-
-def _fmat_reserve(compiler, nb: int) -> bool:
-    import weakref
-    if _fmat_pool["bytes"] + nb > _FMAT_CACHE_BYTES:
-        return False
-    _fmat_pool["bytes"] += nb
-
-    def _release(n=nb):
-        _fmat_pool["bytes"] -= n
-    weakref.finalize(compiler, _release)
-    return True
-
-
-def fused_materialize(compiler, node: P.PlanNode,
-                      cache: bool = False) -> Optional[Batch]:
+def fused_materialize(compiler, node: P.PlanNode) -> Optional[Batch]:
     """Materialize a fusible chain's full output as ONE device batch via a
     single lax.map program over scan chunks — the zero-host-sync analog of
     draining a streaming subtree batch by batch.  Used for join build
-    sides (cache=True: results stay HBM-resident across re-executions —
-    generated connector data is immutable and writes clear the plan cache)
-    and sort/window inputs.  Returns None when the subtree is not a
+    sides (what keeps one across executions is `join_build`'s door, not
+    this) and sort/window inputs.  Returns None when the subtree is not a
     fusible chain (caller streams instead)."""
     if compiler.ctx.memory.limited:
         return None     # budgeted/limited runs keep the accounted
         # streaming path (a bare query.max-memory ceiling still needs
         # the reservations that enforce it)
-    # keyed STRUCTURALLY so replayed subtrees (scalar-subquery re-plans,
-    # decorrelated copies — fresh node ids, same shape) share one
-    # materialization; on a hit from a twin, columns rename positionally.
-    # Parameterized subtrees append the execution's parameter fingerprint:
-    # the cached batch is a function of the bound constants
-    sk = P.structural_key(node)
-    ckey = ("fmat_result", sk, compiler._splits_fingerprint(node))
-    if '"@type": "parameter"' in sk:
-        ckey += (compiler.ctx.params_fingerprint,)
-    if cache and ckey in compiler._jit_cache:
-        cached, names = compiler._jit_cache[ckey]
-        return _renamed_batch(cached, names,
-                              [v.name for v in node.output_variables])
     chain = assemble_chain(compiler, node)
     if chain is None or not chain.chunks:
         return None
@@ -871,7 +940,6 @@ def fused_materialize(compiler, node: P.PlanNode,
             node, "chain_materialize", run_all,
             extra=prog.signature(expands, leaf_cap))
     from .pipeline import _maybe_compact
-    from .memory import batch_bytes
     out = _maybe_compact(run_all(pos_arr, cnt_arr, aux))
     if compiler.ctx.stats is not None:
         probe = chain_counts_fn(chain, expands, leaf_cap,
@@ -879,20 +947,7 @@ def fused_materialize(compiler, node: P.PlanNode,
                                 ("fmat_counts", node.id, expands))
         record_chain_stats(compiler.ctx.stats, chain,
                            probe(pos_arr, cnt_arr, aux), S)
-    if cache and _fmat_reserve(compiler, batch_bytes(out)):
-        compiler._jit_cache[ckey] = \
-            (out, [v.name for v in node.output_variables])
     return out
-
-
-def _renamed_batch(batch: Batch, names: List[str],
-                   new_names: List[str]) -> Batch:
-    """Positionally rename a cached twin's columns to this subtree's
-    output names (structural equality aligns the output order)."""
-    if names == new_names:
-        return batch
-    cols = {new: batch.columns[old] for old, new in zip(names, new_names)}
-    return Batch(cols, batch.mask)
 
 
 def chain_counts_fn(chain: "FusedChain", expands: Tuple[int, ...],

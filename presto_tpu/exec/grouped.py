@@ -110,7 +110,7 @@ def _materialize_bucket_build(compiler, jn, scan_node, btable: str,
     ctx.splits[scan_node.id] = [catalog.TableSplit(
         cid, btable, sf, rows[0], rows[1])]
     try:
-        b = fused_materialize(compiler, jn.right, cache=False)
+        b = fused_materialize(compiler, jn.right)
     finally:
         if saved_split is None:
             ctx.splits.pop(scan_node.id, None)

@@ -760,12 +760,6 @@ class _RevocableBuildBuffer:
                 self._spill_locked()
             self.spill.add(b, self._keys)
 
-    def seed(self, batches: List[Batch]) -> None:
-        """Pre-collected batches with no reservation (the fused
-        materialization path, which only runs unbudgeted)."""
-        with self._lock:
-            self.collected.extend(batches)
-
     def finish(self):
         """-> (collected, spill).  Stops revocation: past this point the
         batches feed the device hash table, which spilling the staging
@@ -803,6 +797,35 @@ class _RevocableBuildBuffer:
                 self._table_bytes = 0
             self.collected = []
             self._reserved = 0
+
+
+def _stats_delta(before: dict, after: Optional[dict]) -> Optional[dict]:
+    """What one operator's statistics entry gained while a build side
+    was made: counts as differences, flags and names as they stand; the
+    wall is left out (a task that takes the build from the cache spent
+    none)."""
+    if not after:
+        return None
+    out = {}
+    for k, v in after.items():
+        if k == "wall_s":
+            continue
+        if isinstance(v, (int, float)) and not isinstance(v, bool):
+            v = v - before.get(k, 0)
+        out[k] = v
+    return out
+
+
+def _stats_replay(stats: dict, node_id: str, delta: Optional[dict]) -> None:
+    """`_stats_delta`'s record into this task's entry of the node."""
+    if delta is None:
+        return
+    ent = stats.setdefault(node_id, {"rows": 0, "wall_s": 0.0, "batches": 0})
+    for k, v in delta.items():
+        if isinstance(v, (int, float)) and not isinstance(v, bool):
+            ent[k] = ent.get(k, 0) + v
+        else:
+            ent[k] = v
 
 
 def _fragment_batch_sig(batch: Batch) -> tuple:
@@ -894,6 +917,85 @@ class PlanCompiler:
                 (node, P.named_structural_key(node))
         key = (purpose,) + structure[1] + (tuple(extra), self._config_fp)
         return FRAGMENT_JIT_CACHE.get_or_build(key, build)
+
+    def shared_build(self, node, keys, for_join: bool, build):
+        """The join build side `build()` makes for the subtree `node` (a
+        `JoinBuild`, exec/fused.py): THE place a build side is
+        remembered.  Where the plan says the subtree's result is fixed by
+        what a key can hold (`_build_share_key`), the entry comes from the
+        process-wide cache (serving/builds.py) -- a worker task's new
+        PlanCompiler finds what the last task with the same subtree and
+        the same splits built, launches nothing and fetches nothing --
+        and the lookup counts `joinBuildCacheHits` / `...Misses` /
+        `...Bytes` on this task.  Everything else is built for this
+        execution alone and counts neither.  Either way the operator
+        statistics of the subtree's nodes read as if it had run here."""
+        names = tuple(v.name for v in node.output_variables)
+        key = self._build_share_key(node, names, keys, for_join)
+        if key is None:
+            return build()
+        from ..serving.builds import JOIN_BUILD_CACHE
+        stats = self.ctx.stats
+        ids = [n.id for n in P.walk_plan(node)] if stats is not None else ()
+
+        def entry():
+            before = {i: dict(stats.get(i) or ()) for i in ids}
+            jb = build()
+            jb.names = names
+            jb.op_stats = tuple(_stats_delta(before[i], stats.get(i))
+                                for i in ids)
+            return jb
+        jb, hit = JOIN_BUILD_CACHE.get_or_build(key, entry,
+                                                self.ctx.runtime_stats)
+        if hit and stats is not None:
+            for i, delta in zip(ids, jb.op_stats):
+                _stats_replay(stats, i, delta)
+        return jb.renamed(names)
+
+    def _build_share_key(self, node, names, keys, for_join: bool):
+        """The process-wide key of `node`'s build side, or None where
+        something that shapes it is in no key: decided from the plan and
+        the task's context, by no knob and no table's name.  Shareable:
+        every leaf a scan of a generated table (a counter-hash function
+        of the row id, immutable: `device_gen`'s registry; a stored table
+        can change under DDL, and what flows through a RemoteSourceNode
+        is this execution's alone), no dynamic filter pruning a scan's
+        chunks, no local exchange merging inputs, and no memory budget
+        (budgeted runs keep the accounted streaming path and the
+        revocable build buffer).  The engine lowers no non-deterministic
+        function, so every expression is a function of its row."""
+        cfg = self.ctx.config
+        if self.ctx.memory.limited or any(k not in names for k in keys):
+            return None
+        from ..connectors import device_gen
+        assigns_ids = False
+        for n in P.walk_plan(node):
+            if isinstance(n, P.TableScanNode):
+                if not device_gen.generated(n.table.connector_id,
+                                            n.table.table_name):
+                    return None
+                if cfg.dynamic_filtering \
+                        and getattr(n, "runtime_filters", ()):
+                    return None
+            elif not n.sources:
+                return None     # RemoteSource, Values: not in the key
+            elif isinstance(n, P.ExchangeNode) and n.inputs:
+                return None
+            elif isinstance(n, P.AssignUniqueIdNode):
+                assigns_ids = True
+        if self._config_fp is None:
+            from ..sql.canonical import config_fingerprint
+            self._config_fp = config_fingerprint(cfg)
+        sk = P.structural_key(node)
+        # a pinned task's arrays live on its own chip
+        device = jax.config.jax_default_device
+        return (sk, self._splits_fingerprint(node),
+                self.ctx.params_fingerprint
+                if '"@type": "parameter"' in sk else None,
+                self._config_fp, tuple(names.index(k) for k in keys),
+                bool(for_join), self.ctx.stats is not None,
+                self.ctx.task_index if assigns_ids else None,
+                getattr(device, "id", device))
 
     def _dense(self, batches, key: str):
         """`dense_batches` into this task's RuntimeStats; under a memory
@@ -1433,10 +1535,11 @@ class PlanCompiler:
                     total += int(r)
                     conn.staged(f).commit()
             # the table changed under every cached program that was
-            # probed against its old contents: on a worker this commit is
-            # the DDL (the runner's own is _invalidate_plans)
-            from ..serving.fragments import FRAGMENT_JIT_CACHE
-            FRAGMENT_JIT_CACHE.invalidate_all()
+            # probed against its old contents, and under every kept build
+            # side: on a worker this commit is the DDL (the runner's own
+            # is _invalidate_plans)
+            from ..serving.builds import invalidate_compiled
+            invalidate_compiled()
             cols = {node.outputs[0].name:
                     Column(jnp.asarray(np.array([total], dtype=np.int64)))}
             yield Batch(cols, jnp.asarray(np.array([True])))
@@ -3288,50 +3391,20 @@ class PlanCompiler:
                     [s.to_dict() for s in sp], sort_keys=True))
         return "|".join(parts)
 
-    def _materialize(self, src: BatchSource) -> Optional[Batch]:
-        batches = list(src.batches())
-        if not batches:
-            return None
-        if len(batches) == 1:
-            return batches[0]
-        return _compact_concat(batches)
-
     def _materialize_node(self, node: P.PlanNode,
-                          cache: bool = False) -> Optional[Batch]:
+                          dense: Optional[str] = None) -> Optional[Batch]:
         """Materialize a subtree's full output as one batch, via the fused
         single-program path when the subtree is a fusible chain (zero host
-        syncs), else by draining the streaming source.  cache=True keeps
-        the result HBM-resident across re-executions (join build sides)
-        and across structurally identical replays of the subtree (scalar-
-        subquery re-plans, decorrelated copies)."""
-        from .fused import _fmat_reserve, _renamed_batch, fused_materialize
-        b = fused_materialize(self, node, cache=cache)
+        syncs), else by draining the streaming source (`dense`: through
+        `dense_batches` under that key).  Nothing is kept: a join's build
+        side is remembered by `shared_build`."""
+        from .fused import fused_materialize
+        b = fused_materialize(self, node)
         if b is not None:
             return b
-        skey = None
-        if cache and not self.ctx.memory.limited:
-            sk = P.structural_key(node)
-            skey = ("mat_result", sk, self._splits_fingerprint(node))
-            if '"@type": "parameter"' in sk:
-                # parameterized subtree (an optimizer rule moved a probe
-                # side into a build): the structural key is value-free, so
-                # the cached result must be pinned to this execution's
-                # bound values
-                skey += (self.ctx.params_fingerprint,)
-            ent = self._jit_cache.get(skey)
-            if ent is not None:
-                cached, names = ent
-                return (None if cached is None else _renamed_batch(
-                    cached, names, [v.name for v in node.output_variables]))
-        out = self._materialize(self._compile(node))
-        if skey is not None:
-            from .memory import batch_bytes
-            nb = 0 if out is None else batch_bytes(out)
-            if _fmat_reserve(self, nb):
-                self._jit_cache[skey] = \
-                    (out, [] if out is None
-                     else [v.name for v in node.output_variables])
-        return out
+        batches = self._compile(node).batches()
+        batches = list(self._dense(batches, dense) if dense else batches)
+        return _compact_concat(batches) if batches else None
 
     def _compile_JoinNode(self, node: P.JoinNode) -> BatchSource:
         if node.join_type not in (P.INNER, P.LEFT, P.FULL):
@@ -3445,10 +3518,11 @@ class PlanCompiler:
         # EXPLAIN ANALYZE stats as dynamicFilterRowsDropped
         df_cache: dict = {}
 
-        def make_dynamic_filter(build_batch):
+        def make_dynamic_filter(jb):
             # INNER only: LEFT joins carry dynamic_filters keyed by their
             # BUILD variables (the probe is preserved and must never be
             # narrowed — see plan_dynamic_filters' direction convention)
+            build_batch = jb.batch
             if node.join_type != P.INNER or not node.dynamic_filters \
                     or build_batch is None:
                 return None
@@ -3490,7 +3564,11 @@ class PlanCompiler:
                     self.shared_jit(node, "df_apply", _apply,
                                     extra=(probe_names,)))
             bounds, apply = df_cache["fn"]
-            bnds = bounds(build_batch)
+            # the bounds are the build side's: computed once an entry
+            names = tuple(rn for _ln, rn in numeric)
+            bnds = jb.memo.get(("df_bounds", names))
+            if bnds is None:
+                bnds = jb.memo[("df_bounds", names)] = bounds(build_batch)
             return lambda batch: apply(batch, bnds)
 
         def gen():
@@ -3623,49 +3701,39 @@ class PlanCompiler:
                 if full:
                     yield unmatched_build(build_batch, matched)
 
-            # materialize the build side under the memory budget; the
-            # staging reservation is REVOCABLE — either this loop's own
-            # budget miss or the arbitrator (another operator starving)
-            # converts it into a grace hash join's partitioned host store
-            # (reference: HashBuilderOperator.java:56 revocable memory +
-            # partitioned spilling)
-            buf = _RevocableBuildBuffer(self, build_keys, cfg.spill_enabled)
+            # the build side: through the door that remembers build
+            # sides (`shared_build`) where memory is unbudgeted; under a
+            # budget staged batch by batch, and the staging reservation
+            # is REVOCABLE — either this loop's own budget miss or the
+            # arbitrator (another operator starving) converts it into a
+            # grace hash join's partitioned host store (reference:
+            # HashBuilderOperator.java:56 revocable memory + partitioned
+            # spilling)
+            from .fused import DirectTable, finish_join_build, join_build
+            buf = (_RevocableBuildBuffer(self, build_keys, cfg.spill_enabled)
+                   if pool.limited else None)
+            held = 0
             try:
                 with _span(rs, "joinBuild"):
-                    from .fused import fused_materialize
-                    fb = fused_materialize(self, build_src_node, cache=True)
-                    if fb is not None:
-                        # fused single-program build materialization (only
-                        # when memory is unbudgeted, so no reservation
-                        # bookkeeping)
-                        buf.seed([fb])
+                    spill = None
+                    if buf is None:
+                        jb = join_build(self, build_src_node,
+                                        tuple(build_keys), True,
+                                        dense="buildCoalesce")
+                        # whoever built it, this task holds it while it
+                        # probes: its bytes count toward the task's peak
+                        nb = jb.nbytes
+                        held = nb if pool.try_reserve(nb) else 0
                     else:
-                        for b in self._dense(
-                                self._compile(build_src_node).batches(),
-                                "buildCoalesce"):
+                        for b in self._compile(build_src_node).batches():
                             buf.add(b)
-                    collected, spill = buf.finish()
+                        collected, spill = buf.finish()
+                        if spill is None:
+                            jb = finish_join_build(
+                                _compact_concat(collected) if collected
+                                else None, tuple(build_keys), True)
                 if spill is None:
-                    with _span(rs, "joinBuild"):
-                        build_batch = (
-                            None if not collected else collected[0]
-                            if len(collected) == 1
-                            else _compact_concat(collected))
-                        if build_batch is not None \
-                                and self.ctx.shared_jits is not None:
-                            # stage-shared tracing: sibling tasks' build
-                            # sides differ by a few rows, which would
-                            # retrace every shared join program per task —
-                            # normalize to a power-of-two bucket so the
-                            # stage converges on one build shape (costs one
-                            # live-count sync)
-                            live = int(host_get(build_batch.mask.sum(),
-                                                "join_build_live"))
-                            bucket = _bucket_for(live) \
-                                or 1 << max(0, live - 1).bit_length()
-                            if bucket != build_batch.capacity:
-                                build_batch = _jit_compact(build_batch,
-                                                           bucket)
+                    build_batch = jb.batch
                     probe = self._compile(probe_src_node)
                     if build_batch is None:
                         if node.join_type == P.INNER:
@@ -3673,30 +3741,21 @@ class PlanCompiler:
                         for batch in probe.batches():
                             yield null_extended(batch)
                         return
-                    from .fused import _drop_null_keys, try_direct_table
-                    with _span(rs, "joinBuild"):
-                        dropped = _drop_null_keys(build_batch,
-                                                  tuple(build_keys))
-                        dt = (try_direct_table(dropped, build_keys[0],
-                                               allow_dup=False)
-                              if len(build_keys) == 1 else None)
-                        table = None if dt is not None else \
-                            _jits()[1](dropped, tuple(build_keys))
-                        if rs is not None:
-                            count("joinBuildRows", int(host_get(
-                                _jit_count_live(dropped.mask),
-                                "join_build_rows")))
-                    if dt is not None:
+                    if rs is not None:
+                        with _span(rs, "joinBuild"):
+                            count("joinBuildRows", jb.rows())
+                    dyn_filter = make_dynamic_filter(jb)
+                    if isinstance(jb.table, DirectTable):
                         # dense unique integer key: fanout-1 direct probe,
                         # zero per-batch host syncs (no overflow/live
                         # fetch — output capacity == probe capacity)
                         yield from probe_stream_direct(
-                            dt, probe.batches(), build_batch,
-                            dyn_filter=make_dynamic_filter(build_batch))
+                            jb.table, probe.batches(), build_batch,
+                            dyn_filter=dyn_filter)
                         return
                     yield from probe_stream(
-                        table, probe.batches(), build_batch,
-                        dyn_filter=make_dynamic_filter(build_batch))
+                        jb.table, probe.batches(), build_batch,
+                        dyn_filter=dyn_filter)
                     return
                 # grace path: partition the probe the same way, join
                 # bucket-by-bucket (each bucket is a Lifespan).  A bucket
@@ -3757,7 +3816,10 @@ class PlanCompiler:
                     finally:
                         pool.free(bucket_bytes)
             finally:
-                buf.close()
+                if buf is not None:
+                    buf.close()
+                if held:
+                    pool.free(held)
         return BatchSource(gen, out_names, out_types)
 
     def _compile_SemiJoinNode(self, node: P.SemiJoinNode) -> BatchSource:
@@ -3791,25 +3853,16 @@ class PlanCompiler:
             if fs is not None:
                 yield from (b.select(names) for b in fs)
                 return
-            build_batch = self._materialize_node(node.filtering_source,
-                                                 cache=True)
-            if build_batch is None:
+            from .fused import DirectTable, join_build
+            jb = join_build(self, node.filtering_source, (fkey,), False)
+            if jb.batch is None:
                 for b in src.batches():
                     yield b.with_columns({node.semi_join_output.name: Column(
                         jnp.zeros(b.capacity, dtype=bool), None)})
                 return
-            from .fused import (_build_has_null_key, _drop_null_keys,
-                                try_direct_table)
-            has_null = _build_has_null_key(build_batch, (fkey,))
-            dropped = _drop_null_keys(build_batch, (fkey,))
-            dt = try_direct_table(dropped, fkey, allow_dup=True)
-            if dt is not None:
-                for b in src.batches():
-                    yield step_direct(b, dt, has_null)
-                return
-            table = _jits()[1](dropped, (fkey,))
+            probe = step_direct if isinstance(jb.table, DirectTable) else step
             for b in src.batches():
-                yield step(b, table, has_null)
+                yield probe(b, jb.table, jb.had_null)
         return BatchSource(gen, names, types)
 
     def _compile_AssignUniqueIdNode(self, node: P.AssignUniqueIdNode) -> BatchSource:

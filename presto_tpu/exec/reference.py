@@ -10,6 +10,8 @@ IR and the data generator.
 """
 from __future__ import annotations
 
+import threading
+
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -46,8 +48,10 @@ class Table:
 # when set (execute_reference(stats=...)), _exec fills it with one
 # entry per plan node id: {"rows", "wall_s", "batches", "operatorType"}
 # — the oracle-side twin of the engine's OperatorStats spine, so
-# differential tests can diff the stats SURFACE, not just result rows
-_ACTIVE_STATS: Optional[Dict[str, dict]] = None
+# differential tests can diff the stats SURFACE, not just result rows.
+# One a thread: concurrent oracle runs (tests/test_spill.py) must not
+# clear each other's map between `_exec`'s check and its write
+_ACTIVE = threading.local()
 
 
 def execute_reference(node: P.PlanNode,
@@ -58,13 +62,12 @@ def execute_reference(node: P.PlanNode,
     the node's output cardinality, wall_s its INCLUSIVE interpretation
     wall (the interpreter recurses, so a node's wall covers its
     subtree), batches is always 1 (the oracle is single-batch)."""
-    global _ACTIVE_STATS
-    prev = _ACTIVE_STATS
-    _ACTIVE_STATS = stats
+    prev = getattr(_ACTIVE, "stats", None)
+    _ACTIVE.stats = stats
     try:
         table = _exec(node)
     finally:
-        _ACTIVE_STATS = prev
+        _ACTIVE.stats = prev
     names = [v.name for v in node.output_variables]
     types = [v.type for v in node.output_variables]
     return _to_rows(table, names, types)
@@ -124,7 +127,8 @@ def _exec(node: P.PlanNode) -> Table:
     fn = globals().get("_exec_" + type(node).__name__)
     if fn is None:
         raise NotImplementedError(type(node).__name__)
-    if _ACTIVE_STATS is None:
+    stats = getattr(_ACTIVE, "stats", None)
+    if stats is None:
         return fn(node)
     import time
     t0 = time.perf_counter()  # lint: allow-wall-clock
@@ -132,7 +136,7 @@ def _exec(node: P.PlanNode) -> Table:
     wall = time.perf_counter() - t0  # lint: allow-wall-clock
     nid = getattr(node, "id", None)
     if nid is not None:
-        _ACTIVE_STATS[str(nid)] = {
+        stats[str(nid)] = {
             "rows": int(table.n),
             "wall_s": wall,
             "batches": 1,

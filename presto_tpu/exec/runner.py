@@ -578,10 +578,11 @@ class LocalQueryRunner:
         """DDL changed table contents: every cached plan/executable (and
         every recorded prepared fast path, whose template keys assume the
         old tables) may be stale."""
-        from ..serving import FRAGMENT_JIT_CACHE, PREPARED_REGISTRY
+        from ..serving import PREPARED_REGISTRY
+        from ..serving.builds import invalidate_compiled
         self.plan_cache.invalidate_all()
         PREPARED_REGISTRY.invalidate_fast_paths()
-        FRAGMENT_JIT_CACHE.invalidate_all()
+        invalidate_compiled()
 
     def _explain(self, ast) -> QueryResult:
         """EXPLAIN: plan text.  EXPLAIN ANALYZE: execute with per-node

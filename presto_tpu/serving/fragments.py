@@ -36,7 +36,11 @@ columns and build tables are NOT baked: they ride as traced arguments,
 and jax.jit's own per-aval / per-treedef retracing (column names,
 dictionaries and nullability are part of a Batch's treedef) handles
 their drift inside the one object.  A false share executes the wrong
-program; a missed share costs one retrace.
+program; a missed share costs one retrace.  The build tables themselves
+-- a join's materialised build side and its lookup table -- live in the
+sibling cache `serving/builds.py`, bounded by bytes, reached through
+`PlanCompiler.shared_build` and cleared together with this one
+(`builds.invalidate_compiled`).
 
 What a cached callable must NOT hold: the task that built it.  Nothing
 reachable from the traced function may reach a TaskContext (memory

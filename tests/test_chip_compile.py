@@ -302,6 +302,44 @@ def test_join_path_program_compiles_and_fits(monkeypatch, one_chip, sql,
     assert 0 < need < HBM_BYTES, mem
 
 
+def test_a_cached_build_side_brings_no_program_of_its_own(monkeypatch):
+    """The door that remembers build sides (`PlanCompiler.shared_build`)
+    adds no program family: an execution that takes its build side from
+    the process-wide cache launches a subset of what the first one
+    launched, and none of the build's programs (so nothing new is there
+    to compile for the chip)."""
+    from presto_tpu.serving import FRAGMENT_JIT_CACHE
+    from presto_tpu.serving.builds import JOIN_BUILD_CACHE
+    from presto_tpu.utils.runtime_stats import NamedJit
+    launched = []
+    real_call = NamedJit.__call__
+
+    def recording(self, *args, **kwargs):
+        launched.append(self.name)
+        return real_call(self, *args, **kwargs)
+    monkeypatch.setattr(NamedJit, "__call__", recording)
+    FRAGMENT_JIT_CACHE.invalidate_all()
+    JOIN_BUILD_CACHE.invalidate_all()
+    runs = []
+    for _ in range(2):
+        del launched[:]
+        runner = LocalQueryRunner(
+            "sf0.01", plan_cache=PlanCache(),
+            config=ExecutionConfig(batch_rows=1 << 14,
+                                   join_out_capacity=1 << 16))
+        res = runner.execute(FULL_JOIN)
+        runs.append((set(launched), res.runtime_stats, len(res.rows)))
+    (first, cold, n_first), (second, warm, n_second) = runs
+    assert cold["joinBuildCacheMisses"]["sum"] == 1
+    assert warm["joinBuildCacheHits"]["sum"] == 1
+    assert n_first == n_second
+    build_family = {"chain_materialize", "chain_counts", "key_stats",
+                    "direct_table_build", "build_table", "max_run"}
+    assert first & build_family
+    assert not second & build_family
+    assert second <= first, second - first
+
+
 def test_stream_coalescer_programs_compile(one_chip):
     """`dense_batches`' append step, the count behind it and the window
     cut of a dense buffer, for a batch of an int64, a decimal, a date and

@@ -170,8 +170,11 @@ def test_host_syncs_do_not_grow_with_the_batch_count(batch_rows):
     chunks are never under 4096 rows): the operators' rows are fetched
     once each, and the whole query's host syncs stay within the few
     windows the stream coalescer looks through."""
+    from presto_tpu.serving.builds import JOIN_BUILD_CACHE
     runs = {}
     for rows in (1 << 13, batch_rows):
+        # both runs build their build side: a kept one costs no sync
+        JOIN_BUILD_CACHE.invalidate_all()
         r = LocalQueryRunner("sf0.1", plan_cache=PlanCache(),
                              config=ExecutionConfig(**dict(
                                  UNFUSED, batch_rows=rows)))
