@@ -25,6 +25,9 @@ class QueryResult:
     rows: List[List]
     # per-query RuntimeStats map (§5.1; populated by the runners)
     runtime_stats: dict = None
+    # the records behind it (`RuntimeStats.timelines()`) where the run's
+    # stats were its own: a library call's, a micro-batched launch's
+    timeline: list = None
     # statement-protocol side channel: PREPARE sets (name, text) so the
     # server can answer with X-Presto-Added-Prepare; DEALLOCATE the name
     added_prepare: tuple = None
@@ -47,6 +50,18 @@ def plan_template_digest(template_sk) -> str:
 
 
 _history_qid = itertools.count()
+
+
+def _close_query(result: QueryResult, stats, began_ns: int) -> None:
+    """A run under RuntimeStats of its own is the query level: its result
+    carries its records and, in `runtime_stats`, the partition of its
+    wall (telemetry/query_wall.py) from `began_ns` to now."""
+    from ..telemetry.query_wall import runtime_stats_keys
+    from ..utils.runtime_stats import unix_ns
+    result.timeline = stats.timelines()
+    result.runtime_stats = {
+        **stats.to_dict(),
+        **runtime_stats_keys(result.timeline, began_ns, unix_ns())}
 
 
 def pages_to_result(pages, names, types) -> "QueryResult":
@@ -126,7 +141,7 @@ class LocalQueryRunner:
         carries the execution's bound-parameter vector."""
         from ..sql.canonical import cache_key_from_parts, parameterize
         from ..spi import plan as P
-        with stats.record_wall("queryPlan"), self._validation():
+        with stats.span("queryPlan"), self._validation():
             planner = Planner(default_schema=self.schema,
                               default_catalog=self.catalog,
                               bound_params=bound_params)
@@ -154,7 +169,7 @@ class LocalQueryRunner:
             exe = _Execution(output, compiler, key, False,
                              list(slot_types))
         else:
-            with stats.record_wall("queryOptimize"), self._validation():
+            with stats.span("queryOptimize"), self._validation():
                 output = Planner.optimize_output(pp.template)
             compiler = self._new_compiler(cfg, stats)
             exe = _Execution(output, compiler, key, True,
@@ -404,6 +419,7 @@ class LocalQueryRunner:
         batch_stats.add("servingBatchOccupancy", len(lanes))
         batch_stats.add("servingBatchLaunchNanos", launch_ns, "NANO")
         lane_stats = batch_stats.to_dict()
+        lane_lines = batch_stats.timelines("batch")
         self._last_template_digest = plan_template_digest(
             fast.template_key)
         names = output.column_names
@@ -416,6 +432,7 @@ class LocalQueryRunner:
                                      if compiler.ctx.memory is not None
                                      else 0)
             res.runtime_stats = dict(lane_stats)
+            res.timeline = lane_lines
             results[i] = res
             SERVING_METRICS.incr("prepared_fast_path")
             self._record_history(res, output)
@@ -430,22 +447,28 @@ class LocalQueryRunner:
                 ) -> QueryResult:
         from contextlib import ExitStack
 
-        from ..utils.runtime_stats import RuntimeStats, current_stats
+        from ..utils.runtime_stats import (RuntimeStats, current_stats,
+                                           unix_ns)
         tracer = self.tracer_provider.new_tracer(sql) \
             if self.tracer_provider else None
         # the statement executor's stats when it set one (the query's
         # RuntimeStats in QueryInfo), else this execution's own
-        stats = current_stats() or RuntimeStats(tracer=tracer, root="query")
+        owner = current_stats()
+        stats = owner or RuntimeStats(tracer=tracer, root="query")
+        began = unix_ns()
         with ExitStack() as stack:
             root = stack.enter_context(tracer.span("query", sql=sql)) \
                 if tracer else None
             stack.enter_context(stats.activate(parent_span=root))
-            return self._execute_owned(sql, prepared, stats)
+            return self._execute_owned(
+                sql, prepared, stats, None if owner else began)
 
-    def _execute_owned(self, sql: str, prepared, stats) -> QueryResult:
+    def _execute_owned(self, sql: str, prepared, stats,
+                       began: Optional[int] = None) -> QueryResult:
         """`execute` under its RuntimeStats, which owns the thread: the
         pipeline's launches and host syncs and JAX's events record into
-        it beside the phases below."""
+        it beside the phases below.  `began` (unix ns) where the stats
+        are this execution's own: it is then the query level."""
         from ..common.types import BOOLEAN
         from ..serving import PREPARED_REGISTRY
         from ..sql import parser as A
@@ -485,6 +508,8 @@ class LocalQueryRunner:
                     compiler.source_to_pages(src), names, types)
         result.profile_trace_dir = trace_dir
         result.runtime_stats = stats.to_dict()
+        if began is not None:
+            _close_query(result, stats, began)
         # peak MemoryPool reservation, for QueryCompletedEvent enrichment
         result.peak_memory_bytes = (compiler.ctx.memory.peak
                                     if compiler.ctx.memory is not None
@@ -616,6 +641,9 @@ class LocalQueryRunner:
             # scheduler/worker paths use so the footer's CPU-vs-wall
             # line is populated here too
             import time as _t
+            from ..telemetry.query_wall import with_partition
+            from ..utils.runtime_stats import unix_ns
+            began = unix_ns()
             t0 = _t.perf_counter()  # lint: allow-wall-clock
             c0 = _t.thread_time()
             with profile_capture(self.config.profile_dir, "analyze",
@@ -628,15 +656,15 @@ class LocalQueryRunner:
             rstats.add("driverWallNanos",
                        (_t.perf_counter() - t0) * 1e9, "NANO")  # lint: allow-wall-clock
             self.last_operator_stats = stats
+            # the analysed run is the query here: its wall, partitioned
+            rstats = with_partition(rstats, began)
         text = format_plan(output, stats)
         if rstats is not None:
             footer = format_analyze_footer(rstats, profile_dir=trace_dir)
             if footer:
                 text += "\n\n" + footer
         return QueryResult(["Query Plan"], [VarcharType(max(1, len(text)))],
-                           [[text]],
-                           runtime_stats=None if rstats is None
-                           else rstats.to_dict())
+                           [[text]], runtime_stats=rstats)
 
     def _fragmenter_config(self):
         from ..sql.fragmenter import FragmenterConfig
@@ -814,6 +842,9 @@ class DistributedQueryRunner(LocalQueryRunner):
                 if (self.tracer_provider and sql) else None
             if tracer is not None:
                 sched.tracer = tracer
+            from ..telemetry.query_wall import with_partition
+            from ..utils.runtime_stats import unix_ns
+            began = unix_ns()
             with (tracer.span("query", sql=sql) if tracer
                   else nullcontext()):
                 with profile_capture(self.config.profile_dir, "analyze",
@@ -822,8 +853,8 @@ class DistributedQueryRunner(LocalQueryRunner):
                     for _page in sched.execute(subplan):
                         pass
             self.last_operator_stats = stats
-            footer = format_analyze_footer(sched.stats,
-                                           profile_dir=trace_dir)
+            footer = format_analyze_footer(
+                with_partition(sched.stats, began), profile_dir=trace_dir)
         text = format_subplan(subplan, stats)
         if footer:
             text += "\n\n" + footer
@@ -842,10 +873,13 @@ class DistributedQueryRunner(LocalQueryRunner):
     def execute(self, sql: str, prepared: Optional[Dict[str, str]] = None
                 ) -> QueryResult:
         from ..sql import parser as A
-        from ..utils.runtime_stats import RuntimeStats, current_stats
+        from ..utils.runtime_stats import (RuntimeStats, current_stats,
+                                           unix_ns)
         # the statement executor's stats when it set one (the query's
         # RuntimeStats in QueryInfo), else this execution's own
-        stats = current_stats() or RuntimeStats()
+        owner = current_stats()
+        stats = owner or RuntimeStats()
+        began = unix_ns()
         with stats.span("queryParse"):
             ast = A.parse_sql(sql)
         if isinstance(ast, A.Explain):
@@ -890,6 +924,8 @@ class DistributedQueryRunner(LocalQueryRunner):
         # fabric-tagged exchange stats (bytes / walls per fabric) collected
         # while the result drained
         result.runtime_stats = sched.stats.to_dict()
+        if owner is None:
+            _close_query(result, sched.stats, began)
         # query-level context peak (all tasks' reservations bubbled up)
         result.peak_memory_bytes = (sched.memory.peak
                                     if sched.memory is not None else 0)

@@ -819,7 +819,8 @@ class InProcessScheduler:
                 stats.add("taskDevice", task_index)
                 stats.add(f"meshTaskLaunches.{task_index}",
                           launches.sum if launches else 0)
-                self.stats.merge(stats)
+                self.stats.merge(
+                    stats, f"task {frag.fragment_id}.{task_index}")
             return out, wall
 
         def run_task_retrying(task_index: int):
@@ -985,6 +986,7 @@ class InProcessScheduler:
                 return False
 
         t0 = _time.perf_counter()  # lint: allow-wall-clock
+        c0_ns = _time.thread_time_ns()
         # ONE device->host transfer covers every task's live-row count
         # (the _compact_concat idiom) — the only host sync on this path;
         # the old per-task device_get loop serialized n round-trips
@@ -1081,8 +1083,8 @@ class InProcessScheduler:
             ICI_CHUNK_TUNER.observe(FABRIC_METRICS.overlap_fraction("ici"))
         self.stats.add("exchangeFabricIciBytes", bytes_moved, "BYTE")
         self.stats.add("exchangeFabricIciChunks", n_chunks)
-        self.stats.add("exchangeFabricIciDispatchWallNanos",
-                       wall * 1e9, "NANO")
+        self.stats.record("exchangeFabricIciDispatch", t0 * 1e9, wall * 1e9,
+                          _time.thread_time_ns() - c0_ns)
         return True
 
     def _spill_batches_to_pages(self, stage: StageInfo, task_batches,
@@ -1177,7 +1179,13 @@ def _device_reader(sources: List[StageInfo], consumer_task: int, rnode,
                                 "stage aborted while draining ICI "
                                 "exchange")
                         _time.sleep(0)
-                    wait += _time.perf_counter() - w0  # lint: allow-wall-clock
+                    # one record a chunk: how long its collective was
+                    # still in flight when the consumer came for it
+                    w = _time.perf_counter() - w0  # lint: allow-wall-clock
+                    wait += w
+                    if stats is not None:
+                        stats.record("exchangeFabricIciWait", w0 * 1e9,
+                                     w * 1e9)
                     cols = {names[j]: b.columns[prod[j]]
                             for j in range(len(names))}
                     yield Batch(cols, b.mask)
@@ -1186,10 +1194,8 @@ def _device_reader(sources: List[StageInfo], consumer_task: int, rnode,
             FABRIC_METRICS.record("ici", compute_wall_s=drain,
                                   wait_wall_s=wait)
             if stats is not None:
-                stats.add("exchangeFabricIciDrainWallNanos",
-                          drain * 1e9, "NANO")
-                stats.add("exchangeFabricIciWaitWallNanos",
-                          wait * 1e9, "NANO")
+                stats.record("exchangeFabricIciDrain", drain0 * 1e9,
+                             drain * 1e9, -1)
     return read
 
 

@@ -150,8 +150,8 @@ class MicroBatcher:
         # batcher that saves no launch still shows what its window costs
         owner = current_stats()
         if owner is not None and g.launched_ns:
-            owner.add("servingBatchWaitWallNanos",
-                      g.launched_ns - slot.enqueued_ns, "NANO")
+            owner.record("servingBatchWait", slot.enqueued_ns,
+                         g.launched_ns - slot.enqueued_ns)
             owner.add("servingBatchOccupancy", len(g.slots))
         if slot.result is None:
             if slot.batched:

@@ -77,7 +77,7 @@ class PlanCache:
             wait = time.perf_counter_ns() - t0  # lint: allow-wall-clock
             owner = current_stats()
             if owner is not None:
-                owner.add("compilerCheckoutWaitWallNanos", wait, "NANO")
+                owner.record("compilerCheckoutWait", t0, wait)
             ent = self._entries.get(key)
             if ent is None:
                 self.misses += 1

@@ -179,6 +179,13 @@ def format_analyze_footer(runtime_stats, profile_dir: str = None) -> str:
         lines.append(f"Driver CPU/wall: {cpu['sum'] / 1e6:,.1f}ms / "
                      f"{wall['sum'] / 1e6:,.1f}ms "
                      f"({cpu['sum'] / wall['sum']:.2f} busy)")
+    if "queryWall.device" in rs:
+        # the analysed run's wall, every instant of it charged to one
+        # layer (telemetry/query_wall.py)
+        from ..telemetry.query_wall import STATES
+        lines.append("Query wall: " + ", ".join(
+            f"{state} {rs[f'queryWall.{state}']['sum'] / 1e6:,.1f}ms"
+            for state in STATES if rs[f"queryWall.{state}"]["sum"]))
     sp = rs.get("spillBytes")
     if sp and sp.get("sum"):
         # two-tier spill: bytes staged to the host tier, the fraction of

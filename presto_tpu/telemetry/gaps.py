@@ -91,6 +91,63 @@ def nesting(spans: List[Span]) -> List[Tuple[str, str]]:
     return pairs
 
 
+def pair_records(spans: List[Span], records, tolerance_ns: int = 200_000,
+                 window_ns: int = 1_000_000) -> dict:
+    """Lay a query's own records (telemetry/query_wall.py `records_of`:
+    unix nanoseconds) on a capture's axis, which counts from its
+    session's start.  The bridge is the span itself: every record of a
+    `RuntimeStats.span`, a launch or a host sync has a `presto:<name>`
+    annotation of the same extent.  The longest annotation proposes the
+    offset (against each record of its name and duration); the proposal
+    that places most annotations on a record of their name, duration
+    (within `tolerance_ns`) and start (within `window_ns`) wins, and each
+    annotation is paired with the nearest such record, in time order.
+    Returns `offset_ns` (records minus capture, the median over the
+    pairs), `spread_ns` (greatest minus least offset of a pair), `pairs`
+    as (span, record, offset) and the annotations left `unpaired`:
+    add `offset_ns` to the capture's `XLA Ops` and they are on the
+    partition's axis."""
+    by_name: Dict[str, list] = {}
+    for r in records:
+        by_name.setdefault(r[1], []).append(r)
+    for items in by_name.values():
+        items.sort(key=lambda r: r[2])
+    spans = sorted((s for s in spans if s[3] in by_name),
+                   key=lambda s: s[1])
+
+    def place(offset: int, keep: bool):
+        pairs, used = [], set()
+        for s in spans:
+            length, best = s[2] - s[1], None
+            for r in by_name[s[3]]:
+                off = r[2] - s[1]
+                if id(r) in used or abs(off - offset) > window_ns \
+                        or abs((r[3] - r[2]) - length) > tolerance_ns:
+                    continue
+                if best is None or abs(off - offset) < abs(best[2] - offset):
+                    best = (s, r, off)
+            if best is not None:
+                used.add(id(best[1]))
+                pairs.append(best)
+        return pairs if keep else len(pairs)
+
+    if not spans:
+        return {"offset_ns": None, "spread_ns": 0, "pairs": [],
+                "unpaired": 0}
+    longest = max(spans, key=lambda s: s[2] - s[1])
+    proposals = [r[2] - longest[1] for r in by_name[longest[3]]
+                 if abs((r[3] - r[2]) - (longest[2] - longest[1]))
+                 <= tolerance_ns]
+    if not proposals:
+        return {"offset_ns": None, "spread_ns": 0, "pairs": [],
+                "unpaired": len(spans)}
+    pairs = place(max(proposals, key=lambda o: place(o, False)), True)
+    offsets = sorted(p[2] for p in pairs)
+    return {"offset_ns": offsets[len(offsets) // 2],
+            "spread_ns": offsets[-1] - offsets[0], "pairs": pairs,
+            "unpaired": len(spans) - len(pairs)}
+
+
 def _union(intervals):
     merged: List[List[int]] = []
     for start, end in sorted(intervals):

@@ -181,6 +181,8 @@ class HistoryEventListener(EventListener):
     callback lets the server enrich records with state the event does
     not carry (profiler trace dir, query_info_extra)."""
 
+    reads_runtime_stats = False
+
     def __init__(self, store: QueryHistoryStore, extra_fields=None):
         self.store = store
         self._extra_fields = extra_fields

@@ -25,6 +25,7 @@ name, {traces, trace_s, loads, load_s, true_compiles}: served under
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict
 
 from ..utils.runtime_stats import current_stats
@@ -97,16 +98,23 @@ class ProgramTable:
 PROGRAMS = ProgramTable()
 
 
+def _record(s, name: str, seconds: float, count: str = "") -> None:
+    """JAX reports a duration when it is over: the interval ended now, on
+    this thread; nobody measured its CPU time (-1)."""
+    nanos = int(seconds * 1e9)
+    s.record(name, time.perf_counter_ns() - nanos, nanos, -1, count)
+
+
 def _on_duration(event: str, seconds: float, **kw) -> None:
     if event == TRACE_EVENT:
         PROGRAMS.traced(program_name(kw.get("fun_name")), seconds)
         s = current_stats()
         if s is not None:
-            s.add_wall(seconds * 1e9, "jaxTraceWallNanos", count="jaxTraces")
+            _record(s, "jaxTrace", seconds, "jaxTraces")
     elif event == LOWER_EVENT:
         s = current_stats()
         if s is not None:
-            s.add_wall(seconds * 1e9, "jaxLowerWallNanos")
+            _record(s, "jaxLower", seconds)
     elif event == COMPILE_EVENT:
         true_compile = not getattr(_tls, "hits", 0)
         _tls.hits = 0
@@ -114,8 +122,7 @@ def _on_duration(event: str, seconds: float, **kw) -> None:
                         true_compile)
         s = current_stats()
         if s is not None:
-            s.add_wall(seconds * 1e9, "jaxBackendCompileWallNanos",
-                       count="jaxBackendCompiles")
+            _record(s, "jaxBackendCompile", seconds, "jaxBackendCompiles")
             if true_compile:
                 s.add("jaxTrueCompiles", 1)
 

@@ -9,13 +9,27 @@ The analog of the reference's fine-grained engine profiling (§5.1):
     way SqlQueryExecution.java:556-614 wraps analysis/optimization/
     fragmentation in recordWallAndCpuTime.
 
-  * `RuntimeStats.span(name, **attrs)` does three things at once: adds
-    `<name>WallNanos` to the map; opens
+  * `RuntimeStats.span(name, **attrs)` opens
     `jax.profiler.TraceAnnotation("presto:" + name, ...)`, which costs
     under a microsecond with no profiler session and otherwise lands on
-    the SAME timeline as the device's `XLA Ops` (the shared clock); and,
-    only when the owner carries a recording tracer, appends a `Span`
-    with its real interval and the enclosing span as parent.
+    the SAME timeline as the device's `XLA Ops`, and closes through ONE
+    path (`RuntimeStats.close_span`, shared with `host_get`, `named_jit`
+    and every interval measured elsewhere, `RuntimeStats.record`): it
+    adds `<name>WallNanos` to the map, keeps the interval as one record
+    of the owner's bounded timeline (thread, name, start, wall, the
+    thread's own CPU time: recordWallAndCpuTime's pair; a span reads
+    the CPU clock at both ends, a launch leaves its CPU time to the
+    span around it, a blocking sync has none) and, only when
+    the owner carries a recording tracer, hands it on as a `Span` with
+    the enclosing span as parent.
+
+  * The timeline is what telemetry/query_wall.py partitions a query's
+    wall from.  Always on, at most MAX_SPANS_PER_TRACE records an owner
+    (later ones are counted, not kept).  One clock: a record leaves its
+    owner (`timeline()`) in unix microseconds, `perf_counter_ns` plus
+    the anchor this process took once at import, so the records of a
+    coordinator and of its workers on one host lie on one axis (across
+    hosts the clocks are NTP's).
 
   * A thread-local owner (`RuntimeStats.activate`, `current_stats`) lets
     deep code find the query's or task's stats without new arguments:
@@ -33,16 +47,31 @@ from __future__ import annotations
 import functools
 import threading
 import time
+from array import array
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 NANO = 1_000_000_000
-# spans one tracer keeps (a task of ~900 launches records one span each);
-# beyond it spans are dropped and counted in `spansDropped`
+# spans one tracer keeps, and records one owner's timeline keeps (a task
+# of ~900 launches records one each); beyond it they are dropped and
+# counted (`spansDropped`, `queryWallIntervalsDropped`)
 MAX_SPANS_PER_TRACE = 4096
+# integers a record takes where a timeline leaves its owner: thread ident,
+# index into the timeline's name table, start, wall, the thread's CPU time
+RECORD_WIDTH = 5
 
 _perf_ns = time.perf_counter_ns
+_cpu_ns = time.thread_time_ns
+_ident = threading.get_ident
+# the one clock: `perf_counter_ns() + CLOCK_ANCHOR_NS` is unix
+# nanoseconds, the anchor taken once a process
+CLOCK_ANCHOR_NS = time.time_ns() - _perf_ns()
+
+
+def unix_ns() -> int:
+    """Now, on the clock a timeline leaves its owner in."""
+    return _perf_ns() + CLOCK_ANCHOR_NS
 
 
 @dataclass
@@ -116,7 +145,8 @@ class _SpanScope:
     based one costs a microsecond more on paths taken ~900 times a
     query)."""
 
-    __slots__ = ("stats", "name", "attrs", "t0", "ann", "rec", "prev")
+    __slots__ = ("stats", "name", "attrs", "t0", "c0", "ann", "rec",
+                 "prev")
 
     def __init__(self, stats: "RuntimeStats", name: str, attrs: dict):
         self.stats = stats
@@ -124,7 +154,11 @@ class _SpanScope:
         self.attrs = attrs
 
     def __enter__(self):
+        # the CPU clock is read outside the annotation and the wall
+        # clock inside it, last and first: a record and its annotation
+        # are of one extent, to the annotation's own cost
         s = self.stats
+        self.c0 = _cpu_ns()
         self.ann = _annotate("presto:" + self.name, s.ids, self.attrs)
         self.rec = None
         if s.tracer is not None:
@@ -138,13 +172,14 @@ class _SpanScope:
         return self
 
     def __exit__(self, *exc):
-        dt = _perf_ns() - self.t0
-        self.stats.add(self.name + "WallNanos", dt, "NANO")
-        rec = self.rec
-        if rec is not None:
-            rec.end = rec.start + dt / NANO
-            _tls.span = self.prev
+        t0 = self.t0
+        dt = _perf_ns() - t0
         self.ann.__exit__(*exc)
+        cpu = _cpu_ns() - self.c0
+        if self.rec is not None:
+            _tls.span = self.prev
+        self.stats.close_span(self.name, t0, dt, cpu,
+                              (self.name + "WallNanos",), rec=self.rec)
         return False
 
 
@@ -163,6 +198,13 @@ class RuntimeStats:
         self.scope = scope
         self.root = root
         self.ids = ids
+        # the timeline: (thread ident, name, start on perf_counter_ns's
+        # clock, wall, CPU) a record; records past the bound; and the
+        # timelines merged in (a task's, a batch's), each under its
+        # source's label and never summed
+        self._rows: List[tuple] = []
+        self._dropped = 0
+        self._merged: List[Tuple[str, dict]] = []
 
     def add(self, name: str, value: float, unit: str = "NONE") -> None:
         with self._lock:
@@ -171,31 +213,93 @@ class RuntimeStats:
                 m = self._metrics[name] = Metric(unit)
             m.add(value)
 
-    def add_wall(self, nanos: float, *walls: str, count: str = "") -> None:
-        """`nanos` into every key of `walls` and +1 into the counter
-        `count`, under one lock acquisition: the whole update of a
-        `named_jit` call, a `host_get` or a JAX event."""
+    def close_span(self, name: str, t0: int, wall: int, cpu: int = 0,
+                   walls: Tuple[str, ...] = (), count: str = "",
+                   attrs: Optional[dict] = None, rec=None) -> None:
+        """THE close path of every span, launch, sync and interval
+        measured elsewhere: `wall` nanoseconds into every key of `walls`
+        and +1 into the counter `count`, one record (`name`, begun at
+        `t0` on perf_counter_ns's clock, `cpu` nanoseconds of the calling
+        thread's own CPU time; -1 where nobody measured it) onto the
+        timeline, all under one lock acquisition; then the span to a
+        recording tracer: `rec` (opened by `open_span`) gets its end,
+        anything else is handed over as a span that ended now."""
         with self._lock:
-            for name in walls:
-                m = self._metrics.get(name)
+            metrics = self._metrics
+            for key in walls:
+                m = metrics.get(key)
                 if m is None:
-                    m = self._metrics[name] = Metric("NANO")
-                m.add(nanos)
+                    m = metrics[key] = Metric("NANO")
+                m.add(wall)
             if count:
-                m = self._metrics.get(count)
+                m = metrics.get(count)
                 if m is None:
-                    m = self._metrics[count] = Metric("NONE")
+                    m = metrics[count] = Metric("NONE")
                 m.add(1)
+            rows = self._rows
+            if len(rows) < MAX_SPANS_PER_TRACE:
+                rows.append((_ident(), name, t0, wall, cpu))
+            else:
+                self._dropped += 1
+        tracer = self.tracer
+        if tracer is not None:
+            if rec is not None:
+                rec.end = rec.start + wall / NANO
+            else:
+                tracer.closed_span(name, self, wall, attrs or {})
+
+    def record(self, name: str, t0: int, wall: float, cpu: int = 0,
+               count: str = "", **attrs) -> None:
+        """An interval measured elsewhere (a queue's wait taken from two
+        clock readings, a duration JAX reports after the fact): it began
+        at `t0` on perf_counter_ns's clock and lasted `wall` nanoseconds.
+        `<name>WallNanos` and a record, as a span's exit gives."""
+        self.close_span(name, int(t0), int(wall), cpu,
+                        (name + "WallNanos",), count, attrs)
 
     def span(self, name: str, **attrs) -> _SpanScope:
-        """recordWallAndCpuTime analog (wall only; CPU time is not
-        meaningful for device-side work): `<name>WallNanos`, a
-        `presto:<name>` profiler annotation of the same extent and, with
+        """recordWallAndCpuTime analog: `<name>WallNanos`, a
+        `presto:<name>` profiler annotation of the same extent, a record
+        of the interval with the thread's own CPU time over it and, with
         a recording tracer, a Span."""
         return _SpanScope(self, name, attrs)
 
-    # the name every caller used before spans existed; keys unchanged
-    record_wall = span
+    # -- the timeline -----------------------------------------------------
+    def timeline(self) -> dict:
+        """This owner's own records as they leave it: `names` (the table
+        the rows index), `rows` (flat, RECORD_WIDTH integers a record:
+        thread ident, name index, start in unix microseconds, wall and
+        CPU microseconds; CPU -1 = not measured) and `dropped`."""
+        with self._lock:
+            records = list(self._rows)
+            dropped = self._dropped
+        names: Dict[str, int] = {}
+        rows: List[int] = []
+        for ident, name, t0, wall, cpu in records:
+            rows += (ident, names.setdefault(name, len(names)),
+                     (t0 + CLOCK_ANCHOR_NS) // 1000, wall // 1000,
+                     cpu // 1000 if cpu > 0 else cpu)
+        return {"names": list(names), "rows": rows, "dropped": dropped}
+
+    def timelines(self, source: str = "") -> List[Tuple[str, dict]]:
+        """(source label, timeline) of this owner's own records and of
+        every timeline merged into it.  The label keeps one source's
+        thread idents apart from another's."""
+        with self._lock:
+            merged = list(self._merged)
+        return [(source, self.timeline())] + [
+            (f"{source}/{label}" if source else label, t)
+            for label, t in merged]
+
+    def add_timeline(self, source: str, timeline: Optional[dict]) -> None:
+        """Keep a timeline recorded elsewhere (a task's, from its
+        TaskInfo) beside this owner's own: concatenated, never summed."""
+        if timeline and (timeline.get("rows") or timeline.get("dropped")):
+            # (kept until the query's wall is reduced, which may be
+            # never: 8 bytes an integer, not a Python object each)
+            timeline = dict(timeline, rows=array("q", timeline["rows"]))
+            with self._lock:
+                self._merged.append((source, timeline))
 
     @contextmanager
     def activate(self, parent_span: Optional[str] = None):
@@ -213,15 +317,28 @@ class RuntimeStats:
             _tls.stats = prev
             _tls.span = prev_span
 
-    def merge(self, other: "RuntimeStats") -> None:
+    def release_timelines(self) -> None:
+        """Forget every record (the query's partition has been reduced
+        from them and a finished query is kept for its QueryInfo)."""
+        with self._lock:
+            self._rows = []
+            self._merged = []
+
+    def merge(self, other: "RuntimeStats", source: str = "") -> None:
+        """Sum `other`'s map into this one and keep its timelines beside
+        this owner's own, under `source`."""
         with other._lock:
             items = list(other._metrics.items())
+        lines = other.timelines(
+            source or str(other.ids.get("task_id", "merged")))
         with self._lock:
             for name, m in items:
                 mine = self._metrics.get(name)
                 if mine is None:
                     mine = self._metrics[name] = Metric(m.unit)
                 mine.merge(m)
+        for label, t in lines:
+            self.add_timeline(label, t)
 
     def merge_dict(self, other: Optional[Dict[str, dict]]) -> None:
         """Merge the `to_dict()` form (a task's `runtimeStats` as TaskInfo
@@ -264,21 +381,20 @@ def host_get(x, why: str):
     count: syncs there) in the thread's owner."""
     import jax
     s = getattr(_tls, "stats", None)
-    ann = None if s is None else _annotate("presto:hostSync", s.ids,
-                                           {"why": why})
-    t0 = _perf_ns()
+    if s is not None:
+        ann = _annotate("presto:hostSync", s.ids, {"why": why})
+        t0 = _perf_ns()
     try:
-        out = jax.device_get(x)  # lint: allow-host-sync
+        return jax.device_get(x)  # lint: allow-host-sync
     finally:
-        if ann is not None:
+        if s is not None:
             dt = _perf_ns() - t0
             ann.__exit__(None, None, None)
-    if s is not None:
-        s.add_wall(dt, "hostSyncWaitWallNanos", "hostSync." + why,
-                   count="hostSyncs")
-        if s.tracer is not None:
-            s.tracer.closed_span("hostSync", s, dt, {"why": why})
-    return out
+            # a blocked thread burns no CPU time: 0, unread (the thread
+            # CPU clock is a system call, 6 us on the chip's host)
+            s.close_span("hostSync", t0, dt, 0,
+                         ("hostSyncWaitWallNanos", "hostSync." + why),
+                         "hostSyncs", {"why": why})
 
 
 class NamedJit:
@@ -302,15 +418,16 @@ class NamedJit:
                         {"program": self.name})
         t0 = _perf_ns()
         try:
-            out = self._jit(*args, **kwargs)
+            return self._jit(*args, **kwargs)
         finally:
             dt = _perf_ns() - t0
             ann.__exit__(None, None, None)
-        s.add_wall(dt, "pipelineDispatchWallNanos", count="pipelineLaunches")
-        if s.tracer is not None:
-            s.tracer.closed_span("pipelineDispatch", s, dt,
-                                 {"program": self.name})
-        return out
+            # a launch's CPU time is its enclosing span's (-1: unread;
+            # half a join query's records are launches, and the thread
+            # CPU clock is a 6 us system call on the chip's host)
+            s.close_span("pipelineDispatch", t0, dt, -1,
+                         ("pipelineDispatchWallNanos",),
+                         "pipelineLaunches", {"program": self.name})
 
     def __getattr__(self, attr):
         return getattr(self._jit, attr)

@@ -203,8 +203,12 @@ class RemoteTask:
         return headers
 
     def update(self, request: TaskUpdateRequest,
-               deadline_ms: Optional[float] = None) -> TaskStatus:
-        body = json.dumps(request.to_dict()).encode()
+               deadline_ms: Optional[float] = None,
+               body: Optional[bytes] = None) -> TaskStatus:
+        """POST the request (`body`: its JSON where the caller has
+        encoded it already)."""
+        if body is None:
+            body = json.dumps(request.to_dict()).encode()
         headers = {"Content-Type": "application/json", **self._headers()}
         if deadline_ms is not None:
             # the query's REMAINING wall budget at dispatch (relative ms,
@@ -655,9 +659,7 @@ class _QueryExecution:
         (reference SqlStageExecution retrying placement on node refusal)."""
         lineage = self.lineage(stage, ti)
         task_id = self.task_id_for(lineage)
-        req = TaskUpdateRequest.make(task_id, ti, stage.fragment,
-                                     self._make_sources(stage, ti),
-                                     stage.spec, session=self.session)
+        req = body = None
         live = self.runner._live_uris()
         preferred = [u for u in live if u not in self.suspects] or live
         worker = preferred[next(self.runner._rr) % len(preferred)]
@@ -667,9 +669,19 @@ class _QueryExecution:
         for cand in candidates:
             task = RemoteTask(cand, task_id, trace_token=self.trace_token)
             try:
-                # one POST /v1/task; summed over a query's tasks
+                # one POST /v1/task, its request's encoding included
+                # (the fragment's JSON, once whatever worker takes it);
+                # summed over a query's tasks
                 with self.stats.span("schedCreateTasks"):
-                    task.update(req, deadline_ms=self._deadline_ms())
+                    if body is None:
+                        with self.stats.span("schedTaskEncode"):
+                            req = TaskUpdateRequest.make(
+                                task_id, ti, stage.fragment,
+                                self._make_sources(stage, ti), stage.spec,
+                                session=self.session)
+                            body = json.dumps(req.to_dict()).encode()
+                    task.update(req, deadline_ms=self._deadline_ms(),
+                                body=body)
             except urllib.error.HTTPError as e:
                 if e.code != 503:
                     raise
@@ -977,7 +989,8 @@ class _QueryExecution:
         """Task -> query roll-up, one TaskInfo fetch per task AFTER the
         drain: merges every task's `runtimeStats` into the query's
         (RuntimeStats.merge_dict, the same function EXPLAIN ANALYZE's
-        footer uses) and returns the cluster-wide memory peak, the sum of
+        footer uses), keeps its `runtimeTimeline` beside the query's own
+        records (telemetry/query_wall.py) and returns the cluster-wide memory peak, the sum of
         per-task memory-pool peaks (reference
         peakTotalMemoryReservation), so admission history seeding records
         what the distributed run actually reserved instead of 0."""
@@ -991,6 +1004,10 @@ class _QueryExecution:
                 continue
             total += int(stats.get("peakTotalMemoryInBytes", 0) or 0)
             self.stats.merge_dict(stats.get("runtimeStats"))
+            # the maps are summed; the task's records are kept beside
+            # the query's own, for the partition of its wall
+            self.stats.add_timeline(t.task_id,
+                                    stats.get("runtimeTimeline"))
         return total
 
     def close(self) -> None:
@@ -1121,6 +1138,8 @@ class HttpQueryRunner(LocalQueryRunner):
         footer = ""
         if ast.analyze:
             from ..telemetry import profile_capture
+            from ..utils.runtime_stats import unix_ns
+            began = unix_ns()
             root = self._build_stages(subplan)
             qid = (f"q{next(_query_counter)}_"
                    f"{int(time.time() * 1000) % 100000}")
@@ -1154,9 +1173,12 @@ class HttpQueryRunner(LocalQueryRunner):
             # stats
             for st in snapshot["stages"]:
                 for t in st["tasks"]:
-                    execution.stats.merge_dict(
-                        (t.get("stats") or {}).get("runtimeStats"))
-            merged_rs = execution.stats.to_dict()
+                    t_stats = t.get("stats") or {}
+                    execution.stats.merge_dict(t_stats.get("runtimeStats"))
+                    execution.stats.add_timeline(
+                        t.get("taskId", ""), t_stats.get("runtimeTimeline"))
+            from ..telemetry.query_wall import with_partition
+            merged_rs = with_partition(execution.stats, began)
             footer = format_analyze_footer(merged_rs,
                                            profile_dir=trace_dir)
         text = format_subplan(subplan, stats)
@@ -1168,11 +1190,13 @@ class HttpQueryRunner(LocalQueryRunner):
     # -- execution --------------------------------------------------------
     def execute(self, sql: str, trace_token: str = "") -> QueryResult:
         from ..sql import parser as A
-        from ..utils.runtime_stats import current_stats
+        from ..utils.runtime_stats import current_stats, unix_ns
         qid = f"q{next(_query_counter)}_{int(time.time() * 1000) % 100000}"
         # the statement executor's stats when it set one (the query's
         # RuntimeStats in QueryInfo), else this execution's own
-        stats = current_stats() or RuntimeStats(query_id=qid)
+        owner = current_stats()
+        stats = owner or RuntimeStats(query_id=qid)
+        began = unix_ns()
         try:
             with stats.span("queryParse"):
                 ast = A.parse_sql(sql)
@@ -1199,6 +1223,9 @@ class HttpQueryRunner(LocalQueryRunner):
             except Exception:   # noqa: BLE001 — stats are best-effort
                 pass
             result.runtime_stats = stats.to_dict()
+            if owner is None:
+                from ..exec.runner import _close_query
+                _close_query(result, stats, began)
             return result
         except Exception:
             self.queries_failed += 1

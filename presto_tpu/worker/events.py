@@ -79,6 +79,13 @@ class TaskCompletedEvent:
 class EventListener:
     """Listener SPI (EventListener.java): override any subset."""
 
+    # a listener that never looks at a completed event's `runtime_stats`
+    # says so: while no other is registered, a finished query's wall is
+    # partitioned (telemetry/query_wall.py, a few milliseconds of
+    # Python) at the first read of its QueryInfo, not behind its last
+    # response
+    reads_runtime_stats = True
+
     def query_created(self, event: QueryCreatedEvent) -> None:
         pass
 
@@ -140,6 +147,10 @@ class EventListenerManager:
             except Exception:   # noqa: BLE001 — listener isolation
                 self.dispatch_errors += 1
                 traceback.print_exc()
+
+    def reads_runtime_stats(self) -> bool:
+        return any(getattr(listener, "reads_runtime_stats", True)
+                   for listener in self._listeners)
 
     def query_created(self, event: QueryCreatedEvent) -> None:
         self._fire("query_created", event)

@@ -152,11 +152,13 @@ class ExchangeMetrics:
             if self.buffered_bytes > self.buffered_bytes_peak:
                 self.buffered_bytes_peak = self.buffered_bytes
 
-    def on_client_close(self, wait_wall_s: float, drain_wall_s: float
-                        ) -> None:
+    def on_wait(self, wall_s: float) -> None:
+        with self._lock:
+            self.wait_wall_s += wall_s
+
+    def on_client_close(self, drain_wall_s: float) -> None:
         with self._lock:
             self.clients += 1
-            self.wait_wall_s += wait_wall_s
             self.drain_wall_s += drain_wall_s
 
     def snapshot(self) -> dict:
@@ -184,7 +186,7 @@ def _pull_rounds(location: str,
                  sleep: Callable[[float], None] = time.sleep,
                  max_response_bytes: Optional[int] = None,
                  acknowledge: Optional[Callable[[str], None]] = None,
-                 on_round: Optional[Callable[[float], None]] = None,
+                 on_round: Optional[Callable[[int, int, int], None]] = None,
                  start_token: int = 0,
                  park_on_failure: bool = False,
                  on_token: Optional[Callable[[int], None]] = None,
@@ -214,7 +216,7 @@ def _pull_rounds(location: str,
         if should_abort is not None:
             should_abort()
         url = f"{location}/{token}?maxWaitMs={int(DEFAULT_MAX_WAIT_S * 1000)}"
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
         try:
             with _request(url, headers=extra) as resp:
                 complete = resp.headers.get(
@@ -267,7 +269,10 @@ def _pull_rounds(location: str,
                 max_error_duration_s, e, sleep=sleep)
             continue
         if on_round is not None:
-            on_round(time.perf_counter() - t0)
+            # a round that answered: its start, wall and the puller's own
+            # CPU time, nanoseconds
+            on_round(t0, time.perf_counter_ns() - t0,
+                     time.thread_time_ns() - c0)
         if body:
             yield body
         if next_token != token:
@@ -377,10 +382,9 @@ class ExchangeClient:
         self._error: Optional[BaseException] = None
         self._closed = False
         self._stop_event = threading.Event()
-        # client-level counters (flushed into `stats` at close)
-        self._pull_wall = 0.0
-        self._decode_wall = 0.0
-        self._wait_wall = 0.0
+        # client-level counters (flushed into `stats` at close); every
+        # pull round, page decode and consumer wait is a record of
+        # `stats` as it ends (`exchangeClientPull/Decode/Wait`)
         self._pages = 0
         self._bytes = 0
         self._uncompressed = 0
@@ -447,10 +451,10 @@ class ExchangeClient:
         if self._stop_event.wait(delay):
             raise _Stop()
 
-    def _on_round(self, wall_s: float) -> None:
-        with self._cond:
-            self._pull_wall += wall_s
-        EXCHANGE_METRICS.on_response(wall_s)
+    def _on_round(self, t0: int, wall: int, cpu: int) -> None:
+        if self._stats is not None:
+            self._stats.record("exchangeClientPull", t0, wall, cpu)
+        EXCHANGE_METRICS.on_response(wall / 1e9)
 
     def _note_token(self, location: str, token: int) -> None:
         with self._cond:
@@ -507,15 +511,17 @@ class ExchangeClient:
         pos, n = 0, len(view)
         while pos < n:
             _, _, uncompressed, _, _ = _PAGE_HEADER.unpack_from(view, pos)
-            t0 = time.perf_counter()
+            t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
             page, nxt = deserialize_page(view, pos, codec=self._codec)
-            dt = time.perf_counter() - t0
+            dt = time.perf_counter_ns() - t0
+            if self._stats is not None:
+                self._stats.record("exchangeClientDecode", t0, dt,
+                                   time.thread_time_ns() - c0)
             nbytes = nxt - pos
             pos = nxt
             with self._cond:
-                self._decode_wall += dt
                 self._uncompressed += uncompressed
-            EXCHANGE_METRICS.on_page(nbytes, uncompressed, dt)
+            EXCHANGE_METRICS.on_page(nbytes, uncompressed, dt / 1e9)
             self._offer(page, nbytes)
 
     def _offer(self, page: Page, nbytes: int) -> None:
@@ -568,13 +574,20 @@ class ExchangeClient:
         try:
             while True:
                 with self._cond:
-                    while (not self._queue and self._error is None
-                           and self._remaining > 0 and not self._closed):
-                        if self._should_abort is not None:
-                            self._should_abort()
-                        t0 = time.perf_counter()
-                        self._cond.wait(0.1)
-                        self._wait_wall += time.perf_counter() - t0
+                    t0 = 0
+                    try:
+                        while (not self._queue and self._error is None
+                               and self._remaining > 0
+                               and not self._closed):
+                            if self._should_abort is not None:
+                                self._should_abort()
+                            t0 = t0 or time.perf_counter_ns()
+                            self._cond.wait(0.1)
+                    finally:
+                        if t0:
+                            # one record a stretch the consumer slept
+                            # through, however many times it looked up
+                            self._note_wait(t0)
                     if self._error is not None:
                         raise self._error
                     if self._queue:
@@ -590,6 +603,12 @@ class ExchangeClient:
         finally:
             self.close()
 
+    def _note_wait(self, t0: int) -> None:
+        wall = time.perf_counter_ns() - t0
+        if self._stats is not None:
+            self._stats.record("exchangeClientWait", t0, wall)
+        EXCHANGE_METRICS.on_wait(wall / 1e9)
+
     def close(self) -> None:
         with self._cond:
             if self._closed:
@@ -604,17 +623,10 @@ class ExchangeClient:
         if leftover:
             EXCHANGE_METRICS.buffered_delta(-leftover)
         drain_wall = time.perf_counter() - self._t0
-        EXCHANGE_METRICS.on_client_close(self._wait_wall, drain_wall)
+        EXCHANGE_METRICS.on_client_close(drain_wall)
         if self._stats is not None:
-            nano = 1e9
-            self._stats.add("exchangeClientPullWallNanos",
-                            self._pull_wall * nano, "NANO")
-            self._stats.add("exchangeClientDecodeWallNanos",
-                            self._decode_wall * nano, "NANO")
-            self._stats.add("exchangeClientWaitWallNanos",
-                            self._wait_wall * nano, "NANO")
             self._stats.add("exchangeClientDrainWallNanos",
-                            drain_wall * nano, "NANO")
+                            drain_wall * 1e9, "NANO")
             self._stats.add("exchangeClientBytes", self._bytes, "BYTE")
             self._stats.add("exchangeClientUncompressedBytes",
                             self._uncompressed, "BYTE")

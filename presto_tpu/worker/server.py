@@ -865,6 +865,7 @@ collapse}}td,th{{border:1px solid #ccc;padding:4px 8px;text-align:left}}
             # draining node refuses new work; the coordinator reroutes
             self._send(503, {"error": "node is shutting down"})
             return
+        t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
         body = self._body_json()
         if "outputIds" in body or "extraCredentials" in body:
             # reference-shaped request (HttpRemoteTask.java:883-936)
@@ -872,6 +873,7 @@ collapse}}td,th{{border:1px solid #ccc;padding:4px 8px;text-align:left}}
             update = from_reference_update(groups["task"], body)
         else:
             update = TaskUpdateRequest.from_dict(body)
+        t1, c1 = time.perf_counter_ns(), time.thread_time_ns()
         # X-Presto-Task-Deadline carries the query's REMAINING execution
         # budget in ms (no cross-node clock sync needed): the TaskManager
         # reaper and the pipeline drain loop both enforce it
@@ -882,11 +884,22 @@ collapse}}td,th{{border:1px solid #ccc;padding:4px 8px;text-align:left}}
                 deadline_ms = float(raw_deadline)
             except ValueError:
                 deadline_ms = None
-        status = self.server_ref.task_manager.create_or_update(
-            update, deadline_ms=deadline_ms)
+        manager = self.server_ref.task_manager
+        status = manager.create_or_update(update, deadline_ms=deadline_ms)
         from .thrift import task_status_to_thrift
         self._send_negotiated(200, status.to_dict(),
                               thrift_encoder=task_status_to_thrift)
+        if update.fragment_b64:
+            # a creation, seen from its handler: the body to a
+            # TaskUpdateRequest, then the TaskManager's create to the
+            # answer on the wire; into the new task's own stats, which
+            # its thread may own already
+            task = manager.tasks.get(update.task_id)
+            if task is not None:
+                task.stats.record("taskCreateDecode", t0, t1 - t0, c1 - c0)
+                task.stats.record("taskCreateStart", t1,
+                                  time.perf_counter_ns() - t1,
+                                  time.thread_time_ns() - c1)
 
     def do_task_status(self, groups, query):
         task = self.server_ref.task_manager.get(groups["task"])
@@ -954,6 +967,8 @@ class _QuerySpanListener:
     """EventListener bridging terminal queries to the telemetry exporter
     (a plain class with the listener surface: the manager dispatches by
     method name)."""
+
+    reads_runtime_stats = False
 
     def __init__(self, server: "WorkerServer"):
         self._server = server

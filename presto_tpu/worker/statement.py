@@ -307,6 +307,10 @@ class ManagedQuery:
     # own, a batched lane's share of its launch)
     _result_stats: Optional[dict] = None
     _created_ns: int = field(default_factory=time.perf_counter_ns)
+    _finished_ns: Optional[int] = None
+    # `queryWall.*` (telemetry/query_wall.py): reduced once, when the
+    # query is over and somebody first reads its runtimeStats
+    _wall_keys: Optional[dict] = None
     # observability: the query's trace token (minted at submit or taken
     # from the client's X-Presto-Trace-Token) and the stage/task/operator
     # drill-down captured by the executor for /v1/query/{id}
@@ -344,10 +348,39 @@ class ManagedQuery:
         """QueryInfo `runtimeStats`: the result's map with the query's
         own on top (one owner recorded both when the runner found the
         thread-local, so the union never counts twice)."""
+        return self.runtime_stats_map(partitioned=True)
+
+    def runtime_stats_map(self, partitioned: bool) -> Optional[dict]:
         own = self.rstats.to_dict()
         if not own:
             return self._result_stats
-        return {**(self._result_stats or {}), **own}
+        return {**(self._result_stats or {}), **own,
+                **(self._wall() if partitioned else {})}
+
+    def _wall(self) -> dict:
+        """The partition of this query's wall, created .. finished (what
+        `elapsedTimeMillis` reports), from its own records, its tasks'
+        and those of a runner that kept stats of its own.  Off the
+        query's path: reduced at the first read of a finished query's
+        `runtimeStats` in QueryInfo (for the completed event only where
+        a listener reads it there: `_finish`)."""
+        if self._wall_keys is None and self._finished_ns is not None:
+            from ..telemetry.query_wall import runtime_stats_keys
+            from ..utils.runtime_stats import CLOCK_ANCHOR_NS
+            lines = self.rstats.timelines()
+            src = self._stats_src
+            if src is not None and src is not self.rstats \
+                    and hasattr(src, "timelines"):
+                lines += src.timelines()
+            keys = runtime_stats_keys(
+                lines, self._created_ns + CLOCK_ANCHOR_NS,
+                self._finished_ns + CLOCK_ANCHOR_NS)
+            if self._wall_keys is None:
+                # (two first readers at once: the one that came second
+                # may have read records the first had released already)
+                self._wall_keys = keys
+                self.rstats.release_timelines()
+        return self._wall_keys or {}
 
     def notify_changed(self) -> None:
         with self._changed:
@@ -508,8 +541,8 @@ class DispatchManager:
         q.started_at = time.time()
         q.notify_changed()
         # submit -> an executor thread runs it (admission, thread start)
-        q.rstats.add("statementQueuedWallNanos",
-                     time.perf_counter_ns() - q._created_ns, "NANO")
+        q.rstats.record("statementQueued", q._created_ns,
+                        time.perf_counter_ns() - q._created_ns)
         attempt = 0
         while True:
             try:
@@ -530,6 +563,10 @@ class DispatchManager:
                 q.rows = [[_json_value(v) for v in row]
                           for row in result.rows]
                 q._result_stats = getattr(result, "runtime_stats", None)
+                # a micro-batched launch recorded into stats of its own:
+                # its records belong to every query it served
+                for label, line in getattr(result, "timeline", None) or ():
+                    q.rstats.add_timeline(label, line)
                 q.peak_memory_bytes = int(
                     getattr(result, "peak_memory_bytes", 0) or 0)
                 q.profile_trace_dir = getattr(
@@ -566,6 +603,14 @@ class DispatchManager:
             error = "Query was canceled"   # clients must not see success
         q.error = error
         q.finished_at = time.time()
+        q._finished_ns = time.perf_counter_ns()
+        partitioned = self.events.reads_runtime_stats()
+        if partitioned:
+            # a listener reads the partition in the completed event:
+            # reduced here, so that the event follows `done` as closely
+            # as ever (with the server's own listeners alone it waits
+            # for the first read of the query's QueryInfo)
+            q._wall()
         q.done.set()
         q.notify_changed()
         from .events import QueryCompletedEvent
@@ -578,7 +623,7 @@ class DispatchManager:
             rows=(q.rows_served if q._row_iter is not None
                   else len(q.rows or [])),
             error=error,
-            runtime_stats=q.runtime_stats,
+            runtime_stats=q.runtime_stats_map(partitioned),
             peak_memory_bytes=q.peak_memory_bytes,
             trace_token=q.trace_token,
             resource_group=q.resource_group))

@@ -159,6 +159,11 @@ class TpuTask:
                 "exchangeFabricRequested": getattr(
                     self.config, "exchange_fabric", "auto"),
                 "runtimeStats": self.stats.to_dict(),
+                # the task's records (utils/runtime_stats.py `timeline`),
+                # only once it is over: what the query's roll-up fetches
+                # and telemetry/query_wall.py partitions the wall from
+                **({"runtimeTimeline": self.stats.timeline()}
+                   if self.state in DONE_STATES else {}),
             },
             "pipelines": [{
                 "operators": self.plan_nodes,
@@ -505,8 +510,9 @@ class TpuTask:
         # counting so concurrent scoped tasks compose — is checked against
         # the declared rank order and metered into presto_tpu_lock_*
         import time as _t
-        self.stats.add("taskQueuedWallNanos",
-                       _t.perf_counter_ns() - self._created_ns, "NANO")
+        # created by the handler -> this thread runs it
+        self.stats.record("taskQueued", self._created_ns,
+                          _t.perf_counter_ns() - self._created_ns)
         with self.stats.activate():
             if getattr(ctx.config, "lock_validation", False):
                 with validation_scope():

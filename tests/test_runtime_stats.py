@@ -23,7 +23,7 @@ def test_metric_merge():
 
 def test_record_wall():
     s = RuntimeStats()
-    with s.record_wall("phase"):
+    with s.span("phase"):
         pass
     m = s.get("phaseWallNanos")
     assert m is not None and m.count == 1 and m.sum >= 0

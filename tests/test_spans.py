@@ -50,11 +50,11 @@ def _get_json(url):
 # the primitive
 # ---------------------------------------------------------------------------
 
-def test_span_adds_wall_and_record_wall_is_its_alias():
+def test_span_adds_wall():
     s = RuntimeStats(query_id="q")
     with s.span("phase", why="test"):
         time.sleep(0.002)
-    with s.record_wall("phase"):
+    with s.span("phase"):
         pass
     m = s.get("phaseWallNanos")
     assert m.unit == "NANO" and m.count == 2
@@ -307,6 +307,42 @@ def test_profiler_capture_holds_nested_presto_spans(tmp_path):
     assert reduced["spans"] == len(spans) and reduced["window_s"] > 0
 
 
+def test_records_and_their_annotations_lie_on_one_axis(tmp_path):
+    """The query's own records (unix nanoseconds) against a capture of it
+    (nanoseconds from the session's start): ONE offset fits every pair,
+    so the device's `XLA Ops` and the partition of the wall
+    (telemetry/query_wall.py) can be laid side by side."""
+    from presto_tpu.telemetry.query_wall import records_of
+    r = LocalQueryRunner("sf0.01", config=ExecutionConfig(
+        batch_rows=1 << 13))
+    r.execute(Q6)                                  # warm: capture a run
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        result = r.execute(Q6)
+    finally:
+        jax.profiler.stop_trace()
+    _ops, _programs, spans = gaps.load(str(tmp_path))
+    records, dropped = records_of(result.timeline)
+    assert not dropped
+    paired = gaps.pair_records(spans, records)
+    by_record = {id(rec): span for span, rec, _off in paired["pairs"]}
+    wanted = [rec for rec in records
+              if rec[1] in ("queryExecute", "pipelineDispatch", "hostSync")]
+    assert {rec[1] for rec in wanted} == {
+        "queryExecute", "pipelineDispatch", "hostSync"}
+    for rec in wanted:
+        span = by_record.get(id(rec))
+        assert span is not None, (rec, paired["unpaired"])
+        assert span[3] == rec[1]
+        assert abs((span[2] - span[1]) - (rec[3] - rec[2])) <= 200_000
+    # the shared clock: the offsets of all pairs lie within a millisecond
+    assert len(paired["pairs"]) >= len(wanted) + 2
+    assert paired["spread_ns"] <= 1_000_000, paired["spread_ns"]
+    # the capture counts from its session's start, the records from 1970
+    assert paired["offset_ns"] > 10 ** 18
+    assert gaps.pair_records(spans, [])["offset_ns"] is None
+
+
 # ---------------------------------------------------------------------------
 # the served paths: coordinator -> workers, and the single node
 # ---------------------------------------------------------------------------
@@ -529,3 +565,109 @@ def single_node_info():
 def test_single_node_query_info_holds_every_key(single_node_info, key):
     assert single_node_info["runtimeStats"][key]["count"] >= 1, sorted(
         single_node_info["runtimeStats"])
+
+
+# ---------------------------------------------------------------------------
+# the partition of the wall in QueryInfo, on both served paths
+# (telemetry/query_wall.py; the reduction itself: tests/test_query_wall.py)
+# ---------------------------------------------------------------------------
+
+from presto_tpu.telemetry.query_wall import STATES  # noqa: E402
+from presto_tpu.utils.runtime_stats import RECORD_WIDTH  # noqa: E402
+
+WALL = ["queryWall." + s for s in STATES]
+HOST_STATES = ("pipeline", "exchange", "sched", "plan", "statement")
+PARTITION_KEYS = WALL + ["queryWallCpu." + s for s in STATES[:-1]] + [
+    "queryWallIntervals", "queryWallIntervalsDropped"]
+
+
+def assert_sums_to_elapsed(info):
+    rs = info["runtimeStats"]
+    total_ms = sum(rs[k]["sum"] for k in WALL) / 1e6
+    elapsed = info["queryStats"]["elapsedTimeMillis"]
+    # elapsedTimeMillis is cut to whole milliseconds
+    assert elapsed - 1 <= total_ms <= elapsed + 2, (total_ms, elapsed)
+
+
+@pytest.mark.parametrize("key", PARTITION_KEYS)
+def test_distributed_query_info_carries_the_partition(traced_cluster, key):
+    info = traced_cluster[3]
+    assert info["runtimeStats"][key]["count"] == 1, sorted(
+        info["runtimeStats"])
+    assert info["runtimeStats"][key]["sum"] >= 0
+
+
+def test_distributed_partition_sums_to_elapsed(traced_cluster):
+    info = traced_cluster[3]
+    assert_sums_to_elapsed(info)
+    rs = info["runtimeStats"]
+    assert rs["queryWallIntervalsDropped"]["sum"] == 0
+    assert rs["queryWallIntervals"]["sum"] > 30
+    waits = sum(v["sum"] for k, v in rs.items()
+                if k.startswith("queryWall.wait."))
+    assert waits == rs["queryWall.wait"]["sum"]
+    for state in ("pipeline", "sched", "plan"):
+        assert rs["queryWall." + state]["sum"] > 0, state
+
+
+def test_tasks_carry_their_records_and_no_partition(traced_cluster):
+    info = traced_cluster[3]
+    tasks = [t for st in info["stages"] for t in st["tasks"]]
+    assert len(tasks) == 3
+    n_records = 0
+    for t in tasks:
+        stats = t["stats"]
+        assert not [k for k in stats["runtimeStats"]
+                    if k.startswith("queryWall")]
+        line = stats["runtimeTimeline"]
+        assert len(line["rows"]) % RECORD_WIDTH == 0 and not line["dropped"]
+        assert {"taskQueued", "taskCreateDecode", "taskCreateStart",
+                "pipelineBuild", "pipelineDrain"} <= set(line["names"])
+        n_records += len(line["rows"]) // RECORD_WIDTH
+        # the handler's two spans, in the new task's own stats
+        for key in ("taskCreateDecodeWallNanos", "taskCreateStartWallNanos"):
+            assert stats["runtimeStats"][key]["count"] == 1, key
+    rs = info["runtimeStats"]
+    assert rs["queryWallIntervals"]["sum"] > n_records
+    assert rs["taskCreateStartWallNanos"]["count"] == 3
+    # the fragment's JSON, inside the POST's span
+    assert rs["schedTaskEncodeWallNanos"]["count"] == 3
+    assert rs["schedTaskEncodeWallNanos"]["sum"] \
+        < rs["schedCreateTasksWallNanos"]["sum"]
+
+
+def test_warm_distributed_q6_is_attributed(traced_cluster):
+    from presto_tpu.client import StatementClient
+    coordinator = traced_cluster[0]
+    client = StatementClient(coordinator.uri, schema="sf0.01")
+    client.execute(Q6)                      # builds columns and programs
+    res = client.execute(Q6)
+    info = _get_json(f"{coordinator.uri}/v1/query/{res.query_id}")
+    assert_sums_to_elapsed(info)
+    rs = info["runtimeStats"]
+    whole = sum(rs[k]["sum"] for k in WALL)
+    assert rs["queryWall.unattributed"]["sum"] < max(0.2 * whole, 10e6), {
+        k: rs[k]["sum"] / 1e6 for k in WALL}
+    # host work that burnt CPU: the share is a share
+    host = sum(rs["queryWall." + s]["sum"] for s in HOST_STATES)
+    cpu = sum(rs["queryWallCpu." + s]["sum"] for s in HOST_STATES)
+    assert 0 < cpu <= host * 1.05
+
+
+@pytest.mark.parametrize("key", PARTITION_KEYS)
+def test_single_node_query_info_carries_the_partition(single_node_info, key):
+    assert single_node_info["runtimeStats"][key]["count"] == 1, sorted(
+        single_node_info["runtimeStats"])
+
+
+def test_single_node_partition_sums_to_elapsed(single_node_info):
+    assert_sums_to_elapsed(single_node_info)
+    rs = single_node_info["runtimeStats"]
+    whole = sum(rs[k]["sum"] for k in WALL)
+    # (a 15 ms query: on a loaded machine one late wake-up of a thread,
+    # which no span covers, is milliseconds)
+    assert rs["queryWall.unattributed"]["sum"] < max(0.2 * whole, 10e6)
+    assert rs["queryWall.sched"]["sum"] == rs["queryWall.exchange"]["sum"] == 0
+    # the batcher's window is dead time, and named
+    assert "queryWall.wait.servingBatchWait" in rs or \
+        rs["queryWall.wait"]["sum"] == 0
