@@ -1194,15 +1194,21 @@ def fused_dense_stream(compiler, node: P.PlanNode, chain=None, prep=None,
 
     def count(pos_arr, cnt_arr, aux):
         def step(pc):
-            b, c = prog.make(pc[0], pc[1], aux, expands, leaf_cap,
-                             with_counts=True, cut=cut)
-            # a cut chain's lookup is most of its work (a gather a scanned
-            # row): what it found is kept on the device, 4 bytes a row,
-            # and the write pass does not look again
+            with ops.lookup_paths() as paths:
+                b, c = prog.make(pc[0], pc[1], aux, expands, leaf_cap,
+                                 with_counts=True, cut=cut)
+            # a cut chain's lookup is most of its work: what it found is
+            # kept on the device, 4 bytes a row, and the write pass does
+            # not look again
             found = b.columns[BUILD_ROW].values if cut is not None else ()
-            return jnp.sum(b.mask, dtype=jnp.int32), c, found
-        live, counts, found = jax.lax.map(step, (pos_arr, cnt_arr))
-        return (live, jnp.sum(counts, axis=0)), found
+            # (the chunk has a lookup, every lookup of it read its blocks)
+            blocked = jnp.all(jnp.stack(paths) == ops.LOOKUP_BLOCKED) \
+                if paths else False
+            lookups = jnp.asarray([len(paths) > 0, blocked], jnp.int32)
+            return jnp.sum(b.mask, dtype=jnp.int32), c, lookups, found
+        live, counts, lookups, found = jax.lax.map(step, (pos_arr, cnt_arr))
+        return (live, jnp.sum(counts, axis=0),
+                jnp.sum(lookups, axis=0)), found
 
     try:
         counted = compiler.shared_jit(
@@ -1211,8 +1217,8 @@ def fused_dense_stream(compiler, node: P.PlanNode, chain=None, prep=None,
     except NotImplementedError:     # an expression the chain cannot lower
         compiler._jit_cache[key] = None
         return None
-    (live, totals), found = host_get(counted[0], "chain_dense_counts"), \
-        counted[1]
+    (live, totals, lookups), found = \
+        host_get(counted[0], "chain_dense_counts"), counted[1]
     total = int(live.sum())
     # (8 value bytes + 1 null byte a column: _instrument's estimate; a cut
     # chain's rows have no more columns than the scan and one index)
@@ -1234,6 +1240,9 @@ def fused_dense_stream(compiler, node: P.PlanNode, chain=None, prep=None,
     if rs is not None:
         rs.add("denseStreamChunks", len(chunks))
         rs.add("denseStreamBatches", n_out)
+        if lookups[0]:
+            rs.add("chainLookupChunks", int(lookups[0]))
+            rs.add("chainLookupBlockedChunks", int(lookups[1]))
     if total == 0:
         return iter(())
 

@@ -14,6 +14,8 @@ drivers sit in pipeline.py.
 from __future__ import annotations
 
 import math
+import threading
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 from typing import Callable, Dict, List, Optional, Tuple
@@ -1419,27 +1421,120 @@ def probe_join(batch: Batch, table: BuildTable, probe_keys: List[str],
 # live indices all lie within this many entries of each other reads them
 # from a window sliced out of the table
 GATHER_WINDOW = 1 << 16
+# ... and one whose live indices, LOOKUP_BLOCK rows at a time, lie in two
+# neighbouring LOOKUP_TILE-entry tiles of that window reads no entry by
+# index: each block's two tiles are picked out by a one-hot int8 product on
+# the matrix unit and each row selects its entry by a compare and a select
+# (on the v5e a 64K-row chunk's lookup takes 36 us against the window's
+# 564: PERF.md §6).  A block reads LOOKUP_SPAN entries
+LOOKUP_BLOCK = 256
+LOOKUP_TILE = 128
+LOOKUP_SPAN = 2 * LOOKUP_TILE
+# the ways a `gather_near` can go, as `gather_near_path` reports them
+LOOKUP_GATHER, LOOKUP_WINDOW, LOOKUP_BLOCKED = 0, 1, 2
+
+_LOOKUPS = threading.local()
+
+
+@contextmanager
+def lookup_paths():
+    """The way each `gather_near` traced inside the block went, as traced
+    int32 scalars (LOOKUP_*) in trace order: a program returns them with
+    its outputs, so the host reads them in a fetch it makes anyway."""
+    outer = getattr(_LOOKUPS, "paths", None)
+    _LOOKUPS.paths = []
+    try:
+        yield _LOOKUPS.paths
+    finally:
+        _LOOKUPS.paths = outer
 
 
 def gather_near(table, idx, live):
-    """`table[idx]`, through a GATHER_WINDOW-entry window where the live
-    rows' indices (already clipped to the table) all fall inside one: a
-    batch of a fact table scanned in its key's order, as `lineitem` is in
-    `l_orderkey`'s, probes a stretch of the key space no longer than
-    itself.  Which it is, the indices decide, a batch at a time; rows
-    that are not live read garbage."""
+    """`table[idx]` (an int32 table, the indices already clipped to it) by
+    the cheapest way the live rows' indices allow: a batch of a fact table
+    scanned in its key's order, as `lineitem` is in `l_orderkey`'s, probes
+    a stretch of the key space no longer than itself, and each
+    LOOKUP_BLOCK rows of it a stretch shorter still.  Which it is, the
+    indices decide, a batch at a time (`gather_near_path`); rows that are
+    not live read garbage."""
+    values, path = gather_near_path(table, idx, live)
+    paths = getattr(_LOOKUPS, "paths", None)
+    if paths is not None:
+        paths.append(path)
+    return values
+
+
+def gather_near_path(table, idx, live):
+    """(`gather_near`'s values, the LOOKUP_* way it took): the block-local
+    read where every block's live indices lie in the two tiles that begin
+    at the tile of its least one, else the window where all of them lie in
+    GATHER_WINDOW entries, else the gather."""
     size = table.shape[0]
     if size <= 2 * GATHER_WINDOW:
-        return table[idx]
+        return table[idx], jnp.int32(LOOKUP_GATHER)
     lo = jnp.min(jnp.where(live, idx, size - 1))
     hi = jnp.max(jnp.where(live, idx, 0))
     start = jnp.minimum(lo, size - GATHER_WINDOW)
 
+    def window():
+        return jax.lax.dynamic_slice(table, (start,), (GATHER_WINDOW,))
+
     def near():
-        window = jax.lax.dynamic_slice(table, (start,), (GATHER_WINDOW,))
-        return window[jnp.clip(idx - start, 0, GATHER_WINDOW - 1)]
-    return jax.lax.cond(hi - start < GATHER_WINDOW, near,
-                        lambda: table[idx])
+        return window()[jnp.clip(idx - start, 0, GATHER_WINDOW - 1)]
+    ways = [lambda: table[idx], near]
+    path = jnp.where(hi - start < GATHER_WINDOW, LOOKUP_WINDOW, LOOKUP_GATHER)
+    if idx.shape[0] % LOOKUP_BLOCK == 0:
+        blocks = idx.reshape(-1, LOOKUP_BLOCK)
+        held = live.reshape(blocks.shape)
+        lo_b = jnp.min(jnp.where(held, blocks, size - 1), axis=1)
+        hi_b = jnp.max(jnp.where(held, blocks, 0), axis=1)
+        # the window's tile of each block's least live index (a block with
+        # no live row reads the last two and fits)
+        tile = jnp.clip((lo_b - start) // LOOKUP_TILE, 0,
+                        GATHER_WINDOW // LOOKUP_TILE - 2)
+        base = start + tile * LOOKUP_TILE
+        ways.append(lambda: _block_local(window(), tile,
+                                         blocks - base[:, None]))
+        path = jnp.where(jnp.all(hi_b - base < LOOKUP_SPAN), LOOKUP_BLOCKED,
+                         path)
+    path = path.astype(jnp.int32)
+    return jax.lax.switch(path, ways), path
+
+
+def _block_local(window, tile, rel):
+    """Row r of block b reads entry `rel[b, r]` of the LOOKUP_SPAN entries
+    that begin at tile `tile[b]` of the window.  Exact: the product sums
+    one byte and zeros, the select one entry and zeros."""
+    nt = GATHER_WINDOW // LOOKUP_TILE
+    pairs = jnp.concatenate(
+        [window[:-LOOKUP_TILE].reshape(nt - 1, LOOKUP_TILE),
+         window[LOOKUP_TILE:].reshape(nt - 1, LOOKUP_TILE)], axis=1)
+    onehot = (tile[:, None] == jnp.arange(nt - 1, dtype=tile.dtype)
+              ).astype(jnp.int8)
+    stretch = _from_bytes(jax.lax.dot(onehot, _to_bytes(pairs),
+                                      preferred_element_type=jnp.int32))
+    # [block, entry, row]: the rows on the vector's lanes, the reduction
+    # over the entries elementwise across registers
+    pick = rel[:, None, :] == jnp.arange(LOOKUP_SPAN, dtype=rel.dtype
+                                         )[None, :, None]
+    return jnp.sum(jnp.where(pick, stretch[:, :, None], 0), axis=1,
+                   dtype=window.dtype).reshape(-1)
+
+
+def _to_bytes(v):
+    """int32 [m, k] -> int8 [m, 4k]: each entry's four bytes, less 128."""
+    u = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    parts = [((u >> (8 * j)) & 0xFF).astype(jnp.int32) - 128
+             for j in range(4)]
+    return jnp.stack(parts, axis=-1).astype(jnp.int8).reshape(
+        v.shape[0], 4 * v.shape[1])
+
+
+def _from_bytes(r):
+    """`_to_bytes`' inverse, from the int32 sums of its bytes."""
+    b = (r + 128).astype(jnp.uint32).reshape(r.shape[0], -1, 4)
+    u = b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) | (b[..., 3] << 24)
+    return jax.lax.bitcast_convert_type(u, jnp.int32)
 
 
 def direct_lookup(batch: Batch, dt, probe_key: str):

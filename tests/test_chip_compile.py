@@ -302,6 +302,31 @@ def test_join_path_program_compiles_and_fits(monkeypatch, one_chip, sql,
     assert 0 < need < HBM_BYTES, mem
 
 
+def test_block_local_lookup_compiles_and_fits(one_chip):
+    """`ops.gather_near` as Q3's count pass runs it at SF10: 64K-row
+    batches of l_orderkey against the 15M-entry direct-address table of
+    orders, its way returned with the sums.  The block-local read's int8
+    product lands on the matrix unit (a `convolution`)."""
+    from presto_tpu.exec import operators as ops
+    rows, chunks, size = 1 << 16, 4, 15_000_000
+
+    def count(table, idx, live):
+        def step(chunk):
+            with ops.lookup_paths() as paths:
+                found = ops.gather_near(table, *chunk)
+            return jnp.sum(found, dtype=jnp.int32), paths[0]
+        return jax.lax.map(step, (idx, live))
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    compiled = jax.jit(count).lower(
+        shape((size,), jnp.int32), shape((chunks, rows), jnp.int32),
+        shape((chunks, rows), jnp.bool_)).compile()
+    assert "convolution" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < HBM_BYTES
+
+
 def test_a_cached_build_side_brings_no_program_of_its_own(monkeypatch):
     """The door that remembers build sides (`PlanCompiler.shared_build`)
     adds no program family: an execution that takes its build side from

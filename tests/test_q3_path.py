@@ -2,8 +2,9 @@
 coordinator -> worker against the benchmark's independent reference, the
 join distribution chosen by bytes, a filtered dense key addressed
 directly, the aggregation of a stream (one pass, the table grown in
-place, the source never executed again), TopN without a sort, the windowed
-gather, and the door for Presto's catalog properties in WorkerServer."""
+place, the source never executed again), TopN without a sort, the
+block-local lookup, and the door for Presto's catalog properties in
+WorkerServer."""
 import os
 import sys
 import time
@@ -11,7 +12,6 @@ import time
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from presto_tpu.exec import operators as ops
@@ -329,28 +329,6 @@ def test_filtered_dense_key_builds_a_direct_table():
     assert try_direct_table(sparse, "k", allow_dup=False) is None
 
 
-@pytest.mark.parametrize("case", ["clustered", "scattered", "none_live",
-                                  "small_table"])
-def test_gather_near_equals_the_gather(case):
-    rng = np.random.default_rng(4)
-    size = 1 << 12 if case == "small_table" else 1 << 19
-    table = jnp.asarray(rng.integers(-1, 1000, size), dtype=jnp.int32)
-    n = 8192
-    if case == "scattered":
-        idx = rng.integers(0, size, n)
-    else:
-        idx = np.sort(rng.integers(size // 2, size // 2 + 1000, n)) % size
-    live = jnp.asarray(rng.random(n) < 0.5) if case != "none_live" \
-        else jnp.zeros(n, dtype=bool)
-    idx = jnp.asarray(idx, dtype=jnp.int32)
-    got = jax.jit(ops.gather_near)(table, idx, live)
-    want = table[idx]
-    # (a row that is not live may read garbage through the window)
-    assert bool(jnp.all(jnp.where(live, got == want, True)))
-    if case in ("scattered", "small_table"):    # the plain gather ran
-        assert bool(jnp.all(got == want))
-
-
 # ---------------------------------------------------------------------------
 # one aggregation-sizing rule: a stream is read once
 # ---------------------------------------------------------------------------
@@ -544,4 +522,6 @@ def test_chain_with_a_join_is_cut_and_made_dense(analyze):
         stats = result.runtime_stats
         assert _sum(stats, "denseStreamChunks") >= 10
         assert _sum(stats, "aggRestreams") == 0
+        # lineitem's sorted orderkeys read the orders table block by block
+        assert _sum(stats, "chainLookupBlockedChunks") > 0
     runner.assert_same_as_reference(Q3_TEXT)
