@@ -170,6 +170,40 @@ class Int128Block(Block):
             arr[i, 1] = hi
         return Int128Block(arr.view(np.int64), _mask(nulls))
 
+    @staticmethod
+    def from_int64(values, nulls=None) -> "Int128Block":
+        """`from_ints` of an int64 array, by whole-array operations: the same
+        words, a null row two zero words.  |INT64_MIN| wraps to the int64
+        bit pattern of 2**63, which is its magnitude's low word."""
+        values = np.asarray(values, dtype=np.int64)
+        nulls = _mask(nulls)
+        if nulls is not None:
+            values = np.where(nulls, 0, values)
+        words = np.empty((len(values), 2), dtype=np.int64)
+        words[:, 0] = np.abs(values)
+        words[:, 1] = np.where(values < 0, np.iinfo(np.int64).min, 0)
+        return Int128Block(words, nulls)
+
+    def to_int64(self) -> np.ndarray:
+        """The values narrowed to int64 by whole-array operations, null rows
+        0 (`self.nulls` says which).  Raises OverflowError where a live row's
+        magnitude does not fit: word 1 holds a bit below the sign, or word 0
+        is 2**63 or more, short of -2**63 exactly."""
+        lo, hi = self.values[:, 0], self.values[:, 1]
+        negative = hi < 0
+        wide = ((hi & np.iinfo(np.int64).max) != 0) | (
+            (lo < 0) & ~(negative & (lo == np.iinfo(np.int64).min)))
+        if self.nulls is not None:
+            wide &= ~self.nulls
+        if wide.any():
+            raise OverflowError(
+                "long decimal beyond int64, which is its device form: "
+                f"{int(wide.sum())} row(s)")
+        values = np.where(negative, -lo, lo)   # -(-2**63) wraps to itself
+        if self.nulls is not None:
+            values[self.nulls] = 0
+        return values
+
 
 class VariableWidthBlock(Block):
     """VARIABLE_WIDTH: concatenated bytes + (n+1) int32 offsets."""

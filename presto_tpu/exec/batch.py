@@ -203,16 +203,7 @@ def block_to_column(typ: Type, block, capacity: int) -> Column:
     if isinstance(block, Int128Block):
         # device holds long decimals narrowed to int64 (batch_to_page widens
         # on the way back out); values beyond int64 have no device form
-        ints = block.to_pylist()
-        vals = np.zeros(capacity, dtype=np.int64)
-        nm = np.zeros(capacity, dtype=bool)
-        for i, v in enumerate(ints):
-            if v is None:
-                nm[i] = True
-            else:
-                vals[i] = v
-        nulls = jnp.asarray(nm) if nm.any() else None
-        return Column(jnp.asarray(vals), nulls)
+        block = FixedWidthBlock(block.to_int64(), block.nulls)
 
     from ..common.block import ArrayBlock
     if isinstance(block, ArrayBlock):
@@ -400,9 +391,7 @@ def batch_to_page(batch: Batch, names, types) -> Page:
         if isinstance(typ, DecimalType) and not typ.is_short:
             # device accumulates long decimals in int64; widen on the host
             from ..common.block import Int128Block
-            ints = [None if (nulls is not None and nulls[i]) else int(v)
-                    for i, v in enumerate(values)]
-            blocks.append(Int128Block.from_ints(ints, nulls))
+            blocks.append(Int128Block.from_int64(values, nulls))
             continue
         if isinstance(typ, BooleanType):
             values = values.astype(np.int8)
@@ -440,12 +429,7 @@ def _host_column(typ: Type, block):
     if isinstance(block, Int128Block):
         # device holds long decimals narrowed to int64 (batch_to_page widens
         # on the way back out); values beyond int64 have no device form
-        ints = block.to_pylist()
-        nulls = np.fromiter((v is None for v in ints), dtype=bool,
-                            count=len(ints))
-        values = np.fromiter((0 if v is None else v for v in ints),
-                             dtype=np.int64, count=len(ints))
-        return values, nulls if nulls.any() else None, None
+        return block.to_int64(), block.nulls, None
     if not isinstance(block, FixedWidthBlock):
         return None
     return _logical_np(typ, block.values), block.nulls, None
